@@ -66,9 +66,14 @@ def butterfly_reduce_quant_kernel(x, w_reduce, *, bits: int = 8,
 def _reduce_quant_bincount_kernel(x_ref, w_ref, codes_ref, scales_ref,
                                   counts_ref, *, qmax: int, nsym: int):
     """Reduce+quant epilogue plus a per-channel symbol histogram, accumulated
-    across the token grid into a single fixed-index (d_r, nsym) output — the
+    across the token grid into a single fixed-index (nsym, d_r) output — the
     codes never leave VMEM between quantization and counting, so the edge
-    gets its entropy estimate for free in the same pass."""
+    gets its entropy estimate for free in the same pass.
+
+    Counting runs one symbol at a time: a (TM, d_r) compare and a column sum
+    per symbol.  A (TM, d_r, nsym) one-hot would do the same work but holds
+    nsym times the codes in VMEM, which overflows the scoped limit at real
+    widths (TM=256, d_r=128)."""
     x = x_ref[...]
     w = w_ref[...]
     r = jax.lax.dot_general(
@@ -85,9 +90,13 @@ def _reduce_quant_bincount_kernel(x_ref, w_ref, codes_ref, scales_ref,
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
     sym = codes.astype(jnp.int32) + (qmax + 1)            # (TM, d_r) in [0, nsym)
-    ks = jax.lax.broadcasted_iota(jnp.int32, (1, 1, nsym), 2)
-    onehot = (sym[:, :, None] == ks).astype(jnp.int32)
-    counts_ref[...] += jnp.sum(onehot, axis=0)            # (d_r, nsym)
+
+    def count(k, carry):
+        hits = jnp.sum((sym == k).astype(jnp.int32), axis=0, keepdims=True)
+        counts_ref[pl.ds(k, 1), :] += hits                # (1, d_r)
+        return carry
+
+    jax.lax.fori_loop(0, nsym, count, 0)
 
 
 def butterfly_reduce_quant_bincount_kernel(x, w_reduce, *, bits: int = 8,
@@ -101,7 +110,7 @@ def butterfly_reduce_quant_bincount_kernel(x, w_reduce, *, bits: int = 8,
     qmax = 2 ** (bits - 1) - 1
     nsym = 1 << bits
     grid = (T // block_t,)
-    return pl.pallas_call(
+    codes, scales, counts = pl.pallas_call(
         functools.partial(_reduce_quant_bincount_kernel, qmax=qmax, nsym=nsym),
         grid=grid,
         in_specs=[
@@ -111,15 +120,16 @@ def butterfly_reduce_quant_bincount_kernel(x, w_reduce, *, bits: int = 8,
         out_specs=[
             pl.BlockSpec((block_t, d_r), lambda i: (i, 0)),
             pl.BlockSpec((block_t, 1), lambda i: (i, 0)),
-            pl.BlockSpec((d_r, nsym), lambda i: (0, 0)),
+            pl.BlockSpec((nsym, d_r), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((T, d_r), jnp.int8),
             jax.ShapeDtypeStruct((T, 1), jnp.float32),
-            jax.ShapeDtypeStruct((d_r, nsym), jnp.int32),
+            jax.ShapeDtypeStruct((nsym, d_r), jnp.int32),
         ],
         interpret=interpret,
     )(x, w_reduce)
+    return codes, scales, counts.T
 
 
 def _dequant_restore_norm_kernel(codes_ref, scales_ref, w_ref, nw_ref,

@@ -1,9 +1,12 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels run in interpret mode — the kernel body
-executes with jnp semantics, which is how correctness is validated.  On a
-real TPU backend the same calls compile through Mosaic.  ``use_pallas()``
-picks the implementation; callers can force the reference path.
+On a TPU backend every call compiles through Mosaic.  On the CPU backend the
+kernels run in interpret mode (the kernel body executes with jnp semantics),
+which is how the tests check them against the ``ref`` oracles.  Any other
+backend raises: a measurement must never fall back to the interpreter.
+The wrappers pick each kernel's row tile (:func:`decode_row_block`, capped
+by a VMEM budget for wide rows) and handle padding and the decode-row fast
+path.
 """
 from __future__ import annotations
 
@@ -25,7 +28,14 @@ from repro.kernels.rmsnorm import rmsnorm_kernel
 
 
 def interpret_mode() -> bool:
-    return jax.default_backend() != "tpu"
+    """True on the CPU backend, False on TPU; any other backend raises."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels need a TPU (or the CPU interpreter); "
+                       f"backend is {backend!r}")
 
 
 def _pad_to(x, multiple: int, axis: int):
@@ -53,6 +63,21 @@ def decode_row_block(n_rows: int = 1, block_t: int = 256) -> int:
     return min(block_t, max(_FAST_PATH_ROWS, n_rows))
 
 
+# Mosaic's scoped-VMEM limit on a TPU v5e is 16 MiB per kernel.  A row tile
+# holds its double-buffered row blocks plus an f32 working row; keeping
+# those under 12 MiB leaves room for the weight block and compiler scratch.
+# (At d=4096 the fused restore+norm with two f32 outputs needs 17 MiB at
+# 256 rows and is refused; it fits at 128.)
+_VMEM_ROW_BUDGET = 12 * 2 ** 20
+
+
+def _row_block(n_rows: int, block_t: int, row_bytes: int) -> int:
+    """:func:`decode_row_block`, capped at the largest power of two of rows
+    of ``row_bytes`` VMEM each that fits ``_VMEM_ROW_BUDGET``."""
+    fit = max(_FAST_PATH_ROWS, _VMEM_ROW_BUDGET // row_bytes)
+    return min(decode_row_block(n_rows, block_t), 1 << (fit.bit_length() - 1))
+
+
 def _reduce_quant_rows(xf, w_reduce, qmax: int):
     r = jax.lax.dot_general(xf, w_reduce, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -77,7 +102,7 @@ def butterfly_reduce_quant(x, w_reduce, *, bits: int = 8,
                                            2 ** (bits - 1) - 1)
         return (codes.reshape(*shape[:-1], d_r),
                 scales.reshape(*shape[:-1], 1))
-    block = decode_row_block(T, block_t)
+    block = _row_block(T, block_t, d * (2 * x.dtype.itemsize + 4))
     xf, pad_t = _pad_to(xf, block, 0)
     codes, scales = butterfly_reduce_quant_kernel(
         xf, w_reduce, bits=bits, block_t=block, interpret=interpret_mode())
@@ -116,7 +141,7 @@ def butterfly_reduce_quant_bincount(x, w_reduce, *, bits: int = 8,
         counts = _channel_bincount(codes, qmax, nsym)
         return (codes.reshape(*shape[:-1], d_r),
                 scales.reshape(*shape[:-1], 1), counts)
-    block = decode_row_block(T, block_t)
+    block = _row_block(T, block_t, d * (2 * x.dtype.itemsize + 4))
     xf, pad_t = _pad_to(xf, block, 0)
     codes, scales, counts = butterfly_reduce_quant_bincount_kernel(
         xf, w_reduce, bits=bits, block_t=block, interpret=interpret_mode())
@@ -130,7 +155,10 @@ def butterfly_reduce_quant_bincount(x, w_reduce, *, bits: int = 8,
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "block_t"))
 def butterfly_dequant_restore(codes, scales, w_restore, *,
-                              out_dtype=jnp.float32, block_t: int = 256):
+                              out_dtype=None, block_t: int = 256):
+    """codes (..., d_r) int8, scales (..., 1) -> restored (..., d).
+    ``out_dtype`` defaults to the model dtype (``w_restore``'s)."""
+    out_dtype = w_restore.dtype if out_dtype is None else out_dtype
     shape = codes.shape
     d_r = shape[-1]
     d = w_restore.shape[1]
@@ -142,7 +170,8 @@ def butterfly_dequant_restore(codes, scales, w_restore, *,
         out = jax.lax.dot_general(r, w_restore, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
         return out.astype(out_dtype).reshape(*shape[:-1], d)
-    block = decode_row_block(T, block_t)
+    block = _row_block(T, block_t,
+                       d * (2 * jnp.dtype(out_dtype).itemsize + 4))
     cf, pad_t = _pad_to(cf, block, 0)
     sf, _ = _pad_to(sf, block, 0)
     out = butterfly_dequant_restore_kernel(
@@ -155,14 +184,16 @@ def butterfly_dequant_restore(codes, scales, w_restore, *,
 
 @functools.partial(jax.jit, static_argnames=("eps", "out_dtype", "block_t"))
 def butterfly_restore_norm(codes, scales, w_restore, norm_w, *,
-                           eps: float = 1e-6, out_dtype=jnp.float32,
+                           eps: float = 1e-6, out_dtype=None,
                            block_t: int = 256):
     """Fused dequant + restore + first-cloud-layer RMSNorm.
 
     codes: (..., d_r) int8, scales: (..., 1) -> (x (..., d), h (..., d))
     where ``x`` is the restored boundary activation (the residual-stream
     input) and ``h = rms_norm(x, norm_w)`` (the layer's norm1 output).
-    Bitwise equal to butterfly_dequant_restore followed by rms_norm."""
+    Bitwise equal to butterfly_dequant_restore followed by rms_norm.
+    ``out_dtype`` defaults to the model dtype (``w_restore``'s)."""
+    out_dtype = w_restore.dtype if out_dtype is None else out_dtype
     shape = codes.shape
     d_r = shape[-1]
     d = w_restore.shape[1]
@@ -176,7 +207,8 @@ def butterfly_restore_norm(codes, scales, w_restore, norm_w, *,
         x = out.astype(out_dtype)
         h = ref.rms_norm_ref(x, norm_w, eps)
         return (x.reshape(*shape[:-1], d), h.reshape(*shape[:-1], d))
-    block = decode_row_block(T, block_t)
+    block = _row_block(T, block_t,
+                       d * (4 * jnp.dtype(out_dtype).itemsize + 4))
     cf, pad_t = _pad_to(cf, block, 0)
     sf, _ = _pad_to(sf, block, 0)
     x, h = butterfly_dequant_restore_norm_kernel(

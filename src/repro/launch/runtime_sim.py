@@ -60,14 +60,18 @@ def parse_ramp(spec: str):
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--arch", default="qwen3-8b")
+    ap.add_argument("--full", action="store_true",
+                    help="run the arch at its published widths and dtype "
+                         "instead of its reduced smoke variant; --layers "
+                         "then cuts only the depth")
     ap.add_argument("--layers", type=int, default=4,
-                    help="override layer count of the reduced arch "
+                    help="override layer count of the arch "
                          "(>=2; more layers = more candidate splits)")
     ap.add_argument("--heads", type=int, default=None,
-                    help="override attention head count of the reduced arch "
+                    help="override attention head count of the arch "
                          "(model-parallel degrees must divide the heads)")
     ap.add_argument("--kv-heads", type=int, default=None,
-                    help="override kv head count of the reduced arch")
+                    help="override kv head count of the arch")
     ap.add_argument("--mode", choices=("split", "cloud", "edge"),
                     default="split")
     ap.add_argument("--wire-mode",
@@ -195,7 +199,12 @@ def main():
                                          parse_topology, trace_arrivals,
                                          trace_faults)
 
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
+    if not args.full:
+        cfg = cfg.reduced()
+    if not args.no_numerics:
+        from repro.compile_cache import configure_compile_cache
+        configure_compile_cache()
     if args.layers and args.layers != cfg.num_layers:
         cfg = dataclasses.replace(cfg, num_layers=max(2, args.layers))
     if args.heads:

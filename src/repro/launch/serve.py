@@ -4,6 +4,7 @@ baseline) or the 2-pod split pipeline (--split, the paper's deployment).
 Example:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --prompts "hello" "world"
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-12b --split
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --full --layers 8
 """
 from __future__ import annotations
 
@@ -13,6 +14,11 @@ import argparse
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b")
+    ap.add_argument("--full", action="store_true",
+                    help="run the arch at its published widths and dtype "
+                         "instead of its reduced smoke variant")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the arch to this many layers (depth only)")
     ap.add_argument("--prompts", nargs="*", default=["the quick brown fox",
                                                      "once upon a time"])
     ap.add_argument("--max-new-tokens", type=int, default=16)
@@ -36,11 +42,17 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.compile_cache import configure_compile_cache
     from repro.configs import get_config
     from repro.data import tokenizer as tok
     from repro.models import model as M
 
-    cfg = get_config(args.arch).reduced()
+    configure_compile_cache()
+    cfg = get_config(args.arch)
+    if not args.full:
+        cfg = cfg.reduced()
+    if args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
     cfg = dataclasses.replace(cfg, vocab_size=max(cfg.vocab_size, tok.VOCAB_SIZE))
     if args.split:
         cfg = cfg.with_butterfly(args.butterfly_layer, args.d_r)

@@ -50,6 +50,7 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from repro.compile_cache import configure_compile_cache
     from repro.configs import get_config
     from repro.data import lm_batches
     from repro.models import model as M
@@ -57,6 +58,7 @@ def main():
                                 make_train_step)
     from repro.training.checkpoint import save_checkpoint
 
+    configure_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.models.common import dense_init, glu_act
 from repro.models.parallel import ParallelContext
@@ -273,7 +272,7 @@ def apply_moe(params, x, *, cfg: ModelConfig, pctx: ParallelContext, act: str):
             return out.reshape(xb.shape), lb, zl
 
         e_ax = ("pod", "model") if over_pod else "model"
-        out, lb, zl = compat.shard_map(
+        out, lb, zl = jax.shard_map(
             shard_fn, mesh=pctx.mesh,
             in_specs=(P(dpx, None, None), P(None, None),
                       P(e_ax, None, ff_ax), P(e_ax, None, ff_ax),

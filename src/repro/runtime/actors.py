@@ -48,6 +48,7 @@ class SimRequest:
     trace: RequestTrace
     tokens: Optional[np.ndarray] = None       # prompt (numerics mode)
     max_new_tokens: int = 1
+    record_logits: bool = False               # engine keeps per-step logits
     payload: Optional[tuple] = None           # (codes, scales, stage0_cache)
     engine_req: object = None                 # serving.engine.Request
     slot: int = -1                            # cloud slot (virtual accounting)
@@ -307,7 +308,8 @@ class EdgeDevice:
         if self.bank is not None and req.tokens is not None:
             eng = self._ensure_local_engine()
             req.engine_req = eng.submit(req.tokens,
-                                        max_new_tokens=req.max_new_tokens)
+                                        max_new_tokens=req.max_new_tokens,
+                                        record_logits=req.record_logits)
             eng.run()
             t.new_tokens = len(req.engine_req.generated)
         else:
@@ -354,7 +356,8 @@ class EdgeDevice:
         if self.bank is not None:
             eng = self._ensure_local_engine()
             req.engine_req = eng.submit(req.tokens,
-                                        max_new_tokens=req.max_new_tokens)
+                                        max_new_tokens=req.max_new_tokens,
+                                        record_logits=req.record_logits)
             eng.run()
             t.new_tokens = len(req.engine_req.generated)
         else:

@@ -500,6 +500,9 @@ class SimConfig:
     max_concurrent: int = 8
     seed: int = 0
     numerics: bool = True
+    # serving engines keep every request's per-step logits (host copies)
+    # on its engine Request (``SimRequest.engine_req.logits_history``)
+    record_logits: bool = False
     arrivals: Optional[Sequence[Arrival]] = None   # overrides Poisson build
     # workload spec (a WorkloadSpec or its grammar string): THE arrival
     # API.  Its rate/n/prompt_len override the three legacy fields above,
@@ -895,7 +898,8 @@ class Simulation:
                 split=0, prompt_len=c.prompt_len,
                 cell=self.cells[a.cell].name, slo_class=a.slo)
             req = SimRequest(trace=trace, tokens=a.tokens,
-                             max_new_tokens=c.max_new_tokens)
+                             max_new_tokens=c.max_new_tokens,
+                             record_logits=c.record_logits)
             self.requests.append(req)
             self.loop.schedule_at(a.t, self._make_arrival(a.device, req))
 

@@ -500,10 +500,10 @@ class SplitModelBank:
         mesh = self.mp_mesh(mp)
         if mesh is None:
             return fn
-        from repro import compat
+        import jax
         in_specs, out_specs = specs()
-        return compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_vma=False)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _make_edge(self, split: int, mp: int = 1):
         import jax

@@ -130,10 +130,12 @@ class CacheHandoffTransport(DecodeTransport):
                 logits_row, cache1, cache0 = server._cloud_numerics(req)
                 req.engine_req = eng.submit_prefilled(
                     t.prompt_len, [cache0, cache1], logits_row,
-                    max_new_tokens=req.max_new_tokens)
+                    max_new_tokens=req.max_new_tokens,
+                    record_logits=req.record_logits)
             else:
                 req.engine_req = eng.submit(
-                    req.tokens, max_new_tokens=req.max_new_tokens)
+                    req.tokens, max_new_tokens=req.max_new_tokens,
+                    record_logits=req.record_logits)
             req.payload = None
             if req.engine_req.done:
                 server._complete(req)
@@ -264,7 +266,8 @@ class StreamedTransport(DecodeTransport):
             req.cloud_pos = t.prompt_len
             eng = server._engine(t.split)
             req.engine_req = eng.submit_streamed(
-                t.prompt_len, logits_row, max_new_tokens=req.max_new_tokens)
+                t.prompt_len, logits_row, max_new_tokens=req.max_new_tokens,
+                record_logits=req.record_logits)
             req.payload = None
             tok = req.engine_req.generated[0]
         else:
@@ -373,7 +376,8 @@ class ProgressiveTransport(StreamedTransport):
             req.cloud_pos = t.prompt_len
             eng = server._engine(t.split)
             req.engine_req = eng.submit_streamed(
-                t.prompt_len, logits_row, max_new_tokens=req.max_new_tokens)
+                t.prompt_len, logits_row, max_new_tokens=req.max_new_tokens,
+                record_logits=req.record_logits)
             req.payload = None
             tok = int(req.engine_req.generated[0])
         else:

@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.quantization import (dequantize, pack_int4, quantize,
                                      unpack_int4, wire_bytes)
 from repro.kernels import ops
@@ -74,7 +73,7 @@ def pipeline_param_specs(built: M.BuiltModel, mp: int):
 
 def make_split_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
                         seq_len: int, microbatch: int,
-                        wire_mode: str = "int8"):
+                        wire_mode: str = "int8", use_kernel: bool = False):
     """Returns jit-able ``pipeline_fn(params, tokens) -> last-token logits``.
 
     tokens: (num_microbatches * microbatch, seq_len) int32, sharded over the
@@ -90,6 +89,9 @@ def make_split_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
       "int8"    the paper: reduction + int8 wire (codes + f32 scales)
       "int4"    reduction + 4-bit wire: codes quantize to [-8, 7] and pack
                 two per byte, halving per-token uplink bytes vs int8
+
+    use_kernel — quantized wires run through the fused Pallas reduce+quant
+    (edge) and dequant+restore (cloud) kernels instead of the eager codec.
     """
     cfg = built.cfg
     assert built.has_butterfly and len(built.stages) == 2, \
@@ -125,10 +127,14 @@ def make_split_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
             shared_params=params.get("shared_attn"))
         if wire_mode == "raw":
             return x, jnp.zeros((x.shape[0], seq_len, 1), jnp.float32)
-        r = x @ params["butterfly"]["w_reduce"]
-        if wire_mode == "reduced":
-            return r, jnp.zeros((r.shape[0], seq_len, 1), jnp.float32)
-        codes, scales = quantize(r, bits)
+        if use_kernel and wire_mode != "reduced":
+            codes, scales = ops.butterfly_reduce_quant(
+                x, params["butterfly"]["w_reduce"], bits=bits)
+        else:
+            r = x @ params["butterfly"]["w_reduce"]
+            if wire_mode == "reduced":
+                return r, jnp.zeros((r.shape[0], seq_len, 1), jnp.float32)
+            codes, scales = quantize(r, bits)
         if wire_mode == "int4":
             codes = pack_int4(codes)
         return codes, scales
@@ -145,8 +151,13 @@ def make_split_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
             return unembed(table, x)[:, 0]
         if wire_mode == "int4":
             codes = unpack_int4(codes)
-        r = codes if wire_mode == "reduced" else dequantize(codes, scales, dt)
-        x = r @ params["butterfly"]["w_restore"]
+        if use_kernel and wire_mode != "reduced":
+            x = ops.butterfly_dequant_restore(
+                codes, scales, params["butterfly"]["w_restore"], out_dtype=dt)
+        else:
+            r = codes if wire_mode == "reduced" else \
+                dequantize(codes, scales, dt)
+            x = r @ params["butterfly"]["w_restore"]
         x, _, _ = tfm.apply_stage(
             list(built.stages[1]), params["stages"][1], x, cfg=cfg,
             pctx=pctx, mode="train", stage_cache=None, pos=None,
@@ -202,24 +213,26 @@ def make_split_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
         out0 = jnp.zeros((Mmb, mb, V), jnp.float32)
         carry = (*zero_wire, out0, out0)
         *_, out, back = jax.lax.fori_loop(0, Mmb + 1, tick, carry)
-        # pod 1 filled `out` locally; pod 0 received `back`. Select the live
-        # copy so the caller-visible result is pod-invariant.
-        result = jnp.where(pod == 0, back, out)
-        return result[None]                                  # add pod dim
+        # pod 1 filled `out` locally; pod 0 received `back`, a bitwise copy
+        # of it. Select the live copy so the result is pod-invariant.
+        return jnp.where(pod == 0, back, out).reshape(-1, V)
 
-    data_ax = "data" if "data" in axes else None
-    fn = compat.shard_map(
+    return _pod_invariant_shard_map(shard_body, built, mesh, mp)
+
+
+def _pod_invariant_shard_map(shard_body, built, mesh, mp: int):
+    """shard_map a pipeline body whose per-pod results are bitwise equal.
+    The output spec leaves ``pod`` unmapped, so the caller gets one copy
+    without indexing a pod-sharded dim, and the body returns its rows in
+    input order, so no reshape merges a sharded dim.  Sharding-in-types
+    rejects both on a mesh with explicit axes."""
+    data_ax = "data" if "data" in mesh.axis_names else None
+    return jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(pipeline_param_specs(built, mp), P(data_ax, None)),
-        out_specs=P("pod", None, data_ax, None),
+        out_specs=P(data_ax, None),
         check_vma=False,
     )
-
-    def pipeline_fn(params, tokens):
-        res = fn(params, tokens)
-        return res[0].reshape(-1, V)                         # pod 0's copy
-
-    return pipeline_fn
 
 
 def _grow_cache(small, template):
@@ -503,19 +516,7 @@ def make_decode_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
         # pipelined: one extra drain tick so the cloud finishes the last row
         carry = jax.lax.fori_loop(0, n_ticks + (1 if pipelined else 0),
                                   tick, carry)
-        out = carry[3]
-        return jnp.transpose(out, (0, 2, 1))[None]           # (1, Mmb, mb, T)
+        # both pods commit every decoded token, so `out` is pod-invariant
+        return jnp.transpose(carry[3], (0, 2, 1)).reshape(-1, T)
 
-    data_ax = "data" if "data" in axes else None
-    fn = compat.shard_map(
-        shard_body, mesh=mesh,
-        in_specs=(pipeline_param_specs(built, mp), P(data_ax, None)),
-        out_specs=P("pod", None, data_ax, None),
-        check_vma=False,
-    )
-
-    def decode_fn(params, tokens):
-        res = fn(params, tokens)
-        return res[0].reshape(-1, T)                         # pod 0's copy
-
-    return decode_fn
+    return _pod_invariant_shard_map(shard_body, built, mesh, mp)

@@ -62,6 +62,54 @@ def test_butterfly_restore_norm_vs_ref(T, d, d_r):
                                rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("T", [32, 4])     # kernel grid path, fast path
+def test_restore_out_dtype_follows_model_dtype(T):
+    """Without an explicit out_dtype the restore kernels emit the model
+    dtype (w_restore's), bitwise what an explicit out_dtype gives."""
+    k1, k2, k3 = jax.random.split(jax.random.key(3), 3)
+    x = jax.random.normal(k1, (T, 128), jnp.float32)
+    w = jax.random.normal(k2, (128, 16), jnp.float32) * 0.05
+    codes, scales = ref.butterfly_reduce_quant_ref(x, w)
+    for dt in (jnp.float32, jnp.bfloat16):
+        wr = (jax.random.normal(k3, (16, 128), jnp.float32) * 0.05).astype(dt)
+        nw = jnp.zeros((128,), dt)
+        out = ops.butterfly_dequant_restore(codes, scales, wr, block_t=16)
+        xr, h = ops.butterfly_restore_norm(codes, scales, wr, nw, block_t=16)
+        assert out.dtype == xr.dtype == h.dtype == dt
+        exp = ops.butterfly_dequant_restore(codes, scales, wr, block_t=16,
+                                            out_dtype=dt)
+        exp_x, exp_h = ops.butterfly_restore_norm(codes, scales, wr, nw,
+                                                  block_t=16, out_dtype=dt)
+        for got, want in ((out, exp), (xr, exp_x), (h, exp_h)):
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("row_bytes,rows", [
+    (4096 * (4 * 4 + 4), 128),   # restore+norm, two f32 outputs at d=4096
+    (4096 * (4 * 2 + 4), 256),   # restore+norm, two bf16 outputs at d=4096
+    (128 * (2 * 4 + 4), 256),    # narrow rows keep the requested tile
+    (10 ** 9, 8),                # never below the 8-row tile
+])
+def test_row_tile_capped_by_vmem_budget(row_bytes, rows):
+    assert ops._row_block(512, 256, row_bytes) == rows
+    assert ops._row_block(4, 256, row_bytes) == min(rows, 8)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    """The Pallas interpreter runs on the CPU only: a TPU compiles through
+    Mosaic, and any other backend fails instead of falling back."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops.interpret_mode()
+    else:
+        assert ops.interpret_mode() is interpret
+
+
 @pytest.mark.parametrize("T,d,d_r", [(32, 128, 8),     # kernel grid path
                                      (100, 128, 16),   # padded grid (count fix)
                                      (4, 128, 16)])    # decode-row fast path

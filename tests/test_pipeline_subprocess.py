@@ -27,6 +27,10 @@ logits = pipe(params, toks)
 ref, _ = M.forward_train(params, built, {"tokens": toks})
 err = float(jnp.max(jnp.abs(logits - ref[:, -1])))
 assert err < 5e-3, err
+# the fused Pallas wire kernels (interpret mode here) give the same logits
+kern = jax.jit(make_split_pipeline(built, mesh, Mmb, S, mb, use_kernel=True))
+kerr = float(jnp.max(jnp.abs(kern(params, toks) - logits)))
+assert kerr < 5e-3, kerr
 hlo = jax.jit(pipe).lower(params, toks).compile().as_text()
 assert any("collective-permute" in l and "s8[" in l for l in hlo.splitlines()), \
     "wire must cross the pod boundary as int8"

@@ -304,3 +304,25 @@ def test_streamed_e2e_numerics_traces():
     assert sim.server.num_active == 0
     for eng in sim.server._engines.values():
         assert eng.num_active == 0
+
+
+@pytest.mark.parametrize("transport", ["cache_handoff", "streamed",
+                                       "progressive"])
+def test_record_logits_reaches_every_decode_step(transport):
+    """SimConfig.record_logits keeps one finite logits row per generated
+    token on each request, and greedy decoding picked each row's argmax."""
+    cfg = small_cfg(layers=2)
+    sc = SimConfig(cfg=cfg, mode="split", wire_mode="int8", network="wifi",
+                   num_devices=2, num_requests=3, arrival_rate=20.0,
+                   prompt_len=12, max_new_tokens=3, d_r=16, numerics=True,
+                   max_concurrent=2, transport=transport, seed=2,
+                   record_logits=True)
+    sim = Simulation(sc)
+    sim.run()
+    for req in sim.requests:
+        er = req.engine_req
+        rows = np.stack([np.asarray(r, np.float32)
+                         for r in er.logits_history])
+        assert rows.shape == (3, cfg.vocab_size)
+        assert np.isfinite(rows).all()
+        assert rows.argmax(-1).tolist() == list(er.generated)
