@@ -11,6 +11,10 @@ epilogue in-register.
 
 TM defaults to 256 rows; d and d_r are padded to the 128-lane boundary by
 the ops.py wrapper so MXU dims stay hardware-aligned.
+
+Each ``pallas_call`` is named after its wrapper in ops.py, so its custom
+call (and the profiler's op for it) keeps that name in whatever program it
+is compiled into.
 """
 from __future__ import annotations
 
@@ -60,6 +64,7 @@ def butterfly_reduce_quant_kernel(x, w_reduce, *, bits: int = 8,
             jax.ShapeDtypeStruct((T, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="butterfly_reduce_quant",
     )(x, w_reduce)
 
 
@@ -128,6 +133,7 @@ def butterfly_reduce_quant_bincount_kernel(x, w_reduce, *, bits: int = 8,
             jax.ShapeDtypeStruct((nsym, d_r), jnp.int32),
         ],
         interpret=interpret,
+        name="butterfly_reduce_quant_bincount",
     )(x, w_reduce)
     return codes, scales, counts.T
 
@@ -183,6 +189,7 @@ def butterfly_dequant_restore_norm_kernel(codes, scales, w_restore, norm_w, *,
             jax.ShapeDtypeStruct((T, d), out_dtype),
         ],
         interpret=interpret,
+        name="butterfly_restore_norm",
     )(codes, scales, w_restore, norm_w)
 
 
@@ -215,4 +222,5 @@ def butterfly_dequant_restore_kernel(codes, scales, w_restore, *,
         out_specs=pl.BlockSpec((block_t, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((T, d), out_dtype),
         interpret=interpret,
+        name="butterfly_dequant_restore",
     )(codes, scales, w_restore)

@@ -32,9 +32,12 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
+import numpy as np
+
 from repro.core import costs
 from repro.core.planner import wire_mode_bytes
 from repro.core.profiler import HardwareProfile
+from repro.runtime.tracing import span
 
 
 def act_bytes(cfg) -> int:
@@ -303,22 +306,25 @@ class SplitModelBank:
         return (kind, split, mp, B, S) + self._wire_sig
 
     def timed_call(self, key: Tuple, fn, *args):
-        """Run one hot-path dispatch, recording its compile-cache key (hit
-        or miss per padded-shape entry) and — when a profiler is attached —
-        its wall-clock first-call/steady attribution."""
-        self.note_key(key)
-        if self.profiler is None:
-            return fn(*args)
-        return self.profiler.timed(key, fn, *args)
+        """Run one hot-path dispatch inside a ``dispatch`` span, recording
+        its compile-cache key (hit or miss per padded-shape entry) and —
+        when a profiler is attached — its wall-clock first-call/steady
+        attribution."""
+        miss = self.note_key(key)
+        with span("dispatch", kind=key[0], miss=int(miss)):
+            if self.profiler is None:
+                return fn(*args)
+            return self.profiler.timed(key, fn, *args)
 
-    def note_key(self, key: Tuple) -> None:
+    def note_key(self, key: Tuple) -> bool:
         """Hit/miss bookkeeping only — for dispatches whose jitted call runs
-        elsewhere (the engine's fused sampling steps)."""
+        elsewhere (the engine's fused sampling steps).  True on a miss."""
         if key in self.jit_cache_keys:
             self.cache_hits += 1
-        else:
-            self.cache_misses += 1
-            self.jit_cache_keys.add(key)
+            return False
+        self.cache_misses += 1
+        self.jit_cache_keys.add(key)
+        return True
 
     @property
     def batch_numerics_ok(self) -> bool:
@@ -351,7 +357,6 @@ class SplitModelBank:
             return None
         if mp not in self._meshes:
             import jax
-            import numpy as np
             assert len(jax.devices()) >= mp, \
                 f"model-axis degree {mp} needs >= {mp} devices " \
                 f"(have {len(jax.devices())}; set " \
@@ -746,51 +751,53 @@ class SplitRunner:
         — the jitted core runs at bucket-padded (B, S)."""
         import jax.numpy as jnp
         bank = self.bank
-        toks = jnp.asarray(toks)
-        B, S = toks.shape
-        Bb, Sb = bank._buckets(B, S)
-        out = bank.timed_call(
-            bank.cache_key("edge", self.split, self.edge_mp, Bb, Sb),
-            bank._fn("edge", self.split, self.edge_mp),
-            params, bank._pad_toks(toks, Bb, Sb))
-        payload, scales, cache0 = out
-        return (payload[:B, :S], scales[:B, :S],
-                bank._slice_cache(cache0, 0, self.split, B, S))
+        B, S = np.shape(toks)
+        with span("split.edge_half", rows=S):
+            toks = jnp.asarray(toks)
+            Bb, Sb = bank._buckets(B, S)
+            out = bank.timed_call(
+                bank.cache_key("edge", self.split, self.edge_mp, Bb, Sb),
+                bank._fn("edge", self.split, self.edge_mp),
+                params, bank._pad_toks(toks, Bb, Sb))
+            payload, scales, cache0 = out
+            return (payload[:B, :S], scales[:B, :S],
+                    bank._slice_cache(cache0, 0, self.split, B, S))
 
     def cloud_half(self, params, payload, scales):
         """Cloud stage: restore + layers [split, N) + LM head.  Returns
         (last-position logits (B, V), cache1)."""
         import jax.numpy as jnp
         bank = self.bank
-        payload = jnp.asarray(payload)
-        B, S = payload.shape[:2]
-        Bb, Sb = bank._buckets(B, S)
-        if (Bb, Sb) != (B, S):
-            pad = ((0, Bb - B), (0, Sb - S), (0, 0))
-            payload = jnp.pad(payload, pad)
-            scales = jnp.pad(jnp.asarray(scales), pad)
-        logits, cache1 = bank.timed_call(
-            bank.cache_key("cloud", self.split, self.cloud_mp, Bb, Sb),
-            bank._fn("cloud", self.split, self.cloud_mp),
-            params, payload, scales, jnp.int32(S))
-        return logits[:B], bank._slice_cache(cache1, 1, self.split, B, S)
+        B, S = np.shape(payload)[:2]
+        with span("split.cloud_half", rows=S):
+            payload = jnp.asarray(payload)
+            Bb, Sb = bank._buckets(B, S)
+            if (Bb, Sb) != (B, S):
+                pad = ((0, Bb - B), (0, Sb - S), (0, 0))
+                payload = jnp.pad(payload, pad)
+                scales = jnp.pad(jnp.asarray(scales), pad)
+            logits, cache1 = bank.timed_call(
+                bank.cache_key("cloud", self.split, self.cloud_mp, Bb, Sb),
+                bank._fn("cloud", self.split, self.cloud_mp),
+                params, payload, scales, jnp.int32(S))
+            return logits[:B], bank._slice_cache(cache1, 1, self.split, B, S)
 
     # --------------------------------------------------------- streamed decode
     def edge_step(self, params, tok, cache0, pos):
         """One streamed-decode edge step: ``tok`` (B, 1) int32, ``cache0``
         the edge-resident stage-0 decode cache (pad with
-        :meth:`pad_decode_cache` first), ``pos`` (B,) int32 write positions.
-        Returns ``(payload, scales, new_cache0)`` — one wire row per batch
-        element."""
+        :meth:`pad_decode_cache` first), ``pos`` (B,) int32 write positions,
+        held on the host.  Returns ``(payload, scales, new_cache0)`` — one
+        wire row per batch element."""
         import jax.numpy as jnp
         bank = self.bank
-        tok = jnp.asarray(tok, jnp.int32)
-        out = bank.timed_call(
-            bank.cache_key("edge_step", self.split, self.edge_mp,
-                           tok.shape[0], 1),
-            bank._fn("edge_step", self.split, self.edge_mp),
-            params, tok, cache0, jnp.asarray(pos, jnp.int32))
-        return out
+        with span("split.edge_step", pos=int(pos[0])):
+            tok = jnp.asarray(tok, jnp.int32)
+            return bank.timed_call(
+                bank.cache_key("edge_step", self.split, self.edge_mp,
+                               tok.shape[0], 1),
+                bank._fn("edge_step", self.split, self.edge_mp),
+                params, tok, cache0, jnp.asarray(pos, jnp.int32))
 
     def stream_step(self, engine, req, cache, payload, scales, pos: int):
         """One streamed-decode cloud turn through ``engine``'s single-slot
@@ -809,7 +816,6 @@ class SplitRunner:
         without a short seq axis (recurrent state) pass through."""
         import jax
         import jax.numpy as jnp
-        template = self.bank._cache_template(stage, self.split, 1, length)
 
         def pad(leaf, t):
             if leaf.shape == t.shape:
@@ -817,7 +823,9 @@ class SplitRunner:
             pads = [(0, ts - ls) for ls, ts in zip(leaf.shape, t.shape)]
             return jnp.pad(leaf, pads)
 
-        return jax.tree.map(pad, cache, template)
+        with span("split.pad_cache", stage=stage):
+            template = self.bank._cache_template(stage, self.split, 1, length)
+            return jax.tree.map(pad, cache, template)
 
     # ------------------------------------------------------ pipelined decode
     def decode_pipeline(self, mesh, num_microbatches: int, prompt_len: int,

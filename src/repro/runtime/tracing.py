@@ -37,11 +37,42 @@ pre-tracing path (telemetry-equality regression test).
 against the trace-event schema (required fields, non-negative durations,
 per-track monotonic non-overlapping spans, minimum track-type coverage) —
 the CI smoke runs it on every topology trace artifact.
+
+Program spans on the wall clock are a separate, thin layer: :func:`span`
+opens a ``jax.profiler.TraceAnnotation`` named ``repro/<name>``.  Nothing
+is recorded unless a profiler is collecting (``jax.profiler.start_trace``);
+then each span lands in the same ``.xplane.pb`` as the device's programs
+and kernels, on the same clock, with its args as event stats.  The served
+path opens these, each child inside its parent on one thread:
+
+  ``split.edge_half``   rows        ``SplitRunner.edge_half``: the jitted
+                                    edge half with its eager pad and slices
+  ``split.cloud_half``  rows        ``SplitRunner.cloud_half``
+  ``split.edge_step``   pos         ``SplitRunner.edge_step`` (first row's)
+  ``split.pad_cache``   stage       ``SplitRunner.pad_decode_cache``
+  ``engine.admit``      uid, rows   ``ServingEngine.submit*``: slot write and
+                                    first-token sampling
+  ``engine.write_slot`` slot        the pool write of one request's caches
+  ``engine.sample``     uid         eager first-token sampling, ending in a
+                                    host read of the token
+  ``engine.step``       active,     ``ServingEngine.step``: slots holding a
+                        slots       request, of ``max_batch``
+  ``engine.stream_step`` uid, pos   ``ServingEngine.stream_step``
+  ``dispatch``          kind[, miss] the call of one jitted program (bank
+                                    key kind, or ``engine_step`` /
+                                    ``engine_stream_step``); ``miss`` is 1
+                                    on the bank's first call of a shape, whose
+                                    trace and compile run inside the span
+  ``sync``              -           the host read of a step's sampled tokens
+
+Span args are values already on the host; no span reads the device.
 """
 from __future__ import annotations
 
 import json
 from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 TRACE_SCHEMA_VERSION = 1
 
@@ -191,6 +222,15 @@ class _NullTracer(Tracer):
 
 
 NULL_TRACER = _NullTracer()
+
+
+SPAN_PREFIX = "repro/"
+
+
+def span(name: str, **args) -> TraceAnnotation:
+    """A program span on the profiler's clock (see the module docstring);
+    about a microsecond when no profiler is collecting."""
+    return TraceAnnotation(SPAN_PREFIX + name, **args)
 
 
 # ---------------------------------------------------------------------------

@@ -79,6 +79,14 @@ def _write_slot_jit(pool, new, slot):
     return jax.tree.map(copy, pool, new)
 
 
+def _span(name: str, **args):
+    """``repro.runtime.tracing.span``, imported on first use: the runtime
+    package builds on this module, so the engine does not import it at
+    load time."""
+    from repro.runtime.tracing import span
+    return span(name, **args)
+
+
 # decode_fn -> jitted (decode + in-graph sampling) step, shared by every
 # engine using the same decode closure (e.g. all engines of one bank split)
 _STEP_FNS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
@@ -192,8 +200,8 @@ class ServingEngine:
                       max_new_tokens=max_new_tokens, temperature=temperature,
                       eos_id=eos_id, record_logits=record_logits)
         self._uid += 1
-        slot = self._free_slot()
-        self._prefill_into(slot, req)
+        with _span("engine.admit", uid=req.uid, rows=len(req.prompt)):
+            self._prefill_into(self._free_slot(), req)
         return req
 
     def submit_prefilled(self, prompt_len: int, caches, last_logits,
@@ -210,14 +218,15 @@ class ServingEngine:
                       max_new_tokens=max_new_tokens, temperature=temperature,
                       eos_id=eos_id, record_logits=record_logits)
         self._uid += 1
-        slot = self._free_slot()
-        self._write_slot(slot, caches)
-        self.positions[slot] = prompt_len
-        self.active[slot] = req
-        last_logits = jnp.asarray(last_logits)
-        if req.record_logits:
-            req.logits_history.append(jax.device_get(last_logits))
-        self._emit(slot, req, self._sample(last_logits, req))
+        with _span("engine.admit", uid=req.uid, rows=prompt_len):
+            slot = self._free_slot()
+            self._write_slot(slot, caches)
+            self.positions[slot] = prompt_len
+            self.active[slot] = req
+            last_logits = jnp.asarray(last_logits)
+            if req.record_logits:
+                req.logits_history.append(jax.device_get(last_logits))
+            self._emit(slot, req, self._sample(last_logits, req))
         return req
 
     def submit_streamed(self, prompt_len: int, last_logits,
@@ -234,10 +243,11 @@ class ServingEngine:
                       max_new_tokens=max_new_tokens, temperature=temperature,
                       eos_id=eos_id, record_logits=record_logits)
         self._uid += 1
-        last_logits = jnp.asarray(last_logits)
-        if req.record_logits:
-            req.logits_history.append(jax.device_get(last_logits))
-        tok = self._sample(last_logits, req)
+        with _span("engine.admit", uid=req.uid, rows=prompt_len):
+            last_logits = jnp.asarray(last_logits)
+            if req.record_logits:
+                req.logits_history.append(jax.device_get(last_logits))
+            tok = self._sample(last_logits, req)
         req.generated.append(tok)
         if (req.eos_id is not None and tok == req.eos_id) or \
                 req.max_new_tokens <= 1:
@@ -250,19 +260,21 @@ class ServingEngine:
         shared compiled cloud step (one dispatch: restore + layers [split, N)
         + sampling) and return ``(token, new_cache)``."""
         assert self._stream_step is not None, "engine built without stream_fn"
-        toks, row, cache, self.key = self._dispatch(
-            "engine_stream_step", self._stream_step,
-            self.params, jnp.asarray(payload), jnp.asarray(scales), cache,
-            jnp.asarray([pos], jnp.int32), self.key,
-            jnp.asarray([req.temperature], jnp.float32))
-        tok = int(jax.device_get(toks)[0])
-        if req.record_logits:
-            req.logits_history.append(np.asarray(jax.device_get(row))[0])
-        req.generated.append(tok)
-        self.decode_steps += 1
-        if (req.eos_id is not None and tok == req.eos_id) or \
-                len(req.generated) >= req.max_new_tokens:
-            req.done = True
+        with _span("engine.stream_step", uid=req.uid, pos=pos):
+            toks, row, cache, self.key = self._dispatch(
+                "engine_stream_step", self._stream_step,
+                self.params, jnp.asarray(payload), jnp.asarray(scales), cache,
+                jnp.asarray([pos], jnp.int32), self.key,
+                jnp.asarray([req.temperature], jnp.float32))
+            with _span("sync"):
+                tok = int(jax.device_get(toks)[0])
+            if req.record_logits:
+                req.logits_history.append(np.asarray(jax.device_get(row))[0])
+            req.generated.append(tok)
+            self.decode_steps += 1
+            if (req.eos_id is not None and tok == req.eos_id) or \
+                    len(req.generated) >= req.max_new_tokens:
+                req.done = True
         return tok, cache
 
     @property
@@ -313,55 +325,64 @@ class ServingEngine:
             self.active[slot] = None
 
     def _dispatch(self, kind: str, fn, *args):
-        """Run a fused step, optionally through the wall-clock profiler."""
-        if self._profiler is None:
-            return fn(*args)
-        return self._profiler.timed((kind,) + self._profile_key, fn, *args)
+        """Run a fused step inside a ``dispatch`` span, optionally through
+        the wall-clock profiler."""
+        with _span("dispatch", kind=kind):
+            if self._profiler is None:
+                return fn(*args)
+            return self._profiler.timed((kind,) + self._profile_key, fn,
+                                        *args)
 
     def _write_slot(self, slot: int, req_cache):
-        self.cache = _write_slot_jit(self.cache, req_cache, jnp.int32(slot))
+        with _span("engine.write_slot", slot=slot):
+            self.cache = _write_slot_jit(self.cache, req_cache,
+                                         jnp.int32(slot))
 
     def _decode_fn(self, params, tokens, caches, pos):
         return M.forward_decode(params, self.built, tokens, caches, pos,
                                 self.pctx)
 
     def _sample(self, logits, req: Request) -> int:
-        if req.temperature <= 0:
-            return int(jnp.argmax(logits))
-        self.key, sub = jax.random.split(self.key)
-        return int(jax.random.categorical(sub, logits / req.temperature))
+        with _span("engine.sample", uid=req.uid):
+            if req.temperature <= 0:
+                return int(jnp.argmax(logits))
+            self.key, sub = jax.random.split(self.key)
+            return int(jax.random.categorical(sub, logits / req.temperature))
 
     def step(self):
         """One batched decode step over all active slots: a single jitted
         dispatch (forward + sampling) and a single host sync for the
         sampled tokens."""
-        if not any(r is not None for r in self.active):
+        active = self.num_active
+        if not active:
             return
-        # .copy() is load-bearing: on the CPU backend jnp.asarray can alias
-        # the numpy buffer zero-copy, and the in-place `positions[i] += 1`
-        # below would race with the still-dispatching decode (observed as a
-        # rare wrong-slot cache write under load)
-        pos = jnp.asarray(self.positions.copy())
-        toks, logits, self.cache, self.key = self._dispatch(
-            "engine_step", self._step,
-            self.params, jnp.asarray(self._last.copy()), self.cache, pos,
-            self.key, jnp.asarray(self._temps.copy()))
-        toks_host = np.asarray(jax.device_get(toks))       # the one host sync
-        logits_host = None
-        self.decode_steps += 1
-        for i, r in enumerate(self.active):
-            if r is None:
-                continue
-            self.positions[i] += 1
-            tok = int(toks_host[i])
-            self._last[i, 0] = tok
-            if r.record_logits:
-                if logits_host is None:     # already computed; copy-only
-                    logits_host = np.asarray(jax.device_get(logits))
-                r.logits_history.append(logits_host[i])
-            r.generated.append(tok)
-            if (r.eos_id is not None and tok == r.eos_id) or \
-                    len(r.generated) >= r.max_new_tokens or \
-                    self.positions[i] >= self.max_len - 1:
-                r.done = True
-                self.active[i] = None
+        with _span("engine.step", active=active, slots=self.max_batch):
+            # .copy() is load-bearing: on the CPU backend jnp.asarray can
+            # alias the numpy buffer zero-copy, and the in-place
+            # `positions[i] += 1` below would race with the still-dispatching
+            # decode (observed as a rare wrong-slot cache write under load)
+            pos = jnp.asarray(self.positions.copy())
+            toks, logits, self.cache, self.key = self._dispatch(
+                "engine_step", self._step,
+                self.params, jnp.asarray(self._last.copy()), self.cache, pos,
+                self.key, jnp.asarray(self._temps.copy()))
+            with _span("sync"):                        # the one host sync
+                toks_host = np.asarray(jax.device_get(toks))
+            logits_host = None
+            self.decode_steps += 1
+            for i, r in enumerate(self.active):
+                if r is None:
+                    continue
+                self.positions[i] += 1
+                tok = int(toks_host[i])
+                self._last[i, 0] = tok
+                if r.record_logits:
+                    if logits_host is None:     # already computed; copy-only
+                        logits_host = np.asarray(jax.device_get(logits))
+                    r.logits_history.append(logits_host[i])
+                r.generated.append(tok)
+                if (r.eos_id is not None and tok == r.eos_id) or \
+                        len(r.generated) >= r.max_new_tokens or \
+                        self.positions[i] >= self.max_len - 1:
+                    r.done = True
+                    self.active[i] = None

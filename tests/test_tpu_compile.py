@@ -82,7 +82,9 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = _kernel_cases()[name]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
-    assert "tpu_custom_call" in _compile_text(jax.jit(fn), *args)
+    text = _compile_text(jax.jit(fn), *args)
+    assert "tpu_custom_call" in text
+    _assert_named(_kernel_instructions(text), name)
 
 
 @pytest.fixture(scope="module")
@@ -123,3 +125,64 @@ def test_bank_half_compiles_for_v5e(bank_runner, one_chip, half):
                 sds((), jnp.int32))
     text = _compile_text(bank._fn(half, 1), params, *args)
     assert "tpu_custom_call" in text
+
+
+
+# each kernel's custom call, by the name of its wrapper in kernels/ops.py
+KERNEL_NAMES = {"reduce_quant": "butterfly_reduce_quant",
+                "reduce_quant_bincount": "butterfly_reduce_quant_bincount",
+                "dequant_restore": "butterfly_dequant_restore",
+                "restore_norm_bf16": "butterfly_restore_norm",
+                "restore_norm_f32": "butterfly_restore_norm"}
+
+
+def _kernel_instructions(text: str) -> list:
+    """Names of the Mosaic custom calls in a compiled HLO text."""
+    return [line.split(" = ", 1)[0].split()[-1].lstrip("%")
+            for line in text.splitlines() if "tpu_custom_call" in line]
+
+
+def _assert_named(found: list, name: str) -> None:
+    """The trace names a kernel's op after its HLO instruction: each
+    custom call found carries the name of the kernel's ops.py wrapper."""
+    want = KERNEL_NAMES[name]
+    assert found, "no Mosaic kernel in the compiled program"
+    assert all(n == want or n.startswith(want + ".") for n in found), found
+
+
+def _raw_kernel(name):
+    """The ``pallas_call`` itself, without the ops.py wrapper's jit, at the
+    shapes of :func:`_kernel_cases` in tiles of 128 rows (a tile that fits
+    VMEM at every output dtype; the wrapper picks its own)."""
+    from repro.kernels import butterfly_kernel as bk
+    return {
+        "reduce_quant": functools.partial(bk.butterfly_reduce_quant_kernel,
+                                          block_t=128),
+        "reduce_quant_bincount": functools.partial(
+            bk.butterfly_reduce_quant_bincount_kernel, block_t=128),
+        "dequant_restore": functools.partial(
+            bk.butterfly_dequant_restore_kernel, out_dtype=jnp.bfloat16,
+            block_t=128),
+        "restore_norm_bf16": lambda c, s, w, n: (
+            bk.butterfly_dequant_restore_norm_kernel(
+                c, s, w, n.reshape(1, -1), out_dtype=jnp.bfloat16,
+                block_t=128)),
+        "restore_norm_f32": lambda c, s, w, n: (
+            bk.butterfly_dequant_restore_norm_kernel(
+                c, s, w, n.reshape(1, -1), block_t=128)),
+    }[name]
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
+def test_kernel_keeps_its_name_in_another_program(one_chip, name):
+    """Compiled straight into another jitted function, without its ops.py
+    wrapper's jit, a kernel still carries the wrapper's name."""
+    _, shapes = _kernel_cases()[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    kernel = _raw_kernel(name)
+
+    def wire_probe(*a):
+        return jax.tree.map(lambda y: y * 1, kernel(*a))
+    _assert_named(_kernel_instructions(
+        _compile_text(jax.jit(wire_probe), *args)), name)
