@@ -168,9 +168,11 @@ def _range_spans(segments: Sequence[Segment], lo: int, hi: int):
 
 
 def range_segments(segments: Sequence[Segment], lo: int, hi: int) -> List[Segment]:
-    """Segmentation of the flat layer range [lo, hi) of a full stage; the
-    structure matches what :func:`slice_stage_params` produces, so cache
-    templates built from it line up with the sliced params."""
+    """Segmentation of the flat layer range [lo, hi) of a full stage, one
+    segment per span of :func:`_range_spans`: the structure of
+    :func:`apply_layer_range`'s range cache (and of what
+    :func:`slice_stage_params` produces), so cache templates built from it
+    line up with both."""
     out: List[Segment] = []
     for span in _range_spans(segments, lo, hi):
         if span[0] == "slice":
@@ -187,9 +189,12 @@ def slice_stage_params(segments: Sequence[Segment], stage_params, lo: int,
     """Restrict a stage's stacked params to flat layers [lo, hi).
 
     Returns ``(segments', params')`` where every leaf of ``params'`` is a
-    static slice of the corresponding full stacked leaf — under jit these are
-    views into the one shared backbone, so materializing every candidate
-    split never copies the parameter set."""
+    static slice ``a[r0:r1]`` of the corresponding full stacked leaf.  Each
+    slice is a real copy of its layers' weights, eagerly or under jit (XLA
+    materialises a slice that feeds a scan); it is kept for the decode
+    pipeline, which slices once, eagerly, outside its program.  Programs
+    that run a range of a shared stack use :func:`apply_layer_range`, which
+    reads the stacked leaves in place."""
     out_segs: List[Segment] = []
     out_params = []
     for span in _range_spans(segments, lo, hi):
@@ -209,18 +214,49 @@ def slice_stage_params(segments: Sequence[Segment], stage_params, lo: int,
 def apply_layer_range(segments: Sequence[Segment], stage_params, x, lo: int,
                       hi: int, *, cfg, pctx, mode, range_cache, pos,
                       enc_out=None, shared_params=None, use_kernel=False,
-                      causal=True, first_h=None, overlap_psum=False):
-    """Run flat layers [lo, hi) of a full stacked stage.  ``range_cache``
-    must be structured per :func:`range_segments` (see init_stage_cache).
-    ``first_h`` feeds a pre-computed norm1 output (the fused restore+norm
-    kernel) to layer ``lo``; ``overlap_psum`` defers each dense layer's MLP
-    psum into the next layer (see :func:`apply_layer`)."""
-    segs, params = slice_stage_params(segments, stage_params, lo, hi)
-    return apply_stage(segs, params, x, cfg=cfg, pctx=pctx, mode=mode,
-                       stage_cache=range_cache, pos=pos, enc_out=enc_out,
-                       shared_params=shared_params, use_kernel=use_kernel,
-                       causal=causal, first_h=first_h,
-                       overlap_psum=overlap_psum)
+                      causal=True):
+    """Run flat layers [lo, hi) of a full stacked stage, reading each
+    layer's weights in place.  Every span of :func:`_range_spans` scans its
+    repeat indices over the full stacked leaves of its segment (closed over,
+    so they are loop-invariant operands of the while loop) and the body
+    takes repeat ``i`` by dynamic index: no range of the stack is copied
+    out.  A peel scans its one index over the one layer's leaves the same
+    way.  ``range_cache`` must be structured per :func:`range_segments`
+    (see init_stage_cache); it is scanned as ``xs``.  Same layers, weights
+    and order as :func:`slice_stage_params` followed by
+    :func:`apply_stage`."""
+    kw = dict(cfg=cfg, pctx=pctx, mode=mode, pos=pos, enc_out=enc_out,
+              shared_params=shared_params, use_kernel=use_kernel,
+              causal=causal)
+    aux_total = jnp.zeros((2,), jnp.float32)
+    new_caches = []
+    for k, span in enumerate(_range_spans(segments, lo, hi)):
+        if span[0] == "slice":
+            _, si, r0, r1 = span
+            seg, unit_params = segments[si], stage_params[si]
+        else:
+            _, si, r0, upos = span
+            r1 = r0 + 1
+            seg = Segment(unit=(segments[si].unit[upos],), repeats=1)
+            unit_params = [stage_params[si][upos]]
+
+        def body(carry, xs, seg=seg, unit_params=unit_params):
+            xc, aux_sum = carry
+            i, unit_cache = xs
+            layer = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+                unit_params)
+            xc, aux_sum, _, nc = _apply_unit(seg, layer, unit_cache, xc,
+                                             aux_sum, None, **kw)
+            return (xc, aux_sum), nc
+
+        cache = None if range_cache is None else range_cache[k]
+        (x, aux), nc = jax.lax.scan(
+            body, (x, jnp.zeros((2,), jnp.float32)),
+            (jnp.arange(r0, r1), cache), unroll=_scan_unroll(r1 - r0))
+        new_caches.append(nc)
+        aux_total = aux_total + aux
+    return x, new_caches, aux_total
 
 
 def first_layer_norm1(segments: Sequence[Segment], stage_params, lo: int = 0):

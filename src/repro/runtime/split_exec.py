@@ -9,10 +9,12 @@ GPU-class cloud over 3G with reproducible traces.
 
 The cloud hosts the paper's "M partitioned models" (Sec. III-C) as ONE
 shared backbone parameter tree: :class:`SplitModelBank` initialises the
-model once and every candidate split's edge/cloud halves slice the stacked
-layer params in-graph (``models/transformer.slice_stage_params``), so bank
-memory stays O(1) in the number of hosted splits and only the tiny
-per-split butterfly projections are materialised per candidate.
+model once and every candidate split's edge/cloud halves read their layer
+range of the stacked params in place, by index inside the layer scan
+(``models/transformer.apply_layer_range``), so bank memory stays O(1) in
+the number of hosted splits, no program copies its range out of the stack,
+and only the tiny per-split butterfly projections are materialised per
+candidate.
 :class:`SplitRunner` is a thin facade over the bank's compile cache: jitted
 edge/cloud/prefill/decode functions are keyed on ``(kind, split)`` with
 bucket-padded ``(B, S)`` shapes, so a candidate sweep re-uses executables
@@ -184,7 +186,7 @@ class CostModel:
 
 
 # ---------------------------------------------------------------------------
-# real numerics: one shared backbone, per-split views
+# real numerics: one shared backbone, per-split layer ranges
 # ---------------------------------------------------------------------------
 
 
@@ -199,8 +201,11 @@ class SplitModelBank:
     """One backbone parameter tree serving every candidate split.
 
     The paper's server hosts M partitioned models and the selection phase
-    picks among them; here the M models are in-graph slices of a single
-    stacked parameter set, so materialising more candidates costs only the
+    picks among them; here the M models are layer ranges of a single
+    stacked parameter set.  Each jitted program reads its range in place:
+    its layer scan takes layer ``i`` of the full stacked leaves by dynamic
+    index (``models/transformer.apply_layer_range``), so no call copies the
+    range's weights, and materialising more candidates costs only the
     per-split butterfly projections (d*d_r + d_r*d params each) plus compile
     cache entries — not O(num_layers) full parameter copies.
 
@@ -422,8 +427,8 @@ class SplitModelBank:
         return jax.tree.map(cut, cache, template)
 
     def engine_stages(self, split: int):
-        """Per-stage segmentations matching the range-sliced param views
-        (the ServingEngine's cache-pool template for this split)."""
+        """Per-stage segmentations of this split's two layer ranges (the
+        ServingEngine's cache-pool template for this split)."""
         segs = list(self.built.stages[0])
         return [self._tfm.range_segments(segs, 0, split),
                 self._tfm.range_segments(segs, split,
@@ -835,10 +840,12 @@ class SplitRunner:
         """Multi-token greedy decode over a ``(pod, ...)`` mesh through this
         split: ``serving.pipeline.make_decode_pipeline``'s microbatch
         rotation (or its serial ping-pong reference with
-        ``pipelined=False``) running the bank's shared backbone slices.
-        Returns ``run(tokens) -> (num_microbatches * microbatch,
-        new_tokens)`` greedy ids.  The compiled fn + split-view params are
-        cached in the bank's compile cache under the wire signature."""
+        ``pipelined=False``) over copies of this split's two layer ranges,
+        sliced once, eagerly, out of the bank's shared backbone
+        (``slice_stage_params``).  Returns ``run(tokens) ->
+        (num_microbatches * microbatch, new_tokens)`` greedy ids.  The
+        compiled fn + sliced params are cached in the bank's compile cache
+        under the wire signature."""
         import jax
         bank = self.bank
         assert bank.wire_mode in ("int8", "int4", "entropy"), \

@@ -1,12 +1,13 @@
 """Ahead-of-time compiles for a described TPU v5e at qwen3-8b's widths
 (d=4096, d_r=128): the main-path Pallas kernels and the split bank's jitted
-edge and cloud halves.  Nothing runs; the TPU compiler refuses here what the
+programs.  Nothing runs; the TPU compiler refuses here what the
 chip would refuse (VMEM overflow, unaligned tiles), and interpret-mode tests
 cannot see either.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library."""
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -87,27 +88,32 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     _assert_named(_kernel_instructions(text), name)
 
 
-@pytest.fixture(scope="module")
-def bank_runner(one_chip):
-    """A 2-layer slice of full-width qwen3-8b in its own dtype (bf16),
-    split after layer 1, with shapes in place of weights: the bank's init
-    is swapped for its ``jax.eval_shape`` so nothing is allocated."""
+def _shapes_bank(one_chip, layers: int, split: int):
+    """Full-width qwen3-8b cut to ``layers`` layers in its own dtype (bf16),
+    with shapes in place of weights (the bank's init is swapped for its
+    ``jax.eval_shape`` so nothing is allocated), and the params of the
+    runner for ``split`` as shapes on the described chip."""
     import dataclasses
 
     from repro.configs import get_config
     from repro.models import model as M
     from repro.runtime.split_exec import SplitModelBank
-    cfg = dataclasses.replace(get_config("qwen3-8b"), num_layers=2)
+    cfg = dataclasses.replace(get_config("qwen3-8b"), num_layers=layers)
     with pytest.MonkeyPatch.context() as mp:
         real_init = M.init_model
         mp.setattr(M, "init_model", lambda key, built: (jax.eval_shape(
             lambda k: real_init(k, built)[0], key), None))
         bank = SplitModelBank(cfg, d_r=D_R)
-    runner = bank.runner(1)
     params = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        runner.params)
+        bank.runner(split).params)
     return bank, params
+
+
+@pytest.fixture(scope="module")
+def bank_runner(one_chip):
+    """A 2-layer slice of qwen3-8b, split after layer 1."""
+    return _shapes_bank(one_chip, 2, 1)
 
 
 @pytest.mark.parametrize("half", ["edge", "cloud"])
@@ -125,6 +131,65 @@ def test_bank_half_compiles_for_v5e(bank_runner, one_chip, half):
                 sds((), jnp.int32))
     text = _compile_text(bank._fn(half, 1), params, *args)
     assert "tpu_custom_call" in text
+
+
+STACK, SPLIT = 4, 2
+
+
+@pytest.fixture(scope="module")
+def stack_bank(one_chip):
+    """A stack of 4 layers of qwen3-8b, split after layer 2: both ranges
+    are proper two-layer sub-ranges of the one stacked backbone, as the
+    served cells' ranges are."""
+    return _shapes_bank(one_chip, STACK, SPLIT)
+
+
+def _program_args(bank, program, sds):
+    """Arguments after ``params`` of each bank program: one 128-token
+    prompt, or one decode step of 4 slots over 128-long caches."""
+    S, slots = 128, 4
+
+    def cache(stage, b):
+        return jax.tree.map(lambda t: sds(t.shape, t.dtype),
+                            bank._cache_template(stage, SPLIT, b, S))
+
+    payload = (sds((1, S, D_R), jnp.int8), sds((1, S, 1), jnp.float32))
+    row = (sds((1, 1, D_R), jnp.int8), sds((1, 1, 1), jnp.float32))
+    return {
+        "edge": (sds((1, S), jnp.int32),),
+        "cloud": payload + (sds((), jnp.int32),),
+        "prefill": (sds((1, S), jnp.int32), sds((), jnp.int32)),
+        "decode": (sds((slots, 1), jnp.int32), [cache(0, slots),
+                                                cache(1, slots)],
+                   sds((slots,), jnp.int32)),
+        "edge_step": (sds((1, 1), jnp.int32), cache(0, 1),
+                      sds((1,), jnp.int32)),
+        "cloud_step": row + (cache(1, 1), sds((1,), jnp.int32)),
+    }[program]
+
+
+@pytest.mark.parametrize("program", ["edge", "cloud", "prefill", "decode",
+                                     "edge_step", "cloud_step"])
+def test_bank_program_reads_stacked_weights_in_place(stack_bank, one_chip,
+                                                     program):
+    """Each bank program scans its layer range over the whole stacked
+    leaves: the compiler keeps less temp than one range's weights (a copy
+    of the range would need all of it), and no slice reads a stacked
+    weight parameter."""
+    bank, params = stack_bank
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(bank._fn(program, SPLIT)).lower(
+        params, *_program_args(bank, program, sds)).compile()
+    stack_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(params["stages"][0]))
+    range_bytes = stack_bytes * SPLIT // STACK    # both ranges: 2 of 4 layers
+    assert compiled.memory_analysis().temp_size_in_bytes < range_bytes
+    weight_slices = re.findall(
+        r"\bslice(?:-start)?\((%params__stages\S*?)[,)]", compiled.as_text())
+    assert not weight_slices, weight_slices
 
 
 
