@@ -3,12 +3,13 @@ butterfly wire, and its lower-precision control.
 
 It imports nothing of the program.  Its weights are the benchmark's own
 (``weights.py``), drawn again from the seed one layer at a time and held in
-float32, so that it fits on the chip after the program has been freed.  It
-follows the published Qwen3 layer: pre-norm RMSNorm with a gain ``1 + w``,
-GQA attention with per-head RMSNorm on queries and keys and split-half
-rotary embeddings, a SwiGLU MLP, an untied head.  After layer ``split`` the
-butterfly wire reduces the residual to ``d_r`` channels, quantizes each row
-to int8 by its absolute maximum, and restores it.
+float32, so that it fits on the chip after the program has been freed.  The
+layer equations are the architecture's (``forward`` in
+``arch/<model_type>.py``), built from the numerics here: matrix products
+that the control rounds to float8, RMSNorm with a gain ``1 + w``, split-half
+rotary embeddings, blocked causal attention, the wire and the head.  After
+layer ``split`` the butterfly wire reduces the residual to ``d_r`` channels,
+quantizes each row to int8 by its absolute maximum, and restores it.
 
 :func:`token_gaps` runs one teacher-forced pass over each prompt and its
 served tokens and returns, for each served token, how far its logit lies
@@ -25,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import spec
 import weights as W
 
 HI = jax.lax.Precision.HIGHEST
@@ -83,22 +85,6 @@ def attention(q, k, v):
     return jnp.concatenate(out, 0)
 
 
-def layer(x, lw, m, control: bool):
-    S = x.shape[0]
-    hd = m.head_dim
-    h = rms_norm(x, lw["norm1"], m.rms_eps)
-    q = _mm(h, lw["wq"], control).reshape(S, m.num_heads, hd)
-    k = _mm(h, lw["wk"], control).reshape(S, m.num_kv_heads, hd)
-    v = _mm(h, lw["wv"], control).reshape(S, m.num_kv_heads, hd)
-    q = rope(rms_norm(q, lw["q_norm"], m.rms_eps), m.rope_theta)
-    k = rope(rms_norm(k, lw["k_norm"], m.rms_eps), m.rope_theta)
-    x = x + _mm(attention(q, k, v).reshape(S, -1), lw["wo"], control)
-    h = rms_norm(x, lw["norm2"], m.rms_eps)
-    g = _mm(h, lw["w_gate"], control)
-    u = _mm(h, lw["w_up"], control)
-    return x + _mm(jax.nn.silu(g) * u, lw["w_down"], control)
-
-
 def wire(x, w_reduce, w_restore, bits: int, control: bool):
     """Reduce, quantize each row to ``bits`` by its absmax, restore."""
     qmax = 2 ** (bits - 1) - 1
@@ -112,8 +98,16 @@ def head_logits(x, final_norm, head, eps, control: bool):
     return _mm(rms_norm(x, final_norm, eps), head.T, control)
 
 
-def _f32(tree):
+def f32(tree):
     return jax.tree.map(lambda a: a.astype(jnp.float32), tree)
+
+
+def wire_rows(seed: int, m, xs, control: bool) -> list:
+    """The butterfly wire over each sequence's residual rows."""
+    fwire = jax.jit(lambda x, a, b: wire(x, a, b, m.wire_bits, control))
+    a = W.draw_global(seed, m, "w_reduce").astype(jnp.float32)
+    b = W.draw_global(seed, m, "w_restore").astype(jnp.float32)
+    return [fwire(x, a, b) for x in xs]
 
 
 def _served_rows(seed: int, m, seqs, control: bool) -> list:
@@ -129,16 +123,7 @@ def _served_rows(seed: int, m, seqs, control: bool) -> list:
         s[:len(p) + len(t) - 1] = np.concatenate([p, t[:-1]])
         xs.append(jnp.take(embed, jnp.asarray(s), axis=0).astype(jnp.float32))
     del embed
-    step = jax.jit(lambda x, lw: layer(x, lw, m, control))
-    fwire = jax.jit(lambda x, a, b: wire(x, a, b, m.wire_bits, control))
-    for li in range(m.num_layers):
-        lw = _f32(W.draw_layer(seed, m, li))
-        xs = [step(x, lw) for x in xs]
-        if li == m.split - 1:
-            a = W.draw_global(seed, m, "w_reduce").astype(jnp.float32)
-            b = W.draw_global(seed, m, "w_restore").astype(jnp.float32)
-            xs = [fwire(x, a, b) for x in xs]
-        del lw
+    xs = spec.arch_module(m.arch).forward(seed, m, xs, control)
     rows = []
     for x, (p, t) in zip(xs, seqs):
         idx = np.full((T,), len(p) - 1, np.int32)
@@ -153,7 +138,9 @@ def token_gaps(seed: int, m, seqs, *, control: bool = False) -> list:
     logit minus its logit of the served token (``control=False``), or of
     the token that the float8 control ranks first at that position, on the
     same prompt and served tokens (``control=True``)."""
-    head = W.draw_global(seed, m, "head").astype(jnp.float32)
+    tied = "head" not in W.global_leaves(m)
+    head = W.draw_global(seed, m, "embed" if tied else "head").astype(
+        jnp.float32)
     fnorm = W.draw_global(seed, m, "final_norm").astype(jnp.float32)
     ref_fn = jax.jit(head_logits, static_argnums=(3, 4))
     ref = [np.asarray(ref_fn(r, fnorm, head, m.rms_eps, False),

@@ -139,14 +139,8 @@ class WindowEvents:
 
 # -------------------------------------------------------------------- build
 def program_config(m):
-    from repro.configs.base import ModelConfig
-    return ModelConfig(name=m.name, arch_type="dense",
-                       num_layers=m.num_layers, d_model=m.d_model,
-                       num_heads=m.num_heads, num_kv_heads=m.num_kv_heads,
-                       head_dim=m.head_dim, d_ff=m.d_ff, vocab_size=m.vocab,
-                       qk_norm=True, act="silu", rope_theta=m.rope_theta,
-                       rms_eps=m.rms_eps, tie_embeddings=False,
-                       dtype="bfloat16")
+    """The program's ``ModelConfig`` for dims ``m``, by their architecture."""
+    return spec.arch_module(m.arch).program_config(m)
 
 
 def build(cell, seed: int):
@@ -260,14 +254,24 @@ class Tracer:
         return t
 
 
+def token_gaps(reqs, seconds: float) -> list:
+    """Every gap between consecutive tokens that reached a client in the
+    window, in seconds."""
+    gaps = []
+    for r in reqs:
+        seen = [t for t in r.tok_times if t <= seconds]
+        gaps += [b - a for a, b in zip(seen, seen[1:])]
+    return gaps
+
+
 def end_to_end(reqs, seconds: float) -> dict:
     """The user-facing numbers over every request due in the window."""
-    ttft, gaps, tokens = [], [], 0
+    ttft, tokens = [], 0
     for r in reqs:
         seen = [t for t in r.tok_times if t <= seconds]
         ttft.append((seen[0] if seen else seconds) - r.due)
-        gaps += [b - a for a, b in zip(seen, seen[1:])]
         tokens += len(seen)
+    gaps = token_gaps(reqs, seconds)
     out = {"ttft_p90_ms": percentile(ttft, 90) * 1e3,
            "output_tokens_per_s": tokens / seconds}
     if gaps:
@@ -392,6 +396,11 @@ def serve_window(cell, seed: int, seconds: float, *, trace: bool = False,
         log(f"generator lag: p50 {percentile(lag, 50) * 1e3:.3f} ms, max "
             f"{max(lag) * 1e3:.3f} ms")
     log("client: " + ", ".join(f"{k} {v}" for k, v in client.items()))
+    gaps = token_gaps(reqs, seconds)
+    if gaps:
+        log(f"token gaps: {len(gaps)}, ms at percentiles " + ", ".join(
+            f"p{q} {percentile(gaps, q) * 1e3:.3f}"
+            for q in (50, 80, 90, 93, 95, 97, 99)))
     log("longest loop turns (s, at s, edge prefills, cloud prefills, "
         "decoding): " + "; ".join(
             f"{d:.4f} at {t:.3f}: {a}, {b}, {c}"

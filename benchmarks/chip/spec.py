@@ -1,51 +1,90 @@
 """What a cell is made of, read from files found by name.
 
 ``BENCHMARK.json`` (at the root of the checkout) names each cell's
-configuration and traffic mix.  The harness then reads, all under this
-directory:
+configuration and traffic mix.  The harness then reads, all under
+:data:`FILES` (this directory):
 
 - ``configs/<config>.json``: the model as it is run, in the keys of its
   published ``config.json``, with the butterfly split it is served with;
+- ``arch/<model_type>.py``: the architecture that the configuration's
+  ``model_type`` names (below);
 - ``traffic/<mix>.json``: arrivals, lengths, transport, link and slots;
 - ``checks/<cell>.json``: the limit that decides ``correct`` and the size of
   the sample it is judged on;
 - ``links.json`` and ``peaks.json``: the link model and the chip's peaks.
 
-A new cell adds files and a ``workloads`` entry; it edits none of these.
+A new cell adds files and a ``workloads`` entry; it edits none of these.  A
+configuration of an architecture that has no module yet brings
+``arch/<model_type>.py`` beside its configuration file.  A ``model_type``
+with no module is refused when its configuration loads.
+
+The interface of an architecture module, which the harness reaches through
+:func:`arch_module` and nothing else:
+
+``Dims``
+    A frozen, hashable dataclass of the model's dims (``weights`` keys its
+    compiled draws on it).  The harness reads only ``name``, ``arch`` (the
+    ``model_type``), ``num_layers``, ``d_model``, ``vocab``, ``rms_eps``,
+    ``split`` (the butterfly after this many layers), ``d_r`` and
+    ``wire_bits``; the rest are the module's own.
+``dims(name, config) -> Dims``
+    The dims of configuration file ``name``; raises ``ValueError`` for keys
+    the module cannot serve.
+``LEAF_IDS``
+    Leaf name -> key index, for every leaf of the layers and of the global
+    leaves.  Never renumber one: each seed would draw new weights.  Indices
+    14 and 15 are the butterfly's (``weights.BUTTERFLY_IDS``).
+``layer_leaves(m, layer) -> {name: shape}``
+    Leaves of layer ``layer``.  Layers may be of different kinds; each leaf
+    is stacked over the layers that have it, in layer order.
+``global_leaves(m) -> {name: shape}``
+    Leaves outside the layers, shared blocks among them.  They hold
+    ``embed`` (vocab, d) and ``final_norm`` (d,), and ``head`` (vocab, d)
+    unless the head is tied to ``embed``.  The butterfly's leaves are added
+    by ``weights``.  A leaf named ``*norm``, ``norm1`` or ``norm2`` is a gain
+    offset, ``embed`` and ``head`` are (vocab, d), and any other is an
+    (..., in, out) matrix (``weights._leaf``).
+``program_config(m)``
+    The program's ``repro.configs.base.ModelConfig`` for these dims.
+``program_tree(w, m)``
+    The program's parameter tree (``repro.models.model.init_model``'s
+    layout) from the drawn weights ``w``, sharing its arrays.
+``forward(seed, m, xs, control) -> xs``
+    The reference's pass over every layer, with the butterfly wire
+    (``reference.wire_rows``) after layer ``split - 1``: ``xs`` holds one
+    float32 (S, d) array of embedded tokens per sequence, the result the
+    residual after the last layer.  Whatever the architecture carries beside
+    the residual stays inside it.  Weights come from ``weights.draw_layer``
+    and ``draw_global``, numerics from ``reference``.
+``edge_prefill(m, seq)``, ``cloud_prefill(m, seq)``, ``edge_step(m, pos)``, \
+``cloud_step(m, pos)``, ``decode_token(m, pos)``
+    Model FLOPs of the programs the window drives (``work.py`` says what
+    they count); ``layer_params(m)`` too, where every layer is alike.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
+import re
+import sys
 from dataclasses import dataclass
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+FILES = HERE        # where configurations, architectures, traffic are found
 
-
-@dataclass(frozen=True)
-class Model:
-    """The dims the harness, the reference and the work counts use."""
-    name: str
-    num_layers: int
-    d_model: int
-    num_heads: int
-    num_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    rope_theta: float
-    rms_eps: float
-    split: int          # butterfly after this many layers
-    d_r: int
-    wire_bits: int
+INTERFACE = ("Dims", "dims", "LEAF_IDS", "layer_leaves", "global_leaves",
+             "program_config", "program_tree", "forward", "edge_prefill",
+             "cloud_prefill", "edge_step", "cloud_step", "decode_token")
+_ARCHS: dict = {}
 
 
 @dataclass(frozen=True)
 class Cell:
     name: str
     chips: int
-    model: Model
+    model: object       # an architecture module's Dims
     traffic: dict
     check: dict
 
@@ -55,23 +94,47 @@ def _json(path: Path) -> dict:
         return json.load(f)
 
 
-def model_from_config(name: str, c: dict) -> Model:
-    """Dims from a configuration file (published ``config.json`` keys)."""
-    if c.get("model_type") != "qwen3":
-        raise ValueError(f"{name}: model_type {c.get('model_type')!r} has no "
-                         f"mapping onto the program's dense stack")
-    if c.get("tie_word_embeddings", False) or c.get("hidden_act") != "silu" \
-            or c.get("attention_bias", False):
-        raise ValueError(f"{name}: only untied, silu, bias-free qwen3 stacks")
-    bf = c["butterfly"]
-    return Model(name=name, num_layers=c["num_hidden_layers"],
-                 d_model=c["hidden_size"],
-                 num_heads=c["num_attention_heads"],
-                 num_kv_heads=c["num_key_value_heads"],
-                 head_dim=c["head_dim"], d_ff=c["intermediate_size"],
-                 vocab=c["vocab_size"], rope_theta=float(c["rope_theta"]),
-                 rms_eps=float(c["rms_norm_eps"]), split=bf["after_layer"],
-                 d_r=bf["d_r"], wire_bits=bf["wire_bits"])
+def arch_module(model_type: str):
+    """The module of architecture ``model_type``: ``arch/<model_type>.py``
+    under :data:`FILES`, loaded once per path."""
+    path = FILES / "arch" / f"{model_type}.py"
+    if path in _ARCHS:
+        return _ARCHS[path]
+    if not isinstance(model_type, str) or \
+            not re.fullmatch(r"[A-Za-z0-9_]+", model_type) or \
+            not path.is_file():
+        raise ValueError(f"model_type {model_type!r} has no architecture "
+                         f"module: looked for {path}")
+    name = f"arch_{model_type}"
+    s = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(s)
+    sys.modules[name] = mod          # dataclasses look their module up
+    s.loader.exec_module(mod)
+    missing = [n for n in INTERFACE if not hasattr(mod, n)]
+    if missing:
+        del sys.modules[name]
+        raise ValueError(f"{path} lacks {', '.join(missing)} of the "
+                         f"architecture interface")
+    _ARCHS[path] = mod
+    return mod
+
+
+def __getattr__(name):
+    # ``Model``: the dims of the architecture the benchmark began with,
+    # under the name that tests and tools build them by
+    if name == "Model":
+        return arch_module("qwen3").Dims
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
+def model_from_config(name: str, c: dict):
+    """Dims from a configuration file (published ``config.json`` keys), by
+    the module of its ``model_type``."""
+    try:
+        mod = arch_module(c.get("model_type"))
+    except ValueError as e:
+        raise ValueError(f"{name}: {e}") from None
+    return mod.dims(name, c)
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
@@ -80,11 +143,11 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     if name not in cells:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
-    config = _json(HERE / "configs" / f"{w['config']}.json")
+    config = _json(FILES / "configs" / f"{w['config']}.json")
     return Cell(name=name, chips=w["chips"],
                 model=model_from_config(w["config"], config),
-                traffic=_json(HERE / "traffic" / f"{w['traffic']}.json"),
-                check=_json(HERE / "checks" / f"{name}.json"))
+                traffic=_json(FILES / "traffic" / f"{w['traffic']}.json"),
+                check=_json(FILES / "checks" / f"{name}.json"))
 
 
 def _metrics(kind: str, name: str, root: Path) -> list:

@@ -16,10 +16,11 @@ def test_layer_draws_match_the_whole_draw():
     w = W.draw(SEED, m)
     for li in range(m.num_layers):
         lw = W.draw_layer(SEED, m, li)
-        for name in W.LAYER_LEAVES:
+        assert lw.keys() == W.layer_leaves(m, li).keys()
+        for name in lw:
             assert np.array_equal(np.asarray(w["layers"][name][li]),
                                   np.asarray(lw[name])), (li, name)
-    for name in W.GLOBAL_LEAVES:
+    for name in W.global_leaves(m):
         assert np.array_equal(np.asarray(w[name]),
                               np.asarray(W.draw_global(SEED, m, name)))
     other = W.draw(SEED + 1, m)

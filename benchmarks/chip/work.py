@@ -7,57 +7,50 @@ elementwise work are left out: under 1% of a layer's matrix work at these
 widths.  Kernel counts are of the rows the kernel processes, as its HLO
 instruction in the trace gives them; its bytes come from the same
 instruction (``devtrace.kernel_shapes``).
+
+The model counts are the architecture's (``arch/<model_type>.py``); the
+wire kernels' counts are shared.
 """
 from __future__ import annotations
 
+import spec
+
+
+def _arch(m):
+    return spec.arch_module(m.arch)
+
 
 def layer_params(m) -> int:
-    """Weights of one layer's matrix multiplications."""
-    d, q, kv = m.d_model, m.num_heads * m.head_dim, m.num_kv_heads * \
-        m.head_dim
-    return d * q + 2 * d * kv + q * d + 3 * d * m.d_ff
-
-
-def _attn(m, layers: int, queries_keys: int) -> int:
-    """QK^T and PV over ``queries_keys`` (query, key) pairs per layer."""
-    return 4 * m.num_heads * m.head_dim * queries_keys * layers
-
-
-def _pairs(seq: int) -> int:
-    return seq * (seq + 1) // 2
+    """Weights of one layer's matrix multiplications, where every layer is
+    alike."""
+    return _arch(m).layer_params(m)
 
 
 def edge_prefill(m, seq: int) -> int:
     """Layers [0, split) over ``seq`` tokens and the reduction."""
-    return (2 * seq * layer_params(m) * m.split + _attn(m, m.split, _pairs(seq))
-            + 2 * seq * m.d_model * m.d_r)
+    return _arch(m).edge_prefill(m, seq)
 
 
 def cloud_prefill(m, seq: int) -> int:
     """Restoration, layers [split, L) over ``seq`` tokens and the head at the
     last position."""
-    n = m.num_layers - m.split
-    return (2 * seq * m.d_r * m.d_model + 2 * seq * layer_params(m) * n
-            + _attn(m, n, _pairs(seq)) + 2 * m.d_model * m.vocab)
+    return _arch(m).cloud_prefill(m, seq)
 
 
 def edge_step(m, pos: int) -> int:
-    """One streamed token on the edge: layers [0, split) and the reduction;
-    the token at position ``pos`` attends to ``pos + 1`` keys."""
-    return (2 * layer_params(m) * m.split + _attn(m, m.split, pos + 1)
-            + 2 * m.d_model * m.d_r)
+    """One streamed token on the edge at position ``pos``: layers
+    [0, split) and the reduction."""
+    return _arch(m).edge_step(m, pos)
 
 
 def cloud_step(m, pos: int) -> int:
     """One streamed token on the cloud: restoration, layers [split, L), head."""
-    n = m.num_layers - m.split
-    return (2 * m.d_r * m.d_model + 2 * layer_params(m) * n
-            + _attn(m, n, pos + 1) + 2 * m.d_model * m.vocab)
+    return _arch(m).cloud_step(m, pos)
 
 
 def decode_token(m, pos: int) -> int:
     """One token of the batched decode step: the whole model with the wire."""
-    return edge_step(m, pos) + cloud_step(m, pos)
+    return _arch(m).decode_token(m, pos)
 
 
 def reduce_quant(rows: int, d: int, d_r: int) -> int:
