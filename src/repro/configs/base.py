@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field, replace
-from typing import Callable, Optional
+from typing import Callable, Optional, Tuple
 
 # ---------------------------------------------------------------------------
 # sub-configs
@@ -37,6 +37,8 @@ class SSMConfig:
     conv_width: int = 4
     chunk_size: int = 256
     expand: int = 2                  # d_inner = expand * d_model
+    n_groups: int = 1                # B/C groups; head h reads group h // (H/G)
+    dt_min: float = 0.0              # floor of the softplus'd time step
 
 
 @dataclass(frozen=True)
@@ -82,7 +84,8 @@ class ModelConfig:
     vocab_size: int
     head_dim: Optional[int] = None   # default d_model // num_heads
     qk_norm: bool = False
-    act: str = "silu"                # silu -> SwiGLU, gelu -> GeGLU
+    act: str = "silu"                # silu -> SwiGLU, gelu -> GeGLU (tanh),
+                                     # gelu_exact -> GeGLU (erf)
     rope_theta: float = 1e6
     rms_eps: float = 1e-6
     logit_softcap: Optional[float] = None
@@ -92,7 +95,12 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     xlstm: Optional[XLSTMConfig] = None
-    hybrid_attn_every: Optional[int] = None  # zamba2: shared attn every N layers
+    # zamba2: the layers that invoke the shared attention+MLP blocks before
+    # their own mixer; invocation k uses block k % num_mem_blocks and its
+    # own rank-``adapter_rank`` LoRA on the MLP's gate/up projection
+    hybrid_layer_ids: Optional[Tuple[int, ...]] = None
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0
     # encoder-decoder (whisper)
     is_encdec: bool = False
     encoder_layers: int = 0
@@ -135,7 +143,7 @@ class ModelConfig:
         ssm = None
         if self.ssm is not None:
             ssm = replace(self.ssm, num_heads=4, head_dim=32, state_dim=16,
-                          chunk_size=32)
+                          chunk_size=32, n_groups=min(self.ssm.n_groups, 2))
         xl = None
         if self.xlstm is not None:
             xl = replace(self.xlstm, slstm_every=2, chunk_size=16)
@@ -156,7 +164,8 @@ class ModelConfig:
             vocab_size=min(self.vocab_size, 512),
             sliding_window=min(self.sliding_window, 64) if self.sliding_window else None,
             global_every=2 if self.global_every else None,
-            hybrid_attn_every=2 if self.hybrid_attn_every else None,
+            hybrid_layer_ids=(1,) if self.hybrid_layer_ids else None,
+            adapter_rank=min(self.adapter_rank, 8),
             moe=moe, ssm=ssm, xlstm=xl,
             encoder_layers=2 if self.is_encdec else 0,
             encoder_frames=16 if self.is_encdec else self.encoder_frames,

@@ -45,7 +45,8 @@ def mamba_layer_flops(cfg: ModelConfig, seq: int) -> float:
     s = cfg.ssm
     d = cfg.d_model
     d_inner = s.num_heads * s.head_dim
-    proj = 2 * seq * d * (2 * d_inner + 2 * s.state_dim + s.num_heads)
+    proj = 2 * seq * d * (2 * d_inner + 2 * s.n_groups * s.state_dim +
+                          s.num_heads)
     proj += 2 * seq * d_inner * d
     L = min(s.chunk_size, seq)
     ssd = 2 * seq * L * s.state_dim * 2 + 2 * seq * L * s.head_dim * s.num_heads
@@ -84,14 +85,48 @@ def layer_flops(cfg: ModelConfig, layer_idx: int, seq: int,
             f += moe_layer_flops(cfg, seq)
         return f
     if ldef.mixer == "mamba":
-        return mamba_layer_flops(cfg, seq)
+        f = mamba_layer_flops(cfg, seq)
+        if ldef.hybrid is not None:
+            f += hybrid_block_flops(cfg, seq, ldef.window, kv_len)
+        return f
     return xlstm_layer_flops(cfg, seq, ldef.mixer)
+
+
+def hybrid_block_flops(cfg: ModelConfig, seq: int, window: Optional[int] = None,
+                       kv_len: Optional[int] = None) -> float:
+    """One invocation of zamba2's shared block: attention over the 2d-wide
+    concatenation (d-wide out), the MLP with its LoRA adapter, and the
+    layer's d x d linear."""
+    d, hd, H = cfg.d_model, cfg.resolved_head_dim, cfg.num_heads
+    kv = kv_len if kv_len is not None else seq
+    eff = min(kv, window) if window else kv
+    proj = 2 * seq * hd * (2 * d * (H + 2 * cfg.num_kv_heads) + H * d)
+    attn = 2 * seq * eff * H * hd * 2
+    adapter = 2 * seq * cfg.adapter_rank * (d + 2 * cfg.d_ff)
+    return proj + attn + mlp_flops(d, cfg.d_ff, seq) + adapter + \
+        2 * seq * d * d
 
 
 def stack_flops(cfg: ModelConfig, seq: int, lo: int = 0, hi: Optional[int] = None,
                 long_mode: bool = False, kv_len: Optional[int] = None) -> float:
     hi = cfg.num_layers if hi is None else hi
     return sum(layer_flops(cfg, i, seq, long_mode, kv_len) for i in range(lo, hi))
+
+
+def id_width(vocab_size: int) -> int:
+    """Bytes of one token id on the wire: the narrowest integer that holds
+    every id (int16 where the vocabulary fits, else int32)."""
+    return 2 if vocab_size <= 2 ** 15 else 4
+
+
+def id_wire_bytes(cfg: ModelConfig, split: int, seq: int) -> float:
+    """Uplink bytes of the token ids that ride beside the codes when a layer
+    past ``split`` reads the token embeddings (zamba2's hybrid layers): one
+    id per position.  0 otherwise."""
+    ids = cfg.hybrid_layer_ids or ()
+    if not any(i >= split for i in ids):
+        return 0.0
+    return float(seq * id_width(cfg.vocab_size))
 
 
 def embed_flops(cfg: ModelConfig, seq: int) -> float:
@@ -108,15 +143,18 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> float:
     hd = cfg.resolved_head_dim
     d = cfg.d_model
     total = cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2)
-    shared_attn_counted = False
+    if cfg.hybrid_layer_ids is not None:
+        # the shared blocks once each, an adapter per invocation
+        H, K = cfg.num_heads, cfg.num_kv_heads
+        total += cfg.num_mem_blocks * (2 * d * hd * (H + 2 * K) + H * hd * d
+                                       + 3 * d * cfg.d_ff + 3 * d)
+        total += len(cfg.hybrid_layer_ids) * cfg.adapter_rank * \
+            (d + 2 * cfg.d_ff)
     for ldef in build_layer_defs(cfg):
         if ldef.mixer == "attn":
-            if not (ldef.shared and shared_attn_counted):
-                total += d * hd * (cfg.num_heads * 2 + cfg.num_kv_heads * 2)
-                if ldef.ffn == "mlp" or ldef.shared:
-                    total += 3 * d * cfg.d_ff
-                if ldef.shared:
-                    shared_attn_counted = True
+            total += d * hd * (cfg.num_heads * 2 + cfg.num_kv_heads * 2)
+            if ldef.ffn == "mlp":
+                total += 3 * d * cfg.d_ff
             if ldef.ffn == "moe":
                 m = cfg.moe
                 n_exp = m.top_k if active_only else m.num_experts
@@ -129,7 +167,10 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> float:
         elif ldef.mixer == "mamba":
             s = cfg.ssm
             din = s.num_heads * s.head_dim
-            total += d * (2 * din + 2 * s.state_dim + s.num_heads) + din * d
+            total += d * (2 * din + 2 * s.n_groups * s.state_dim +
+                          s.num_heads) + din * d
+            if ldef.hybrid is not None:
+                total += d * d                 # the layer's own linear
         elif ldef.mixer == "mlstm":
             din = 2 * d
             total += d * din * 2 + din * din * 3 + din * d

@@ -93,3 +93,37 @@ def wire_bytes(shape: tuple, bits: int, scale_dtype=jnp.float32) -> int:
     n = math.prod(shape)
     rows = n // shape[-1]
     return (n * bits + 7) // 8 + rows * scale_dtype_bytes(scale_dtype)
+
+
+@jax.tree_util.register_pytree_node_class
+class CodedRows:
+    """The edge's wire payload for a model whose layers past the split read
+    the token embeddings beside the residual (zamba2's hybrid layers): the
+    butterfly's codes and, beside them, the id of the token at each row.
+
+    A pytree, so it passes into and out of jitted programs as it is.  Slices
+    and ``shape`` follow the codes' leading (batch, position) axes, and
+    ``nbytes`` counts the codes and the ids, so a link charged by ``nbytes``
+    carries the ids too."""
+
+    def __init__(self, codes, ids):
+        self.codes, self.ids = codes, ids
+
+    def tree_flatten(self):
+        return (self.codes, self.ids), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+    @property
+    def shape(self):
+        return self.codes.shape
+
+    @property
+    def nbytes(self) -> int:
+        return self.codes.nbytes + self.ids.nbytes
+
+    def __getitem__(self, index):
+        """``index`` selects over the leading (batch, position) axes."""
+        return CodedRows(self.codes[index], self.ids[index])

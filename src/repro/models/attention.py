@@ -49,11 +49,16 @@ def _padded_heads(cfg: ModelConfig) -> int:
     return p
 
 
-def init_attention(key, cfg: ModelConfig, dtype, cross: bool = False):
+def init_attention(key, cfg: ModelConfig, dtype, cross: bool = False,
+                   d_in: Optional[int] = None):
+    """``d_in``: width of the projections' input, when it is not the
+    residual's (zamba2's shared block reads the residual concatenated with
+    the token embeddings); the output is ``d_model`` wide either way."""
     hd = cfg.resolved_head_dim
+    d_in = cfg.d_model if d_in is None else d_in
     kq, kk, kv, ko = jax.random.split(key, 4)
     n_pad = _padded_heads(cfg)
-    wq = dense_init(kq, cfg.d_model, n_pad * hd, dtype)
+    wq = dense_init(kq, d_in, n_pad * hd, dtype)
     wo = dense_init(ko, cfg.num_heads * hd, cfg.d_model, dtype,
                     scale=1.0 / (cfg.num_heads * hd))
     if n_pad != cfg.num_heads:
@@ -66,8 +71,8 @@ def init_attention(key, cfg: ModelConfig, dtype, cross: bool = False):
         wo = jnp.concatenate([wo, pad], axis=1).reshape(n_pad * hd, cfg.d_model)
     params = {
         "wq": wq,
-        "wk": dense_init(kk, cfg.d_model, cfg.num_kv_heads * hd, dtype),
-        "wv": dense_init(kv, cfg.d_model, cfg.num_kv_heads * hd, dtype),
+        "wk": dense_init(kk, d_in, cfg.num_kv_heads * hd, dtype),
+        "wv": dense_init(kv, d_in, cfg.num_kv_heads * hd, dtype),
         "wo": wo,
     }
     # Perf iteration (EXPERIMENTS.md section Perf pair 2): sharding the fused
@@ -81,9 +86,9 @@ def init_attention(key, cfg: ModelConfig, dtype, cross: bool = False):
     q_ok = (not head_aware) or n_pad % 16 == 0
     kv_ok = (not head_aware) or cfg.num_kv_heads % 16 == 0
     specs = {
-        "wq": dense_spec((cfg.d_model, n_pad * hd), 1 if q_ok else None),
-        "wk": dense_spec((cfg.d_model, cfg.num_kv_heads * hd), 1 if kv_ok else None),
-        "wv": dense_spec((cfg.d_model, cfg.num_kv_heads * hd), 1 if kv_ok else None),
+        "wq": dense_spec((d_in, n_pad * hd), 1 if q_ok else None),
+        "wk": dense_spec((d_in, cfg.num_kv_heads * hd), 1 if kv_ok else None),
+        "wv": dense_spec((d_in, cfg.num_kv_heads * hd), 1 if kv_ok else None),
         "wo": dense_spec((n_pad * hd, cfg.d_model), 0 if q_ok else None),
     }
     if cfg.qk_norm:
@@ -146,15 +151,16 @@ def _project_qkv(params, x, cfg: ModelConfig, positions, rope: bool = True):
     return q, k, v
 
 
-def _gqa_scores(q, k, cfg: ModelConfig):
-    """q: (B,S,N,hd) -> grouped (B,S,K,G,hd); scores (B,K,G,S,T)."""
+def _gqa_scores(q, k, cfg: ModelConfig, scale: Optional[float] = None):
+    """q: (B,S,N,hd) -> grouped (B,S,K,G,hd); scores (B,K,G,S,T), times
+    ``scale`` (default 1/sqrt(hd))."""
     B, S, N, hd = q.shape
     K = k.shape[2]
     G = N // K
     qg = q.reshape(B, S, K, G, hd)
     scores = jnp.einsum("bskgh,btkh->bkgst", qg.astype(jnp.float32),
                         k.astype(jnp.float32))
-    return scores * (1.0 / math.sqrt(hd))
+    return scores * (1.0 / math.sqrt(hd) if scale is None else scale)
 
 
 def _attend(scores, v, mask, dtype):
@@ -182,16 +188,18 @@ def causal_mask(S: int, T: int, offset: int = 0, window: Optional[int] = None):
 
 def attention_fullseq(params, x, *, cfg: ModelConfig, window: Optional[int],
                       positions=None, use_kernel: bool = False,
-                      causal: bool = True, rope: bool = True):
+                      causal: bool = True, rope: bool = True,
+                      scale: Optional[float] = None):
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.arange(S)[None, :]
     q, k, v = _project_qkv(params, x, cfg, positions, rope=rope)
     if use_kernel:
+        assert scale is None, "the flash kernel scales by 1/sqrt(hd)"
         from repro.kernels import ops as kops
         out = kops.flash_attention(q, k, v, causal=causal, window=window)
     else:
-        scores = _gqa_scores(q, k, cfg)
+        scores = _gqa_scores(q, k, cfg, scale)
         if causal:
             mask = causal_mask(S, S, window=window)[None, None, None]
         else:
@@ -207,7 +215,8 @@ def attention_fullseq(params, x, *, cfg: ModelConfig, window: Optional[int],
 
 
 def attention_decode(params, x, cache, cache_pos, *, cfg: ModelConfig,
-                     window: Optional[int], rope: bool = True):
+                     window: Optional[int], rope: bool = True,
+                     scale: Optional[float] = None):
     """x: (B,1,d). ``cache_pos`` — absolute position of the new token, either
     an int32 scalar (all sequences aligned: dry-run / batch decode) or an
     (B,) vector (ragged serving engine).  When ``window`` is set the cache
@@ -232,7 +241,7 @@ def attention_decode(params, x, cache, cache_pos, *, cfg: ModelConfig,
         b_idx = jnp.arange(B)
         k = cache["k"].at[b_idx, slots].set(k_new[:, 0].astype(cache["k"].dtype))
         v = cache["v"].at[b_idx, slots].set(v_new[:, 0].astype(cache["v"].dtype))
-    scores = _gqa_scores(q, k, cfg)                      # (B,K,G,1,T)
+    scores = _gqa_scores(q, k, cfg, scale)               # (B,K,G,1,T)
     idx = jnp.arange(T)[None, :]
     posb = positions                                      # (B,1)
     if window is not None:

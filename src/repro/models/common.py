@@ -125,6 +125,8 @@ def glu_act(gate: jax.Array, act: str) -> jax.Array:
         return jax.nn.silu(gate)
     if act == "gelu":
         return jax.nn.gelu(gate, approximate=True)
+    if act == "gelu_exact":
+        return jax.nn.gelu(gate, approximate=False)
     raise ValueError(f"unknown act {act}")
 
 

@@ -97,13 +97,9 @@ def init_model(key, built: BuiltModel):
         params["butterfly"], specs["butterfly"] = bf_lib.init_butterfly(
             next(keys), cfg.d_model, cfg.butterfly, dtype)
 
-    if cfg.hybrid_attn_every is not None:
-        # zamba2: one shared attention + mlp param set
-        from repro.models.common import init_mlp
-        pa, sa = attn_lib.init_attention(next(keys), cfg, dtype)
-        pm, sm = init_mlp(next(keys), cfg.d_model, cfg.d_ff, dtype)
-        params["shared_attn"] = {"mixer": pa, "ffn": pm}
-        specs["shared_attn"] = {"mixer": sa, "ffn": sm}
+    if cfg.hybrid_layer_ids is not None:
+        params["shared_attn"], specs["shared_attn"] = _init_shared_blocks(
+            keys, cfg, dtype)
 
     if cfg.is_encdec:
         enc_p, enc_s = [], []
@@ -116,6 +112,32 @@ def init_model(key, built: BuiltModel):
         specs["encoder"] = {"segments": enc_s, "final_norm": ns}
 
     return params, specs
+
+
+def _init_shared_blocks(keys, cfg: ModelConfig, dtype):
+    """zamba2: ``num_mem_blocks`` shared attention+MLP blocks (attention over
+    the residual concatenated with the token embeddings, 2 * d_model wide)
+    and one LoRA adapter on the MLP's gate/up projection per invocation."""
+    from repro.models.common import dense_init, init_mlp
+    d, r = cfg.d_model, cfg.adapter_rank
+    blocks, block_specs = [], []
+    for _ in range(cfg.num_mem_blocks):
+        pa, sa = attn_lib.init_attention(next(keys), cfg, dtype, d_in=2 * d)
+        pm, sm = init_mlp(next(keys), d, cfg.d_ff, dtype)
+        n_in, s_in = init_rms_norm(2 * d, dtype)
+        n_ff, s_ff = init_rms_norm(d, dtype)
+        blocks.append({"norm_in": n_in, "mixer": pa, "norm_ff": n_ff,
+                       "ffn": pm})
+        block_specs.append({"norm_in": s_in, "mixer": sa, "norm_ff": s_ff,
+                            "ffn": sm})
+    adapters, adapter_specs = [], []
+    for _ in cfg.hybrid_layer_ids:
+        ka, kb = jax.random.split(next(keys))
+        adapters.append({"a": dense_init(ka, d, r, dtype),
+                         "b": dense_init(kb, r, 2 * cfg.d_ff, dtype)})
+        adapter_specs.append({"a": P(None, None), "b": P(None, None)})
+    return ({"blocks": blocks, "adapters": adapters},
+            {"blocks": block_specs, "adapters": adapter_specs})
 
 
 def tp_param_specs(built: BuiltModel, *, with_butterfly: Optional[bool] = None):
@@ -141,9 +163,12 @@ def tp_param_specs(built: BuiltModel, *, with_butterfly: Optional[bool] = None):
         specs["head"] = P()
     if with_butterfly:
         specs["butterfly"] = {"w_reduce": P(), "w_restore": P()}
-    if cfg.hybrid_attn_every is not None:
-        specs["shared_attn"] = {"mixer": attn_lib.tp_attention_specs(cfg),
-                                "ffn": tfm.tp_mlp_specs()}
+    if cfg.hybrid_layer_ids is not None:
+        # the shared blocks have no tensor-parallel decomposition here: every
+        # rank holds them whole and runs them redundantly, as the mixers
+        shapes = jax.eval_shape(lambda k: _init_shared_blocks(
+            iter(jax.random.split(k, 64)), cfg, dt)[0], jax.random.key(0))
+        specs["shared_attn"] = jax.tree.map(lambda _: P(), shapes)
     return specs
 
 
@@ -189,6 +214,9 @@ def _run_stages(params, built: BuiltModel, x, *, mode, pctx, caches, pos,
                 enc_out, use_kernel, train: bool):
     cfg = built.cfg
     shared = params.get("shared_attn")
+    # hybrid layers read the token embeddings beside the residual, exact
+    # on either side of the butterfly
+    x_emb = x if cfg.hybrid_layer_ids is not None else None
     aux_total = jnp.zeros((2,), jnp.float32)
     rate = jnp.float32(0.0)
     new_caches = []
@@ -208,7 +236,7 @@ def _run_stages(params, built: BuiltModel, x, *, mode, pctx, caches, pos,
         x, nc, aux = tfm.apply_stage(
             list(segs), params["stages"][stage_idx], x, cfg=cfg, pctx=pctx,
             mode=mode, stage_cache=stage_cache, pos=pos, enc_out=enc_out,
-            shared_params=shared, use_kernel=use_kernel)
+            shared_params=shared, use_kernel=use_kernel, x_emb=x_emb)
         new_caches.append(nc)
         aux_total = aux_total + aux
     return x, new_caches, aux_total, rate

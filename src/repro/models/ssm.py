@@ -26,7 +26,7 @@ from repro.models.parallel import ParallelContext
 def _dims(cfg: ModelConfig):
     s = cfg.ssm
     d_inner = s.num_heads * s.head_dim
-    conv_ch = d_inner + 2 * s.state_dim
+    conv_ch = d_inner + 2 * s.n_groups * s.state_dim
     return s, d_inner, conv_ch
 
 
@@ -38,7 +38,7 @@ def _dims(cfg: ModelConfig):
 def init_mamba(key, cfg: ModelConfig, dtype):
     s, d_inner, conv_ch = _dims(cfg)
     d = cfg.d_model
-    proj_out = 2 * d_inner + 2 * s.state_dim + s.num_heads   # z, x, B, C, dt
+    proj_out = d_inner + conv_ch + s.num_heads               # z, x, B, C, dt
     k1, k2, k3, k4 = jax.random.split(key, 4)
     params = {
         "in_proj": dense_init(k1, d, proj_out, dtype),
@@ -100,9 +100,27 @@ def _causal_conv(xbc, conv_w, conv_b, width: int):
     return jax.nn.silu(out + conv_b)
 
 
+def _time_step(params, dt, s: SSMConfig):
+    """softplus(dt + bias), floored at ``dt_min`` where the model has one."""
+    dt = jax.nn.softplus(dt + params["dt_bias"])
+    return jnp.maximum(dt, s.dt_min) if s.dt_min else dt
+
+
 def _gated_out(params, y, z, cfg: ModelConfig):
-    y = rms_norm(y * jax.nn.silu(z), params["norm_w"], cfg.rms_eps)
-    return y @ params["out_proj"]
+    """RMSNorm of ``y * silu(z)`` over each of the ``n_groups`` groups of
+    channels, in float32 from the scan's float32 ``y``, then the out
+    projection in the model's dtype."""
+    G = cfg.ssm.n_groups
+    g = y * jax.nn.silu(z.astype(jnp.float32))
+    shape = g.shape
+    g = rms_norm(g.reshape(*shape[:-1], G, shape[-1] // G),
+                 params["norm_w"].reshape(G, -1), cfg.rms_eps)
+    return g.reshape(shape).astype(z.dtype) @ params["out_proj"]
+
+
+def _per_head(m, heads: int):
+    """(..., G, N) group rows -> (..., H, N): head h reads group h // (H/G)."""
+    return jnp.repeat(m, heads // m.shape[-2], axis=-2)
 
 
 # ---------------------------------------------------------------------------
@@ -111,81 +129,94 @@ def _gated_out(params, y, z, cfg: ModelConfig):
 
 
 def mamba_fullseq(params, x, *, cfg: ModelConfig, return_state: bool = False):
+    """Chunked SSD over any length: the sequence is padded to whole chunks
+    with time step 0 (decay 1, no input), so the padded rows leave the state
+    as the last real row left it."""
     s, d_inner, conv_ch = _dims(cfg)
     Bsz, S, _ = x.shape
-    H, Pd, N, L = s.num_heads, s.head_dim, s.state_dim, s.chunk_size
-    L = min(L, S)
-    assert S % L == 0, f"seq {S} not divisible by chunk {L}"
-    C = S // L
+    H, Pd, N, G = s.num_heads, s.head_dim, s.state_dim, s.n_groups
+    L = min(s.chunk_size, S)
+    C = -(-S // L)
+    pad = C * L - S
 
-    z, xbc, dt = _split_proj(params, x, cfg)
-    xbc = _causal_conv(xbc, params["conv_w"], params["conv_b"], s.conv_width)
+    z, xbc_in, dt = _split_proj(params, x, cfg)
+    # the conv, the scan and the gated norm run in float32, as decode does
+    xbc = _causal_conv(xbc_in.astype(jnp.float32),
+                       params["conv_w"].astype(jnp.float32),
+                       params["conv_b"].astype(jnp.float32), s.conv_width)
     xs = xbc[..., :d_inner].reshape(Bsz, S, H, Pd)
-    Bm = xbc[..., d_inner:d_inner + N]
-    Cm = xbc[..., d_inner + N:]
+    Bm = xbc[..., d_inner:d_inner + G * N].reshape(Bsz, S, G, N)
+    Cm = xbc[..., d_inner + G * N:].reshape(Bsz, S, G, N)
 
-    dt = jax.nn.softplus(dt + params["dt_bias"])                  # (B,S,H) f32
+    dt = _time_step(params, dt, s)                                # (B,S,H) f32
     A = -jnp.exp(params["A_log"])                                 # (H,) < 0
-    a = dt * A                                                    # (B,S,H) < 0
+    if pad:
+        def tail(a):
+            return jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        xs_c, dt, Bm, Cm = tail(xs), tail(dt), tail(Bm), tail(Cm)
+    else:
+        xs_c = xs
 
-    # chunked views
-    xc = xs.reshape(Bsz, C, L, H, Pd)
-    dtc = dt.reshape(Bsz, C, L, H)
-    Bc = Bm.reshape(Bsz, C, L, N).astype(jnp.float32)
-    Cc = Cm.reshape(Bsz, C, L, N).astype(jnp.float32)
-    ac = a.reshape(Bsz, C, L, H)
-    cum = jnp.cumsum(ac, axis=2)                                  # (B,C,L,H)
+    # chunked views; heads split as (group, head in group), before positions
+    R = H // G
+    xc = xs_c.reshape(Bsz, C, L, G, R, Pd).transpose(0, 1, 3, 4, 2, 5)
+    dtc = dt.reshape(Bsz, C, L, G, R).transpose(0, 1, 3, 4, 2)    # (B,C,G,R,L)
+    Bc = Bm.reshape(Bsz, C, L, G, N).astype(jnp.float32)
+    Cc = Cm.reshape(Bsz, C, L, G, N).astype(jnp.float32)
+    cum = jnp.cumsum(dtc * A.reshape(G, R)[:, :, None], axis=-1)  # (B,C,G,R,L)
 
     # ---- intra-chunk (decay-masked attention) -----------------------------
-    seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]           # (B,C,L,L,H) i-j
-    mask = (jnp.arange(L)[:, None] >= jnp.arange(L)[None, :])[None, None, :, :, None]
+    seg = cum[..., :, None] - cum[..., None, :]                   # (..,L,L) i-j
+    mask = jnp.arange(L)[:, None] >= jnp.arange(L)[None, :]
     # mask BEFORE exp: masked (i<j) entries are positive and can overflow;
     # where(mask, exp(seg), 0) would make the backward 0 * inf = NaN
     decay = jnp.exp(jnp.where(mask, seg, -1e9))                   # <= 1
-    scores = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)
-    G = (scores[..., None] * decay).astype(x.dtype)               # (B,C,L,L,H)
-    xdt = (xc * dtc[..., None].astype(x.dtype))
-    y_intra = jnp.einsum("bcijh,bcjhp->bcihp", G, xdt)
+    scores = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc)             # per group
+    Gm = scores[:, :, :, None] * decay                            # (B,C,G,R,L,L)
+    y_intra = jnp.einsum("bcgrij,bcgrjp->bcgrip", Gm, xc * dtc[..., None])
 
     # ---- chunk summary states ---------------------------------------------
-    last = cum[:, :, -1:, :]                                      # (B,C,1,H)
-    w = jnp.exp(last - cum) * dtc                                 # (B,C,L,H)
-    S_chunk = jnp.einsum("bcln,bclh,bclhp->bchpn",
-                         Bc, w, xc.astype(jnp.float32))           # (B,C,H,P,N)
+    last = cum[..., -1:]                                          # (B,C,G,R,1)
+    w = jnp.exp(last - cum) * dtc                                 # (B,C,G,R,L)
+    S_chunk = jnp.einsum("bclgn,bcgrl,bcgrlp->bcgrpn",
+                         Bc, w, xc)                               # (B,C,G,R,P,N)
 
     # ---- inter-chunk scan --------------------------------------------------
-    chunk_decay = jnp.exp(last[:, :, 0, :])                       # (B,C,H)
+    chunk_decay = jnp.exp(last[..., 0])                           # (B,C,G,R)
 
     def step(state, inputs):
         s_c, dec_c, C_c, cum_c = inputs
         # y from previous state, decayed to each position in the chunk
-        y = jnp.einsum("bln,bhpn->blhp", C_c, state) * \
-            jnp.exp(cum_c)[..., None].transpose(0, 1, 2, 3)
-        new = state * dec_c[:, :, None, None] + s_c
+        y = jnp.einsum("blgn,bgrpn->bgrlp", C_c, state) * \
+            jnp.exp(cum_c)[..., None]
+        new = state * dec_c[..., None, None] + s_c
         return new, y
 
-    init = jnp.zeros((Bsz, H, Pd, N), jnp.float32)
+    init = jnp.zeros((Bsz, G, R, Pd, N), jnp.float32)
     # scan over chunk axis: move C to leading
-    xs_scan = (S_chunk.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2),
-               Cc.transpose(1, 0, 2, 3), cum.transpose(1, 0, 2, 3))
-    final_state, y_inter = jax.lax.scan(step, init, xs_scan)
-    y_inter = y_inter.transpose(1, 0, 2, 3, 4)                    # (B,C,L,H,P)
+    lead = lambda a: jnp.moveaxis(a, 1, 0)                        # noqa: E731
+    final_state, y_inter = jax.lax.scan(
+        step, init, (lead(S_chunk), lead(chunk_decay), lead(Cc), lead(cum)))
+    final_state = final_state.reshape(Bsz, H, Pd, N)
 
-    y = (y_intra.astype(jnp.float32) + y_inter).reshape(Bsz, S, H, Pd)
-    y = y + params["D"][None, None, :, None] * xs.astype(jnp.float32)
-    y = y.reshape(Bsz, S, d_inner).astype(x.dtype)
-    out = _gated_out(params, y, z, cfg)
+    y = y_intra + lead(y_inter)                                   # (B,C,G,R,L,P)
+    y = y.transpose(0, 1, 4, 2, 3, 5).reshape(Bsz, C * L, H, Pd)[:, :S]
+    y = y + params["D"][None, None, :, None] * xs
+    out = _gated_out(params, y.reshape(Bsz, S, d_inner), z, cfg)
     if return_state:
-        # conv state must come from the *pre-activation* conv input stream
-        return out, {"ssm": final_state, "conv": _conv_tail(params, x, cfg)}
+        # conv state comes from the *pre-activation* conv input stream
+        return out, {"ssm": final_state,
+                     "conv": _conv_tail(xbc_in, s.conv_width)}
     return out, None
 
 
-def _conv_tail(params, x, cfg: ModelConfig):
-    """Last (W-1) pre-conv channel rows, for seeding decode."""
-    s, d_inner, conv_ch = _dims(cfg)
-    _, xbc, _ = _split_proj(params, x, cfg)
-    return xbc[:, -(s.conv_width - 1):, :]
+def _conv_tail(xbc_in, width: int):
+    """Last (W-1) pre-conv channel rows, for seeding decode; zeros before the
+    first row of a sequence shorter than that."""
+    short = width - 1 - xbc_in.shape[1]
+    if short > 0:
+        xbc_in = jnp.pad(xbc_in, ((0, 0), (short, 0), (0, 0)))
+    return xbc_in[:, -(width - 1):, :]
 
 
 # ---------------------------------------------------------------------------
@@ -197,7 +228,7 @@ def mamba_decode(params, x, state, *, cfg: ModelConfig):
     """x: (B, 1, d); state: {"ssm": (B,H,P,N) f32, "conv": (B,W-1,Cc)}."""
     s, d_inner, conv_ch = _dims(cfg)
     Bsz = x.shape[0]
-    H, Pd, N = s.num_heads, s.head_dim, s.state_dim
+    H, Pd, N, G = s.num_heads, s.head_dim, s.state_dim, s.n_groups
 
     z, xbc_new, dt = _split_proj(params, x, cfg)                  # (B,1,*)
     window = jnp.concatenate([state["conv"], xbc_new], axis=1)    # (B,W,Cc)
@@ -207,17 +238,16 @@ def mamba_decode(params, x, state, *, cfg: ModelConfig):
     new_conv_state = window[:, 1:, :]
 
     xs = xbc[..., :d_inner].reshape(Bsz, H, Pd)
-    Bm = xbc[..., d_inner:d_inner + N].reshape(Bsz, N)
-    Cm = xbc[..., d_inner + N:].reshape(Bsz, N)
+    Bm = _per_head(xbc[..., d_inner:d_inner + G * N].reshape(Bsz, G, N), H)
+    Cm = _per_head(xbc[..., d_inner + G * N:].reshape(Bsz, G, N), H)
 
-    dt = jax.nn.softplus(dt[:, 0] + params["dt_bias"])            # (B,H)
+    dt = _time_step(params, dt[:, 0], s)                          # (B,H)
     A = -jnp.exp(params["A_log"])
     decay = jnp.exp(dt * A)                                       # (B,H)
 
-    upd = jnp.einsum("bh,bhp,bn->bhpn", dt, xs.astype(jnp.float32), Bm)
+    upd = jnp.einsum("bh,bhp,bhn->bhpn", dt, xs.astype(jnp.float32), Bm)
     ssm = state["ssm"] * decay[:, :, None, None] + upd
-    y = jnp.einsum("bhpn,bn->bhp", ssm, Cm)
+    y = jnp.einsum("bhpn,bhn->bhp", ssm, Cm)
     y = y + params["D"][None, :, None] * xs.astype(jnp.float32)
-    y = y.reshape(Bsz, 1, d_inner).astype(x.dtype)
-    out = _gated_out(params, y, z, cfg)
+    out = _gated_out(params, y.reshape(Bsz, 1, d_inner), z, cfg)
     return out, {"ssm": ssm, "conv": new_conv_state.astype(state["conv"].dtype)}

@@ -7,8 +7,10 @@ split cuts the flat list at the configured boundary, producing two stages;
 the butterfly unit (the paper's contribution) runs between them.
 
 Layer kinds: mixer in {attn, mamba, mlstm, slstm} x ffn in {mlp, moe, None};
-``shared=True`` marks zamba2's shared-parameter attention block; ``cross``
-adds whisper-style cross attention.
+``hybrid=k`` marks a zamba2 layer that runs invocation ``k`` of the
+shared-parameter attention+MLP blocks before its own mixer, over the
+residual and the token embeddings (``x_emb``, carried beside the residual);
+``cross`` adds whisper-style cross attention.
 """
 from __future__ import annotations
 
@@ -24,7 +26,8 @@ from repro.models import attention as attn
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 from repro.models import xlstm as xlstm_lib
-from repro.models.common import apply_mlp, init_mlp, init_rms_norm, rms_norm
+from repro.models.common import (apply_mlp, dense_init, dense_spec, glu_act,
+                                 init_mlp, init_rms_norm, rms_norm)
 from repro.models.parallel import LOCAL, ParallelContext, model_psum
 
 # Dry-run knob: when True, segment scans fully unroll so XLA's cost analysis
@@ -51,7 +54,7 @@ class LayerDef:
     mixer: str                      # attn | mamba | mlstm | slstm
     ffn: Optional[str] = "mlp"      # mlp | moe | None
     window: Optional[int] = None
-    shared: bool = False            # zamba2 shared-attention params
+    hybrid: Optional[int] = None    # zamba2: shared-block invocation index
     cross: bool = False             # whisper decoder cross-attention
 
 
@@ -77,11 +80,14 @@ def build_layer_defs(cfg: ModelConfig, long_mode: bool = False) -> List[LayerDef
             mixer = "slstm" if (i % every == every - 1) else "mlstm"
             defs.append(LayerDef(mixer=mixer, ffn=None))
             continue
-        if cfg.hybrid_attn_every is not None:
-            if i % cfg.hybrid_attn_every == cfg.hybrid_attn_every - 1:
+        if cfg.hybrid_layer_ids is not None:
+            # every layer has its own Mamba2 mixer; a hybrid layer runs a
+            # shared block (windowed in long mode) before it
+            ids = list(cfg.hybrid_layer_ids)
+            if i in ids:
                 window = cfg.long_context_window if long_mode else None
-                defs.append(LayerDef(mixer="attn", ffn="mlp", shared=True,
-                                     window=window))
+                defs.append(LayerDef(mixer="mamba", ffn=None,
+                                     hybrid=ids.index(i), window=window))
             else:
                 defs.append(LayerDef(mixer="mamba", ffn=None))
             continue
@@ -113,6 +119,10 @@ def segmentize(defs: Sequence[LayerDef]) -> List[Segment]:
     best = None
     for u in range(1, min(len(defs), 8) + 1):
         unit = tuple(defs[:u])
+        if u > 1 and any(d.hybrid is not None for d in unit):
+            # a hybrid layer's invocation is its own: a unit holding one
+            # never repeats, so it would only unroll its neighbours
+            break
         reps = 1
         while (reps + 1) * u <= len(defs) and tuple(defs[reps * u:(reps + 1) * u]) == unit:
             reps += 1
@@ -147,7 +157,7 @@ def _range_spans(segments: Sequence[Segment], lo: int, hi: int):
       ("peel",  seg_index, rep, pos_in_unit)  — one layer of one repeat
 
     A boundary that lands inside a repeat unit peels individual layers so any
-    0 < boundary < N is representable (zamba2-style multi-layer units)."""
+    0 < boundary < N is representable (gemma3-style multi-layer units)."""
     base = 0
     for si, seg in enumerate(segments):
         u = len(seg.unit)
@@ -214,7 +224,7 @@ def slice_stage_params(segments: Sequence[Segment], stage_params, lo: int,
 def apply_layer_range(segments: Sequence[Segment], stage_params, x, lo: int,
                       hi: int, *, cfg, pctx, mode, range_cache, pos,
                       enc_out=None, shared_params=None, use_kernel=False,
-                      causal=True):
+                      causal=True, x_emb=None):
     """Run flat layers [lo, hi) of a full stacked stage, reading each
     layer's weights in place.  Every span of :func:`_range_spans` scans its
     repeat indices over the full stacked leaves of its segment (closed over,
@@ -222,12 +232,13 @@ def apply_layer_range(segments: Sequence[Segment], stage_params, x, lo: int,
     takes repeat ``i`` by dynamic index: no range of the stack is copied
     out.  A peel scans its one index over the one layer's leaves the same
     way.  ``range_cache`` must be structured per :func:`range_segments`
-    (see init_stage_cache); it is scanned as ``xs``.  Same layers, weights
-    and order as :func:`slice_stage_params` followed by
+    (see init_stage_cache); it is scanned as ``xs``.  ``x_emb`` (the token
+    embeddings, for hybrid layers) is carried beside the residual.  Same
+    layers, weights and order as :func:`slice_stage_params` followed by
     :func:`apply_stage`."""
     kw = dict(cfg=cfg, pctx=pctx, mode=mode, pos=pos, enc_out=enc_out,
               shared_params=shared_params, use_kernel=use_kernel,
-              causal=causal)
+              causal=causal, x_emb=x_emb)
     aux_total = jnp.zeros((2,), jnp.float32)
     new_caches = []
     for k, span in enumerate(_range_spans(segments, lo, hi)):
@@ -281,8 +292,9 @@ def check_tp_divisibility(defs: Sequence[LayerDef], cfg: ModelConfig,
                           mp: int) -> None:
     """Model-parallel stages shard whole attention heads, whole d_ff columns
     and whole experts — fail loudly when ``mp`` can't divide them.  Mixers
-    without a tensor-parallel decomposition here (mamba/xlstm) replicate and
-    run redundantly per rank, so they impose no constraint."""
+    without a tensor-parallel decomposition here (mamba/xlstm, and the
+    shared blocks of hybrid layers) replicate and run redundantly per rank,
+    so they impose no constraint."""
     if mp <= 1:
         return
     for ldef in defs:
@@ -296,7 +308,7 @@ def check_tp_divisibility(defs: Sequence[LayerDef], cfg: ModelConfig,
                 f"model axis {mp} must divide heads "
                 f"({attn._padded_heads(cfg)}) and kv heads "
                 f"({cfg.num_kv_heads})")
-        if (ldef.ffn == "mlp" or ldef.shared) and cfg.d_ff % mp:
+        if ldef.ffn == "mlp" and cfg.d_ff % mp:
             raise ValueError(f"model axis {mp} must divide d_ff ({cfg.d_ff})")
         if ldef.ffn == "moe" and cfg.moe.num_experts % mp:
             raise ValueError(f"model axis {mp} must divide num_experts "
@@ -312,9 +324,9 @@ def tp_layer_specs(ldef: LayerDef, cfg: ModelConfig, dtype,
     tree, then overriding the shardable projections)."""
     specs = jax.tree.map(lambda _: P(), layer_specs(ldef, cfg, dtype),
                          is_leaf=lambda s: isinstance(s, P))
-    if ldef.mixer == "attn" and not ldef.shared:
+    if ldef.mixer == "attn":
         specs["mixer"] = attn.tp_attention_specs(cfg, axis)
-    if ldef.mixer == "attn" and ldef.ffn == "mlp" and not ldef.shared:
+    if ldef.mixer == "attn" and ldef.ffn == "mlp":
         specs["ffn"] = tp_mlp_specs(axis)
     elif ldef.mixer == "attn" and ldef.ffn == "moe":
         specs["ffn"]["wg"] = P(axis, None, None)   # expert dim -> model axis
@@ -358,13 +370,18 @@ def init_layer(key, ldef: LayerDef, cfg: ModelConfig, dtype):
     ks = iter(jax.random.split(key, 8))
     params["norm1"], specs["norm1"] = init_rms_norm(cfg.d_model, dtype)
     if ldef.mixer == "attn":
-        if not ldef.shared:   # shared params are stored once at the top level
-            params["mixer"], specs["mixer"] = attn.init_attention(next(ks), cfg, dtype)
+        params["mixer"], specs["mixer"] = attn.init_attention(next(ks), cfg, dtype)
         if ldef.cross:
             params["norm_cross"], specs["norm_cross"] = init_rms_norm(cfg.d_model, dtype)
             params["cross"], specs["cross"] = attn.init_attention(next(ks), cfg, dtype)
     elif ldef.mixer == "mamba":
         params["mixer"], specs["mixer"] = ssm_lib.init_mamba(next(ks), cfg, dtype)
+        if ldef.hybrid is not None:
+            # the shared blocks live at the top level; the layer owns the
+            # projection of their output into its mixer's input
+            params["linear"] = dense_init(next(ks), cfg.d_model, cfg.d_model,
+                                          dtype)
+            specs["linear"] = dense_spec((cfg.d_model, cfg.d_model), None)
     elif ldef.mixer == "mlstm":
         params["mixer"], specs["mixer"] = xlstm_lib.init_mlstm(next(ks), cfg, dtype)
     elif ldef.mixer == "slstm":
@@ -373,7 +390,7 @@ def init_layer(key, ldef: LayerDef, cfg: ModelConfig, dtype):
         raise ValueError(ldef.mixer)
     if ldef.ffn is not None and ldef.mixer == "attn":
         params["norm2"], specs["norm2"] = init_rms_norm(cfg.d_model, dtype)
-        if ldef.ffn == "mlp" and not ldef.shared:
+        if ldef.ffn == "mlp":
             params["ffn"], specs["ffn"] = init_mlp(next(ks), cfg.d_model, cfg.d_ff, dtype)
         elif ldef.ffn == "moe":
             params["ffn"], specs["ffn"] = moe_lib.init_moe(next(ks), cfg, dtype)
@@ -424,7 +441,11 @@ def init_layer_cache(ldef: LayerDef, cfg: ModelConfig, batch: int, length: int,
             }
         return c
     if ldef.mixer == "mamba":
-        return ssm_lib.init_ssm_state(cfg, batch, dtype)
+        c = ssm_lib.init_ssm_state(cfg, batch, dtype)
+        if ldef.hybrid is not None:       # the shared block's invocation KV
+            cache_len = min(length, ldef.window) if ldef.window else length
+            c["kv"] = attn.init_kv_cache(cfg, batch, cache_len, dtype)
+        return c
     if ldef.mixer == "mlstm":
         return xlstm_lib.init_mlstm_state(cfg, batch, dtype)
     if ldef.mixer == "slstm":
@@ -439,7 +460,10 @@ def layer_cache_spec(ldef: LayerDef, batch_axis, seq_axis, head_axis=None):
             c["cross_kv"] = attn.kv_cache_spec(batch_axis, None)
         return c
     if ldef.mixer == "mamba":
-        return ssm_lib.ssm_state_spec(batch_axis)
+        c = ssm_lib.ssm_state_spec(batch_axis)
+        if ldef.hybrid is not None:       # shared blocks replicate: whole heads
+            c["kv"] = attn.kv_cache_spec(batch_axis, seq_axis, None)
+        return c
     if ldef.mixer == "mlstm":
         return {"C": P(batch_axis, None, None, None), "n": P(batch_axis, None, None),
                 "conv": P(batch_axis, None, None)}
@@ -458,11 +482,43 @@ def to_ring(kv: dict, window: int) -> dict:
     return {k: jnp.zeros_like(v).at[:, slots].set(v) for k, v in tail.items()}
 
 
+def _hybrid_block(ldef: LayerDef, lparams, x, x_emb, *, cfg: ModelConfig,
+                  mode: str, cache, pos, shared_params):
+    """Invocation ``ldef.hybrid`` of zamba2's shared blocks: block
+    ``k % num_mem_blocks`` attends over ``concat(x, x_emb)`` (softmax scale
+    ``(hd/2)^-0.5``), its MLP's gate/up projection gets invocation k's
+    LoRA adapter, and the layer's own ``linear`` projects the result.
+    Returns (t, kv): what is added to the mixer's input, and the
+    invocation's KV cache."""
+    k = ldef.hybrid
+    blk = shared_params["blocks"][k % cfg.num_mem_blocks]
+    adapter = shared_params["adapters"][k]
+    scale = (cfg.resolved_head_dim / 2) ** -0.5
+    h = rms_norm(jnp.concatenate([x, x_emb], axis=-1), blk["norm_in"],
+                 cfg.rms_eps)
+    if mode == "decode":
+        out, kv = attn.attention_decode(blk["mixer"], h, cache["kv"], pos,
+                                        cfg=cfg, window=ldef.window,
+                                        scale=scale)
+    else:
+        out, kv = attn.attention_fullseq(blk["mixer"], h, cfg=cfg,
+                                         window=ldef.window, scale=scale)
+        if ldef.window:
+            kv = to_ring(kv, ldef.window)
+    a = rms_norm(out, blk["norm_ff"], cfg.rms_eps)
+    gate_up = (a @ adapter["a"]) @ adapter["b"]
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    ffn = blk["ffn"]
+    t = (glu_act(a @ ffn["w_gate"] + gate, cfg.act) *
+         (a @ ffn["w_up"] + up)) @ ffn["w_down"]
+    return t @ lparams["linear"], kv
+
+
 def apply_layer(ldef: LayerDef, lparams, x, *, cfg: ModelConfig,
                 pctx: ParallelContext, mode: str, cache, pos,
                 enc_out=None, shared_params=None, use_kernel: bool = False,
                 causal: bool = True, h_pre=None, pending=None,
-                defer_psum: bool = False):
+                defer_psum: bool = False, x_emb=None):
     """Returns (x, new_cache, aux_vec[2], pending_out).
 
     ``h_pre`` short-circuits the input RMSNorm: a caller that already holds
@@ -480,16 +536,20 @@ def apply_layer(ldef: LayerDef, lparams, x, *, cfg: ModelConfig,
     pending, so the carried structure is stable under scan."""
     aux = jnp.zeros((2,), jnp.float32)
     new_cache = None
-    p = dict(lparams)
-    if ldef.shared:
-        p["mixer"] = shared_params["mixer"]
-        p["ffn"] = shared_params["ffn"]
+    p = lparams
 
     if pending is not None:
         x = x + model_psum(pending, pctx)
     pending_out = jnp.zeros_like(x) if defer_psum else None
 
-    h = h_pre if h_pre is not None else rms_norm(x, p["norm1"], cfg.rms_eps)
+    t = kv = None
+    if ldef.hybrid is not None:
+        assert h_pre is None, "a hybrid layer's norm reads its shared block"
+        t, kv = _hybrid_block(ldef, p, x, x_emb, cfg=cfg, mode=mode,
+                              cache=cache, pos=pos,
+                              shared_params=shared_params)
+    h = h_pre if h_pre is not None else \
+        rms_norm(x if t is None else x + t, p["norm1"], cfg.rms_eps)
     rope = not cfg.is_encdec          # whisper uses sinusoid embeds, no RoPE
     if ldef.mixer == "attn":
         if mode == "decode":
@@ -540,6 +600,8 @@ def apply_layer(ldef: LayerDef, lparams, x, *, cfg: ModelConfig,
             out, st = ssm_lib.mamba_fullseq(p["mixer"], h, cfg=cfg,
                                             return_state=(mode == "prefill"))
             new_cache = st
+        if kv is not None and new_cache is not None:
+            new_cache = {**new_cache, "kv": kv}
         x = x + out
     elif ldef.mixer == "mlstm":
         if mode == "decode":
@@ -569,7 +631,7 @@ def apply_layer(ldef: LayerDef, lparams, x, *, cfg: ModelConfig,
 
 def _apply_unit(seg: Segment, unit_params, unit_cache, x, aux_sum, pending, *,
                 cfg, pctx, mode, pos, enc_out, shared_params, use_kernel,
-                causal, first_h=None, defer_psum=False):
+                causal, first_h=None, defer_psum=False, x_emb=None):
     """One pass over a segment's repeat unit; shared by the scan body and
     the peeled first repeat."""
     new_caches = []
@@ -580,7 +642,7 @@ def _apply_unit(seg: Segment, unit_params, unit_cache, x, aux_sum, pending, *,
             cache=c, pos=pos, enc_out=enc_out, shared_params=shared_params,
             use_kernel=use_kernel, causal=causal,
             h_pre=first_h if i == 0 else None, pending=pending,
-            defer_psum=defer_psum)
+            defer_psum=defer_psum, x_emb=x_emb)
         aux_sum = aux_sum + aux
         new_caches.append(nc)
     return x, aux_sum, pending, new_caches
@@ -589,7 +651,7 @@ def _apply_unit(seg: Segment, unit_params, unit_cache, x, aux_sum, pending, *,
 def apply_segment(seg: Segment, seg_params, x, *, cfg, pctx, mode, seg_cache,
                   pos, enc_out=None, shared_params=None, use_kernel=False,
                   causal=True, first_h=None, overlap_psum=False,
-                  pending=None):
+                  pending=None, x_emb=None):
     """seg_params: list per unit pos of stacked params; seg_cache likewise.
 
     ``first_h`` is the fused restore+norm kernel's pre-normed input for the
@@ -601,7 +663,7 @@ def apply_segment(seg: Segment, seg_params, x, *, cfg, pctx, mode, seg_cache,
     pending."""
     kw = dict(cfg=cfg, pctx=pctx, mode=mode, pos=pos, enc_out=enc_out,
               shared_params=shared_params, use_kernel=use_kernel,
-              causal=causal, defer_psum=overlap_psum)
+              causal=causal, defer_psum=overlap_psum, x_emb=x_emb)
     if overlap_psum and pending is None:
         pending = jnp.zeros_like(x)
     aux0 = jnp.zeros((2,), jnp.float32)
@@ -648,7 +710,7 @@ def apply_segment(seg: Segment, seg_params, x, *, cfg, pctx, mode, seg_cache,
 def apply_stage(segments: List[Segment], stage_params, x, *, cfg, pctx, mode,
                 stage_cache, pos, enc_out=None, shared_params=None,
                 use_kernel=False, causal=True, first_h=None,
-                overlap_psum=False):
+                overlap_psum=False, x_emb=None):
     aux_total = jnp.zeros((2,), jnp.float32)
     new_caches = []
     pending = None
@@ -659,7 +721,7 @@ def apply_stage(segments: List[Segment], stage_params, x, *, cfg, pctx, mode,
             seg_cache=cache, pos=pos, enc_out=enc_out,
             shared_params=shared_params, use_kernel=use_kernel, causal=causal,
             first_h=first_h if si == 0 else None,
-            overlap_psum=overlap_psum, pending=pending)
+            overlap_psum=overlap_psum, pending=pending, x_emb=x_emb)
         new_caches.append(nc)
         aux_total = aux_total + aux
     if pending is not None:

@@ -629,8 +629,9 @@ class CloudServer:
                     and r.payload is not None and r.trace.split == split
                     and r.payload[0].shape == req.payload[0].shape]
             runner = self.bank.runner(split)
-            payload = jnp.concatenate([r.payload[0] for r in group])
-            scales = jnp.concatenate([r.payload[1] for r in group])
+            payload, scales = jax.tree.map(
+                lambda *rows: jnp.concatenate(rows),
+                *[r.payload[:2] for r in group])
             logits, cache1 = runner.cloud_half(runner.params, payload, scales)
             for i, r in enumerate(group):
                 self._cloud_results[r.uid] = (

@@ -126,12 +126,17 @@ class CostModel:
             self.cloud_mp)
         return self.cloud.latency_s(f, b) / max(1e-9, 1.0 - load)
 
-    def stream_row_bytes(self, wire_mode: str, d_r: int) -> float:
+    def stream_row_bytes(self, wire_mode: str, d_r: int,
+                         split: Optional[int] = None) -> float:
         """Per-token uplink bytes of the streamed transport: one boundary
         row in the wire format (int8 codes + f32 scale for the paper's
         mode; "int4" nibble-packs two codes per byte, halving the code
-        bytes)."""
-        return wire_mode_bytes(self.cfg, 1, d_r, wire_mode)
+        bytes), and the token's id where a layer past ``split`` reads the
+        embeddings."""
+        b = wire_mode_bytes(self.cfg, 1, d_r, wire_mode)
+        if split is not None:
+            b += costs.id_wire_bytes(self.cfg, split, 1)
+        return b
 
     def serial_decode_tick_s(self, split: int, d_r: int, *,
                              wire_mode: str = "int8",
@@ -143,7 +148,8 @@ class CostModel:
         t = self.edge_decode_step_s(split, d_r) + \
             self.cloud_decode_step_s(split, d_r, batch, load)
         if link_bps:
-            t += self.stream_row_bytes(wire_mode, d_r) * 8.0 / link_bps
+            t += self.stream_row_bytes(wire_mode, d_r, split) * 8.0 / \
+                link_bps
         return t
 
     def pipelined_decode_tick_s(self, split: int, d_r: int, *,
@@ -158,7 +164,7 @@ class CostModel:
         parts = [self.edge_decode_step_s(split, d_r),
                  self.cloud_decode_step_s(split, d_r, batch, load)]
         if link_bps:
-            parts.append(self.stream_row_bytes(wire_mode, d_r) * 8.0
+            parts.append(self.stream_row_bytes(wire_mode, d_r, split) * 8.0
                          / link_bps)
         return max(parts)
 
@@ -175,6 +181,7 @@ class CostModel:
         if mode == "edge":
             return 0.0
         b = wire_mode_bytes(self.cfg, seq, d_r, wire_mode)
+        b += costs.id_wire_bytes(self.cfg, split, seq)
         if new_tokens > 1 and transport == "cache_handoff":
             b += self.stage0_cache_bytes(seq, split)
         return b
@@ -252,6 +259,12 @@ class SplitModelBank:
         self._M, self._tfm = M, tfm
         self._dt = jnp.dtype(base_cfg.dtype)
         self._defs = tfm.build_layer_defs(base_cfg)
+        # hybrid layers (zamba2) read the token embeddings beside the
+        # residual: each program passes them to its layer ranges, and a
+        # split before such a layer ships the token ids with the codes
+        self._reads_emb = base_cfg.hybrid_layer_ids is not None
+        self.ids_dtype = np.dtype(
+            f"int{8 * costs.id_width(base_cfg.vocab_size)}")
 
         # seq bucketing is only numerics-preserving when padded tail rows
         # cannot leak into real rows: pure causal global attention.  Windowed
@@ -330,6 +343,11 @@ class SplitModelBank:
         self.cache_misses += 1
         self.jit_cache_keys.add(key)
         return True
+
+    def ships_ids(self, split: int) -> bool:
+        """Whether a layer past ``split`` reads the token embeddings, so the
+        edge's payload carries the token ids (:class:`CodedRows`)."""
+        return any(d.hybrid is not None for d in self._defs[split:])
 
     @property
     def batch_numerics_ok(self) -> bool:
@@ -517,63 +535,89 @@ class SplitModelBank:
 
     def _make_edge(self, split: int, mp: int = 1):
         import jax
-        import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.kernels import ops as kops
         cfg, segs, scale, embed, _, _, pctx = self._stage_ctx(mp)
-        tfm, wm = self._tfm, self.wire_mode
+        tfm = self._tfm
 
         def edge(params, toks):
             x = embed(params["embed"], toks, scale=scale)
             x, cache0, _ = tfm.apply_layer_range(
                 segs, params["stages"][0], x, 0, split, cfg=cfg, pctx=pctx,
                 mode="prefill", range_cache=None, pos=None,
-                shared_params=params.get("shared_attn"))
-            if wm == "raw":
-                return x, jnp.zeros((*x.shape[:2], 1), jnp.float32), cache0
-            if wm == "reduced":
-                r = x @ params["butterfly"]["w_reduce"]
-                return r, jnp.zeros((*r.shape[:2], 1), jnp.float32), cache0
-            if self._kernel_wire_ok:
-                codes, scales = kops.butterfly_reduce_quant(
-                    x, params["butterfly"]["w_reduce"],
-                    bits=self.wire_eff_bits)
-            else:
-                from repro.core.quantization import quantize
-                codes, scales = quantize(x @ params["butterfly"]["w_reduce"],
-                                         self.wire_eff_bits)
-            return self._pack_wire(codes), scales, cache0
+                shared_params=params.get("shared_attn"),
+                x_emb=x if self._reads_emb else None)
+            payload, scales = self._wire_out(params, x, toks, split)
+            return payload, scales, cache0
 
         edge = self._mp_wrap(
             edge, mp, lambda: ((self._tp_specs(), P()),
                                (P(), P(), self._cache_spec_tree(0, split))))
         return jax.jit(edge)
 
+    def _wire_out(self, params, x, toks, split: int):
+        """The edge's side of the wire, in-graph: (payload, scales) of the
+        boundary rows ``x`` in the wire format, the token ids beside the
+        codes where a layer past ``split`` reads the embeddings."""
+        import jax.numpy as jnp
+        from repro.core.quantization import CodedRows
+        from repro.kernels import ops as kops
+        wm = self.wire_mode
+        if wm == "raw":
+            payload, scales = x, jnp.zeros((*x.shape[:2], 1), jnp.float32)
+        elif wm == "reduced":
+            payload = x @ params["butterfly"]["w_reduce"]
+            scales = jnp.zeros((*payload.shape[:2], 1), jnp.float32)
+        elif self._kernel_wire_ok:
+            codes, scales = kops.butterfly_reduce_quant(
+                x, params["butterfly"]["w_reduce"], bits=self.wire_eff_bits)
+            payload = self._pack_wire(codes)
+        else:
+            from repro.core.quantization import quantize
+            codes, scales = quantize(x @ params["butterfly"]["w_reduce"],
+                                     self.wire_eff_bits)
+            payload = self._pack_wire(codes)
+        if self.ships_ids(split):
+            payload = CodedRows(payload, toks.astype(self.ids_dtype))
+        return payload, scales
+
+    def _wire_in(self, params, payload, scales, split: int, scale: bool):
+        """The cloud's side of the wire, in-graph: the restored boundary rows
+        and, where the payload carries token ids, their embeddings (else
+        None)."""
+        from repro.kernels import ops as kops
+        from repro.models.common import embed
+        wm, dt = self.wire_mode, self._dt
+        x_emb = None
+        if self.ships_ids(split):
+            x_emb = embed(params["embed"], payload.ids.astype("int32"),
+                          scale=scale)
+            payload = payload.codes
+        if wm == "raw":
+            x = payload
+        elif wm == "reduced":
+            x = payload @ params["butterfly"]["w_restore"]
+        elif self._kernel_wire_ok:
+            x = kops.butterfly_dequant_restore(
+                self._unpack_wire(payload), scales,
+                params["butterfly"]["w_restore"], out_dtype=dt)
+        else:
+            from repro.core.quantization import dequantize
+            x = dequantize(self._unpack_wire(payload), scales, dt) @ \
+                params["butterfly"]["w_restore"]
+        return x, x_emb
+
     def _make_cloud(self, split: int, mp: int = 1):
         import jax
-        import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.kernels import ops as kops
-        cfg, segs, _, _, rms_norm, unembed, pctx = self._stage_ctx(mp)
-        tfm, wm, dt = self._tfm, self.wire_mode, self._dt
+        cfg, segs, scale, _, rms_norm, unembed, pctx = self._stage_ctx(mp)
+        tfm = self._tfm
 
         def cloud(params, payload, scales, length):
-            if wm == "raw":
-                x = payload
-            elif wm == "reduced":
-                x = payload @ params["butterfly"]["w_restore"]
-            elif self._kernel_wire_ok:
-                x = kops.butterfly_dequant_restore(
-                    self._unpack_wire(payload), scales,
-                    params["butterfly"]["w_restore"], out_dtype=dt)
-            else:
-                from repro.core.quantization import dequantize
-                x = dequantize(self._unpack_wire(payload), scales, dt) @ \
-                    params["butterfly"]["w_restore"]
+            x, x_emb = self._wire_in(params, payload, scales, split, scale)
             x, cache1, _ = tfm.apply_layer_range(
                 segs, params["stages"][0], x, split, cfg.num_layers, cfg=cfg,
                 pctx=pctx, mode="prefill", range_cache=None, pos=None,
-                shared_params=params.get("shared_attn"))
+                shared_params=params.get("shared_attn"), x_emb=x_emb)
             x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
             x = rms_norm(x, params["final_norm"], cfg.rms_eps)
             table = params["embed"] if cfg.tie_embeddings else params["head"]
@@ -594,15 +638,16 @@ class SplitModelBank:
 
         def prefill(params, toks, length):
             x = embed(params["embed"], toks, scale=scale)
+            x_emb = x if self._reads_emb else None
             x, cache0, _ = tfm.apply_layer_range(
                 segs, params["stages"][0], x, 0, split, cfg=cfg, pctx=pctx,
                 mode="prefill", range_cache=None, pos=None,
-                shared_params=params.get("shared_attn"))
+                shared_params=params.get("shared_attn"), x_emb=x_emb)
             x = self._wire_ingraph(params["butterfly"], x, use_kernel=True)
             x, cache1, _ = tfm.apply_layer_range(
                 segs, params["stages"][0], x, split, cfg.num_layers, cfg=cfg,
                 pctx=pctx, mode="prefill", range_cache=None, pos=None,
-                shared_params=params.get("shared_attn"))
+                shared_params=params.get("shared_attn"), x_emb=x_emb)
             x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
             x = rms_norm(x, params["final_norm"], cfg.rms_eps)
             table = params["embed"] if cfg.tie_embeddings else params["head"]
@@ -626,15 +671,16 @@ class SplitModelBank:
 
         def decode(params, tokens, caches, pos):
             x = embed(params["embed"], tokens, scale=scale)
+            x_emb = x if self._reads_emb else None
             x, nc0, _ = tfm.apply_layer_range(
                 segs, params["stages"][0], x, 0, split, cfg=cfg, pctx=pctx,
                 mode="decode", range_cache=caches[0], pos=pos,
-                shared_params=params.get("shared_attn"))
+                shared_params=params.get("shared_attn"), x_emb=x_emb)
             x = self._wire_ingraph(params["butterfly"], x, use_kernel=True)
             x, nc1, _ = tfm.apply_layer_range(
                 segs, params["stages"][0], x, split, cfg.num_layers, cfg=cfg,
                 pctx=pctx, mode="decode", range_cache=caches[1], pos=pos,
-                shared_params=params.get("shared_attn"))
+                shared_params=params.get("shared_attn"), x_emb=x_emb)
             x = rms_norm(x, params["final_norm"], cfg.rms_eps)
             table = params["embed"] if cfg.tie_embeddings else params["head"]
             return unembed(table, x, cfg.logit_softcap), [nc0, nc1]
@@ -652,32 +698,19 @@ class SplitModelBank:
         against the edge-resident stage-0 decode cache, emit one wire row —
         the per-token payload that replaces the stage-0 cache handoff."""
         import jax
-        import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.kernels import ops as kops
         cfg, segs, scale, embed, _, _, pctx = self._stage_ctx(mp)
-        tfm, wm = self._tfm, self.wire_mode
+        tfm = self._tfm
 
         def edge_step(params, tok, cache0, pos):
             x = embed(params["embed"], tok, scale=scale)
             x, nc0, _ = tfm.apply_layer_range(
                 segs, params["stages"][0], x, 0, split, cfg=cfg, pctx=pctx,
                 mode="decode", range_cache=cache0, pos=pos,
-                shared_params=params.get("shared_attn"))
-            if wm == "raw":
-                return x, jnp.zeros((*x.shape[:2], 1), jnp.float32), nc0
-            if wm == "reduced":
-                r = x @ params["butterfly"]["w_reduce"]
-                return r, jnp.zeros((*r.shape[:2], 1), jnp.float32), nc0
-            if self._kernel_wire_ok:
-                codes, scales = kops.butterfly_reduce_quant(
-                    x, params["butterfly"]["w_reduce"],
-                    bits=self.wire_eff_bits)
-            else:
-                from repro.core.quantization import quantize
-                codes, scales = quantize(x @ params["butterfly"]["w_reduce"],
-                                         self.wire_eff_bits)
-            return self._pack_wire(codes), scales, nc0
+                shared_params=params.get("shared_attn"),
+                x_emb=x if self._reads_emb else None)
+            payload, scales = self._wire_out(params, x, tok, split)
+            return payload, scales, nc0
 
         def specs():
             spec0 = self._cache_spec_tree(0, split)
@@ -693,27 +726,15 @@ class SplitModelBank:
         step (serving/engine._sampled_stream_step), shared by every engine of
         this split."""
         from jax.sharding import PartitionSpec as P
-        from repro.kernels import ops as kops
-        cfg, segs, _, _, rms_norm, unembed, pctx = self._stage_ctx(mp)
-        tfm, wm, dt = self._tfm, self.wire_mode, self._dt
+        cfg, segs, scale, _, rms_norm, unembed, pctx = self._stage_ctx(mp)
+        tfm = self._tfm
 
         def cloud_step(params, payload, scales, cache1, pos):
-            if wm == "raw":
-                x = payload
-            elif wm == "reduced":
-                x = payload @ params["butterfly"]["w_restore"]
-            elif self._kernel_wire_ok:
-                x = kops.butterfly_dequant_restore(
-                    self._unpack_wire(payload), scales,
-                    params["butterfly"]["w_restore"], out_dtype=dt)
-            else:
-                from repro.core.quantization import dequantize
-                x = dequantize(self._unpack_wire(payload), scales, dt) @ \
-                    params["butterfly"]["w_restore"]
+            x, x_emb = self._wire_in(params, payload, scales, split, scale)
             x, nc1, _ = tfm.apply_layer_range(
                 segs, params["stages"][0], x, split, cfg.num_layers, cfg=cfg,
                 pctx=pctx, mode="decode", range_cache=cache1, pos=pos,
-                shared_params=params.get("shared_attn"))
+                shared_params=params.get("shared_attn"), x_emb=x_emb)
             x = rms_norm(x, params["final_norm"], cfg.rms_eps)
             table = params["embed"] if cfg.tie_embeddings else params["head"]
             return unembed(table, x, cfg.logit_softcap), nc1
@@ -748,6 +769,9 @@ class SplitRunner:
         # shallow dict: backbone leaves are bank.params' leaves, not copies
         self.params = dict(bank.params)
         self.params["butterfly"] = bank.butterfly_params(split)
+        # bytes of one token id on the wire, where the payload carries them
+        self.id_bytes = bank.ids_dtype.itemsize if bank.ships_ids(split) \
+            else 0
 
     # ------------------------------------------------------------ split halves
     def edge_half(self, params, toks):
@@ -757,7 +781,7 @@ class SplitRunner:
         import jax.numpy as jnp
         bank = self.bank
         B, S = np.shape(toks)
-        with span("split.edge_half", rows=S):
+        with span("split.edge_half", rows=S, ids_bytes=B * S * self.id_bytes):
             toks = jnp.asarray(toks)
             Bb, Sb = bank._buckets(B, S)
             out = bank.timed_call(
@@ -771,16 +795,18 @@ class SplitRunner:
     def cloud_half(self, params, payload, scales):
         """Cloud stage: restore + layers [split, N) + LM head.  Returns
         (last-position logits (B, V), cache1)."""
+        import jax
         import jax.numpy as jnp
         bank = self.bank
         B, S = np.shape(payload)[:2]
         with span("split.cloud_half", rows=S):
-            payload = jnp.asarray(payload)
+            payload = jax.tree.map(jnp.asarray, payload)
             Bb, Sb = bank._buckets(B, S)
             if (Bb, Sb) != (B, S):
                 pad = ((0, Bb - B), (0, Sb - S), (0, 0))
-                payload = jnp.pad(payload, pad)
-                scales = jnp.pad(jnp.asarray(scales), pad)
+                payload, scales = jax.tree.map(
+                    lambda a: jnp.pad(jnp.asarray(a), pad[:np.ndim(a)]),
+                    (payload, scales))
             logits, cache1 = bank.timed_call(
                 bank.cache_key("cloud", self.split, self.cloud_mp, Bb, Sb),
                 bank._fn("cloud", self.split, self.cloud_mp),
@@ -796,7 +822,8 @@ class SplitRunner:
         wire row per batch element."""
         import jax.numpy as jnp
         bank = self.bank
-        with span("split.edge_step", pos=int(pos[0])):
+        with span("split.edge_step", pos=int(pos[0]),
+                  ids_bytes=int(np.shape(tok)[0]) * self.id_bytes):
             tok = jnp.asarray(tok, jnp.int32)
             return bank.timed_call(
                 bank.cache_key("edge_step", self.split, self.edge_mp,
@@ -927,15 +954,16 @@ class SplitRunner:
         tfm = bank._tfm
         params = self.params
         x = embed(params["embed"], jnp.asarray(toks), scale=scale)
+        x_emb = x if bank._reads_emb else None
         x, cache0, _ = tfm.apply_layer_range(
             segs, params["stages"][0], x, 0, self.split, cfg=cfg, pctx=LOCAL,
             mode="prefill", range_cache=None, pos=None,
-            shared_params=params.get("shared_attn"))
+            shared_params=params.get("shared_attn"), x_emb=x_emb)
         x = bank._wire_ingraph(params["butterfly"], x, use_kernel=False)
         x, cache1, _ = tfm.apply_layer_range(
             segs, params["stages"][0], x, self.split, cfg.num_layers, cfg=cfg,
             pctx=LOCAL, mode="prefill", range_cache=None, pos=None,
-            shared_params=params.get("shared_attn"))
+            shared_params=params.get("shared_attn"), x_emb=x_emb)
         x = rms_norm(x[:, -1:], params["final_norm"], cfg.rms_eps)
         table = params["embed"] if cfg.tie_embeddings else params["head"]
         return unembed(table, x, cfg.logit_softcap), [cache0, cache1]

@@ -78,7 +78,8 @@ def _entropy_payload_adjust(device, req) -> float:
     coded = predicted
     delta = 0.0
     if req.payload is not None and req.payload[0] is not None:
-        codes = np.asarray(req.payload[0][0])          # (S, d_r) int8
+        codes = req.payload[0]
+        codes = np.asarray(getattr(codes, "codes", codes)[0])  # (S, d_r) int8
         actual = wire_codec.coded_nbytes(
             codes, _default_prior(device.d_r)) + t.prompt_len * 4
         # same escape hatch as the planner: the edge ships raw int8 codes
@@ -235,7 +236,8 @@ class StreamedTransport(DecodeTransport):
             return
         t = req.trace
         now = device.loop.now
-        nbytes = device.cost.stream_row_bytes(device.wire_mode, device.d_r)
+        nbytes = device.cost.stream_row_bytes(device.wire_mode, device.d_r,
+                                              t.split)
         t.wire_bytes += nbytes
         if req.stream_t0 is None:
             req.stream_t0 = now                  # RTT: row ready -> id back

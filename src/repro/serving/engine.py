@@ -200,7 +200,8 @@ class ServingEngine:
                       max_new_tokens=max_new_tokens, temperature=temperature,
                       eos_id=eos_id, record_logits=record_logits)
         self._uid += 1
-        with _span("engine.admit", uid=req.uid, rows=len(req.prompt)):
+        with _span("engine.admit", uid=req.uid, rows=len(req.prompt),
+                   **self._slot_bytes(len(req.prompt))):
             self._prefill_into(self._free_slot(), req)
         return req
 
@@ -218,7 +219,8 @@ class ServingEngine:
                       max_new_tokens=max_new_tokens, temperature=temperature,
                       eos_id=eos_id, record_logits=record_logits)
         self._uid += 1
-        with _span("engine.admit", uid=req.uid, rows=prompt_len):
+        with _span("engine.admit", uid=req.uid, rows=prompt_len,
+                   **self._slot_bytes(prompt_len)):
             slot = self._free_slot()
             self._write_slot(slot, caches)
             self.positions[slot] = prompt_len
@@ -243,7 +245,9 @@ class ServingEngine:
                       max_new_tokens=max_new_tokens, temperature=temperature,
                       eos_id=eos_id, record_logits=record_logits)
         self._uid += 1
-        with _span("engine.admit", uid=req.uid, rows=prompt_len):
+        # a streamed request holds no slot: nothing is written to the pool
+        with _span("engine.admit", uid=req.uid, rows=prompt_len,
+                   state_bytes=0, kv_bytes=0):
             last_logits = jnp.asarray(last_logits)
             if req.record_logits:
                 req.logits_history.append(jax.device_get(last_logits))
@@ -263,7 +267,8 @@ class ServingEngine:
         with _span("engine.stream_step", uid=req.uid, pos=pos):
             toks, row, cache, self.key = self._dispatch(
                 "engine_stream_step", self._stream_step,
-                self.params, jnp.asarray(payload), jnp.asarray(scales), cache,
+                self.params, jax.tree.map(jnp.asarray, payload),
+                jnp.asarray(scales), cache,
                 jnp.asarray([pos], jnp.int32), self.key,
                 jnp.asarray([req.temperature], jnp.float32))
             with _span("sync"):
@@ -293,6 +298,21 @@ class ServingEngine:
             steps += 1
 
     # ------------------------------------------------------------- internals
+    def _slot_bytes(self, rows: int) -> dict:
+        """Bytes a request of ``rows`` prompt positions writes into its slot:
+        ``state_bytes`` of recurrent state (the whole slot's, whatever the
+        length) and ``kv_bytes`` of attention KV for its positions (a
+        windowed cache holds at most its window)."""
+        state = kv = 0
+        for path, leaf in jax.tree_util.tree_flatten_with_path(self.cache)[0]:
+            per_slot = leaf.nbytes // self.max_batch   # (repeats, slots, ...)
+            if any(getattr(k, "key", None) == "kv" for k in path):
+                length = leaf.shape[2]
+                kv += per_slot * min(rows, length) // length
+            else:
+                state += per_slot
+        return {"state_bytes": int(state), "kv_bytes": int(kv)}
+
     def _free_slot(self) -> int:
         for i, r in enumerate(self.active):
             if r is None:

@@ -97,6 +97,8 @@ def make_split_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
     assert built.has_butterfly and len(built.stages) == 2, \
         "pipeline needs a butterfly split (cfg.with_butterfly(...))"
     assert not cfg.is_encdec, "enc-dec archs are out of pipeline scope"
+    assert cfg.hybrid_layer_ids is None, \
+        "hybrid layers read the token embeddings, which no pod ships"
     n_pods = mesh.shape["pod"]
     assert n_pods == 2, "2-stage pipeline: edge pod + cloud pod"
     axes = mesh.axis_names
@@ -286,6 +288,8 @@ def make_decode_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
     assert built.has_butterfly and len(built.stages) == 2, \
         "decode pipeline needs a butterfly split (cfg.with_butterfly(...))"
     assert not cfg.is_encdec, "enc-dec archs are out of pipeline scope"
+    assert cfg.hybrid_layer_ids is None, \
+        "hybrid layers read the token embeddings, which no pod ships"
     assert mesh.shape["pod"] == 2, "2-stage pipeline: edge pod + cloud pod"
     axes = mesh.axis_names
     mp = int(mesh.shape["model"]) if "model" in axes else 1

@@ -4,9 +4,10 @@ it must compute exactly what slicing the range out first
 same layers, same weights, same order, so float32 results agree bitwise.
 
 Splits cover a uniform stack (qwen3), a mixture-of-experts stack (a nonzero
-auxiliary loss) and stacks whose repeat unit holds two layers (gemma3's
-local/global pair, zamba2's mamba/shared-attention pair), where odd
-boundaries peel single layers out of a unit."""
+auxiliary loss), stacks whose repeat unit holds two layers (gemma3's
+local/global pair, xlstm's mLSTM/sLSTM pair), where odd boundaries peel
+single layers out of a unit, and zamba2's hybrid stack, whose hybrid layer
+reads the token embeddings carried beside the residual."""
 import dataclasses
 
 import jax
@@ -22,7 +23,7 @@ from repro.models.parallel import LOCAL
 B, S = 2, 12
 # stack depth of each config; the splits are every boundary inside it
 CONFIGS = {"qwen3-8b": 4, "qwen3-moe-235b-a22b": 4, "gemma3-12b": 4,
-           "zamba2-7b": 4}
+           "zamba2-7b": 4, "xlstm-125m": 4}
 CASES = [(name, split) for name, n in CONFIGS.items()
          for split in range(1, n)]
 
@@ -35,9 +36,9 @@ def _build(name):
     return cfg, list(built.stages[0]), params
 
 
-def _run_both(cfg, segs, params, lo, hi, x, *, mode, cache, pos):
+def _run_both(cfg, segs, params, lo, hi, x, *, mode, cache, pos, x_emb):
     kw = dict(cfg=cfg, pctx=LOCAL, mode=mode, pos=pos,
-              shared_params=params.get("shared_attn"))
+              shared_params=params.get("shared_attn"), x_emb=x_emb)
 
     @jax.jit
     def in_place(stage, x, cache):
@@ -67,25 +68,30 @@ def test_in_place_range_matches_sliced_range(name, split):
     cfg, segs, params = _build(name)
     assert cfg.dtype == "float32"
     rng = np.random.default_rng(split)
+    hybrid = cfg.hybrid_layer_ids is not None
+
+    def rows(n):
+        return jnp.asarray(rng.standard_normal((B, n, cfg.d_model)),
+                           jnp.float32)
     for lo, hi in ((0, split), (split, cfg.num_layers)):
-        x = jnp.asarray(rng.standard_normal((B, S, cfg.d_model)),
-                        jnp.float32)
+        x = rows(S)
         got, want = _run_both(cfg, segs, params, lo, hi, x, mode="prefill",
-                              cache=None, pos=None)
+                              cache=None, pos=None,
+                              x_emb=rows(S) if hybrid else None)
         _assert_bitwise(got, want, f"prefill [{lo}, {hi})")
         # one decode step against the range's prefill caches
-        step = jnp.asarray(rng.standard_normal((B, 1, cfg.d_model)),
-                           jnp.float32)
+        step = rows(1)
         pos = jnp.full((B,), S - 1, jnp.int32)
         got, want = _run_both(cfg, segs, params, lo, hi, step,
-                              mode="decode", cache=want[1], pos=pos)
+                              mode="decode", cache=want[1], pos=pos,
+                              x_emb=rows(1) if hybrid else None)
         _assert_bitwise(got, want, f"decode [{lo}, {hi})")
 
 
 def test_cases_peel_inside_multi_layer_units():
     """The two-layer-unit configs really exercise peels: some split of
     each lands inside a unit."""
-    for name in ("gemma3-12b", "zamba2-7b"):
+    for name in ("gemma3-12b", "xlstm-125m"):
         _, segs, _ = _build(name)
         assert [(len(s.unit), s.repeats) for s in segs] == [(2, 2)], name
         kinds = {span[0] for split in range(1, CONFIGS[name])

@@ -28,9 +28,11 @@ PARENTS = {
     ("engine.sample", None): ("engine.admit",),
 }
 
-ARGS = {"split.edge_half": {"rows"}, "split.cloud_half": {"rows"},
-        "split.edge_step": {"pos"}, "split.pad_cache": {"stage"},
-        "engine.admit": {"uid", "rows"}, "engine.write_slot": {"slot"},
+ARGS = {"split.edge_half": {"rows", "ids_bytes"},
+        "split.cloud_half": {"rows"},
+        "split.edge_step": {"pos", "ids_bytes"}, "split.pad_cache": {"stage"},
+        "engine.admit": {"uid", "rows", "state_bytes", "kv_bytes"},
+        "engine.write_slot": {"slot"},
         "engine.sample": {"uid"}, "engine.step": {"active", "slots"},
         "engine.stream_step": {"uid", "pos"}, "sync": set()}
 
@@ -54,17 +56,15 @@ def program_spans(logdir):
     return sorted(out, key=lambda s: s[1])
 
 
-@pytest.fixture(scope="module")
-def served(tmp_path_factory):
+def _serve(cfg, logdir):
     """Spans of one handoff request (two batched steps with two slots, one
-    of them in use) and one streamed request (two streamed tokens)."""
-    cfg = dataclasses.replace(get_config("qwen3-8b").reduced(), num_layers=2)
+    of them in use) and one streamed request (two streamed tokens), split
+    after layer 1."""
     bank = SplitModelBank(cfg, 16, seed=0)
     r = bank.runner(1)
     eng = r.make_engine(max_batch=2, max_len=MAX_LEN, seed=0)
     toks = np.random.default_rng(5).integers(
         0, cfg.vocab_size, size=(1, PROMPT)).astype(np.int32)
-    logdir = str(tmp_path_factory.mktemp("trace"))
     jax.profiler.start_trace(logdir)
     try:
         payload, scales, c0 = r.edge_half(r.params, toks)
@@ -87,7 +87,13 @@ def served(tmp_path_factory):
         jax.block_until_ready((edge_cache, cloud_cache))
     finally:
         jax.profiler.stop_trace()
-    return program_spans(logdir), handoff, streamed
+    return program_spans(logdir), handoff, streamed, eng
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    cfg = dataclasses.replace(get_config("qwen3-8b").reduced(), num_layers=2)
+    return _serve(cfg, str(tmp_path_factory.mktemp("trace")))[:3]
 
 
 def test_every_span_with_its_args(served):
@@ -141,3 +147,26 @@ def test_step_counts_the_slots_in_use(served):
     assert len(steps) == NEW - 1
     assert all(a == {"active": 1, "slots": 2} for a in steps)
 
+
+
+def test_hybrid_spans_count_ids_and_state_bytes(tmp_path):
+    """zamba2 (a Mamba2 layer, then a hybrid layer past the split): the edge
+    puts 2 bytes of token id a row on the wire, and admission writes the
+    slot's recurrent state whole and the hybrid invocation's KV for the
+    prompt's rows; a streamed request writes nothing to the pool."""
+    cfg = dataclasses.replace(get_config("zamba2-7b").reduced(),
+                              num_layers=2)
+    spans, handoff, streamed, eng = _serve(cfg, str(tmp_path))
+    by = lambda n: [a for m, _, _, a in spans if m == n]  # noqa: E731
+    assert [a["ids_bytes"] for a in by("split.edge_half")] == [2 * PROMPT]
+    assert [a["ids_bytes"] for a in by("split.edge_step")] == \
+        [2] * (NEW - 1)
+    s = cfg.ssm
+    state = 2 * (s.num_heads * s.head_dim * s.state_dim * 4 + (
+        s.conv_width - 1) * (s.num_heads * s.head_dim +
+                             2 * s.n_groups * s.state_dim) * 4)
+    kv = 2 * PROMPT * cfg.num_kv_heads * cfg.resolved_head_dim * 4
+    admits = {a["uid"]: (a["state_bytes"], a["kv_bytes"])
+              for a in by("engine.admit")}
+    assert admits == {handoff.uid: (state, kv), streamed.uid: (0, 0)}
+    assert eng._slot_bytes(PROMPT) == {"state_bytes": state, "kv_bytes": kv}

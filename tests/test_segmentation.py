@@ -17,7 +17,7 @@ kinds = st.sampled_from([
     LayerDef(mixer="mamba", ffn=None),
     LayerDef(mixer="mlstm", ffn=None),
     LayerDef(mixer="slstm", ffn=None),
-    LayerDef(mixer="attn", ffn="mlp", shared=True),
+    LayerDef(mixer="mamba", ffn=None, hybrid=0),
 ])
 
 
@@ -54,8 +54,12 @@ def test_assigned_arch_patterns():
     assert [d.window for d in g3[:6]] == [1024] * 5 + [None]
     zam = build_layer_defs(get_config("zamba2-7b"))
     assert len(zam) == 81
-    assert sum(d.shared for d in zam) == 13           # shared attn blocks
-    assert sum(d.mixer == "mamba" for d in zam) == 68
+    # 13 invocations of the shared blocks, at the published hybrid_layer_ids
+    # (gaps of 5 and 6), and every layer keeps its own Mamba2 mixer
+    assert [i for i, d in enumerate(zam) if d.hybrid is not None] == \
+        [6, 11, 17, 23, 29, 35, 41, 47, 53, 59, 65, 71, 77]
+    assert [d.hybrid for d in zam if d.hybrid is not None] == list(range(13))
+    assert sum(d.mixer == "mamba" for d in zam) == 81
     xl = build_layer_defs(get_config("xlstm-125m"))
     assert [d.mixer for d in xl[:3]] == ["mlstm", "mlstm", "slstm"]
     l4 = build_layer_defs(get_config("llama4-maverick-400b-a17b"))
@@ -66,7 +70,13 @@ def test_assigned_arch_patterns():
 
 def test_segment_counts_small():
     """Scan-friendliness: each arch compresses to few segments."""
-    for arch in ("qwen3-14b", "gemma3-12b", "zamba2-7b", "xlstm-125m",
+    for arch in ("qwen3-14b", "gemma3-12b", "xlstm-125m",
                  "qwen3-moe-235b-a22b", "llama4-maverick-400b-a17b"):
         segs = segmentize(build_layer_defs(get_config(arch)))
         assert len(segs) <= 3, (arch, len(segs))
+    # zamba2's invocations are each their own (no two are alike): each is a
+    # one-layer segment, and each run of plain Mamba2 layers between them
+    # one scanned segment, never unrolled into a longer unit
+    segs = segmentize(build_layer_defs(get_config("zamba2-7b")))
+    assert len(segs) == 27 and all(len(s.unit) == 1 for s in segs)
+    assert [s.repeats for s in segs[:4]] == [6, 1, 4, 1]
