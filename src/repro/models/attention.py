@@ -216,13 +216,19 @@ def attention_fullseq(params, x, *, cfg: ModelConfig, window: Optional[int],
 
 def attention_decode(params, x, cache, cache_pos, *, cfg: ModelConfig,
                      window: Optional[int], rope: bool = True,
-                     scale: Optional[float] = None):
+                     scale: Optional[float] = None, layer):
     """x: (B,1,d). ``cache_pos`` — absolute position of the new token, either
     an int32 scalar (all sequences aligned: dry-run / batch decode) or an
     (B,) vector (ragged serving engine).  When ``window`` is set the cache
-    length equals the window and is used as a ring buffer (slot = p % W)."""
+    length equals the window and is used as a ring buffer (slot = p % W).
+
+    ``cache`` is a stack of layers' caches (a leading layer axis): the call
+    writes each sequence's new row into layer ``layer`` of it and returns
+    the whole stack; the scores read that layer from the updated stack.  A
+    layer loop that carries the stack so updates it in place, where handing
+    one layer's cache back would copy it out and in."""
     B, _, _ = x.shape
-    T = cache["k"].shape[1]
+    T = cache["k"].shape[2]
     pos = jnp.asarray(cache_pos, jnp.int32)
     scalar_pos = pos.ndim == 0
     positions = (jnp.full((B, 1), pos, jnp.int32) if scalar_pos
@@ -231,16 +237,37 @@ def attention_decode(params, x, cache, cache_pos, *, cfg: ModelConfig,
     if scalar_pos:
         # aligned path: dynamic_update_slice shards cleanly under GSPMD
         slot = pos % T if window is not None else pos
-        k = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
-        v = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
+
+        def write(stack, new):
+            return jax.lax.dynamic_update_slice(
+                stack, new[None].astype(stack.dtype), (layer, 0, slot, 0, 0))
         slots = jnp.broadcast_to(slot, (B,))
     else:
         slots = pos % T if window is not None else jnp.minimum(pos, T - 1)
-        b_idx = jnp.arange(B)
-        k = cache["k"].at[b_idx, slots].set(k_new[:, 0].astype(cache["k"].dtype))
-        v = cache["v"].at[b_idx, slots].set(v_new[:, 0].astype(cache["v"].dtype))
+        # Which write keeps the pool in place depends on the TPU's default
+        # layout of the cache.  With a head_dim of whole 128-lane tiles,
+        # one scatter of all rows does; one dynamic_update_slice per slot
+        # made XLA relayout the pool (AOT, qwen3-8b's 16 x 4224 step:
+        # temporaries 2.77 GB against the scatter's 0.48 GB).  Otherwise
+        # the layout puts the positions minor and the scatter relayouts
+        # every layer's whole cache (zamba2's head_dim 224: 7.75 GB), so
+        # each slot's row is written on its own.
+        if k_new.shape[-1] % 128:
+            def write(stack, new):
+                for b in range(B):
+                    stack = jax.lax.dynamic_update_slice(
+                        stack, new[b][None, None].astype(stack.dtype),
+                        (layer, b, slots[b], 0, 0))
+                return stack
+        else:
+            b_idx = jnp.arange(B)
+
+            def write(stack, new):
+                return stack.at[layer, b_idx, slots].set(
+                    new[:, 0].astype(stack.dtype))
+    kv = {"k": write(cache["k"], k_new), "v": write(cache["v"], v_new)}
+    k, v = (jax.lax.dynamic_index_in_dim(kv[n], layer, keepdims=False)
+            for n in ("k", "v"))
     scores = _gqa_scores(q, k, cfg, scale)               # (B,K,G,1,T)
     idx = jnp.arange(T)[None, :]
     posb = positions                                      # (B,1)
@@ -253,7 +280,7 @@ def attention_decode(params, x, cache, cache_pos, *, cfg: ModelConfig,
         valid = idx <= posb
     out = _attend(scores, v, valid[:, None, None, None, :], x.dtype)
     out = out.reshape(B, 1, -1) @ params["wo"]
-    return out, {"k": k, "v": v}
+    return out, kv
 
 
 # ---------------------------------------------------------------------------

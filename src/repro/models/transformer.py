@@ -232,13 +232,17 @@ def apply_layer_range(segments: Sequence[Segment], stage_params, x, lo: int,
     takes repeat ``i`` by dynamic index: no range of the stack is copied
     out.  A peel scans its one index over the one layer's leaves the same
     way.  ``range_cache`` must be structured per :func:`range_segments`
-    (see init_stage_cache); it is scanned as ``xs``.  ``x_emb`` (the token
-    embeddings, for hybrid layers) is carried beside the residual.  Same
-    layers, weights and order as :func:`slice_stage_params` followed by
-    :func:`apply_stage`."""
+    (see init_stage_cache).  In decode each span's stacked cache is carried
+    through its scan and every layer writes its new rows and state into it
+    at its index, so a step updates its cache in place (the caller donates
+    it); prefill scans no cache in and emits each layer's as ``ys``.
+    ``x_emb`` (the token embeddings, for hybrid layers) is carried beside
+    the residual.  Same layers, weights and order as
+    :func:`slice_stage_params` followed by :func:`apply_stage`."""
     kw = dict(cfg=cfg, pctx=pctx, mode=mode, pos=pos, enc_out=enc_out,
               shared_params=shared_params, use_kernel=use_kernel,
               causal=causal, x_emb=x_emb)
+    decode = mode == "decode"
     aux_total = jnp.zeros((2,), jnp.float32)
     new_caches = []
     for k, span in enumerate(_range_spans(segments, lo, hi)):
@@ -251,21 +255,22 @@ def apply_layer_range(segments: Sequence[Segment], stage_params, x, lo: int,
             seg = Segment(unit=(segments[si].unit[upos],), repeats=1)
             unit_params = [stage_params[si][upos]]
 
-        def body(carry, xs, seg=seg, unit_params=unit_params):
-            xc, aux_sum = carry
-            i, unit_cache = xs
+        def body(carry, i, seg=seg, unit_params=unit_params, r0=r0):
+            xc, aux_sum, stack = carry
             layer = jax.tree.map(
                 lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
                 unit_params)
-            xc, aux_sum, _, nc = _apply_unit(seg, layer, unit_cache, xc,
-                                             aux_sum, None, **kw)
-            return (xc, aux_sum), nc
+            xc, aux_sum, _, nc = _apply_unit(
+                seg, layer, stack, xc, aux_sum, None,
+                layer=i - r0 if decode else None, **kw)
+            return (xc, aux_sum, nc if decode else None), \
+                None if decode else nc
 
-        cache = None if range_cache is None else range_cache[k]
-        (x, aux), nc = jax.lax.scan(
-            body, (x, jnp.zeros((2,), jnp.float32)),
-            (jnp.arange(r0, r1), cache), unroll=_scan_unroll(r1 - r0))
-        new_caches.append(nc)
+        stack = range_cache[k] if decode else None
+        (x, aux, stack), nc = jax.lax.scan(
+            body, (x, jnp.zeros((2,), jnp.float32), stack),
+            jnp.arange(r0, r1), unroll=_scan_unroll(r1 - r0))
+        new_caches.append(stack if decode else nc)
         aux_total = aux_total + aux
     return x, new_caches, aux_total
 
@@ -483,13 +488,14 @@ def to_ring(kv: dict, window: int) -> dict:
 
 
 def _hybrid_block(ldef: LayerDef, lparams, x, x_emb, *, cfg: ModelConfig,
-                  mode: str, cache, pos, shared_params):
+                  mode: str, cache, pos, shared_params, layer=None):
     """Invocation ``ldef.hybrid`` of zamba2's shared blocks: block
     ``k % num_mem_blocks`` attends over ``concat(x, x_emb)`` (softmax scale
     ``(hd/2)^-0.5``), its MLP's gate/up projection gets invocation k's
     LoRA adapter, and the layer's own ``linear`` projects the result.
     Returns (t, kv): what is added to the mixer's input, and the
-    invocation's KV cache."""
+    invocation's KV cache (in decode, the stack ``layer`` indexes, with the
+    new rows written in place)."""
     k = ldef.hybrid
     blk = shared_params["blocks"][k % cfg.num_mem_blocks]
     adapter = shared_params["adapters"][k]
@@ -499,7 +505,7 @@ def _hybrid_block(ldef: LayerDef, lparams, x, x_emb, *, cfg: ModelConfig,
     if mode == "decode":
         out, kv = attn.attention_decode(blk["mixer"], h, cache["kv"], pos,
                                         cfg=cfg, window=ldef.window,
-                                        scale=scale)
+                                        scale=scale, layer=layer)
     else:
         out, kv = attn.attention_fullseq(blk["mixer"], h, cfg=cfg,
                                          window=ldef.window, scale=scale)
@@ -514,12 +520,35 @@ def _hybrid_block(ldef: LayerDef, lparams, x, x_emb, *, cfg: ModelConfig,
     return t @ lparams["linear"], kv
 
 
+def _layer_of(stack, layer):
+    """Layer ``layer`` of a stacked cache tree (a leading layer axis)."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+        stack)
+
+
+def _state_step(decode_fn, params, h, stack, layer, cfg):
+    """A recurrent mixer's decode step against layer ``layer`` of the stack
+    of its states: the layer's new state is written back into the stack at
+    its index.  Returns (out, new stack)."""
+    state = {k: a for k, a in stack.items() if k != "kv"}
+    out, st = decode_fn(params, h, _layer_of(state, layer), cfg=cfg)
+    return out, {**stack, **{
+        k: jax.lax.dynamic_update_index_in_dim(stack[k], a, layer, 0)
+        for k, a in st.items()}}
+
+
 def apply_layer(ldef: LayerDef, lparams, x, *, cfg: ModelConfig,
                 pctx: ParallelContext, mode: str, cache, pos,
                 enc_out=None, shared_params=None, use_kernel: bool = False,
                 causal: bool = True, h_pre=None, pending=None,
-                defer_psum: bool = False, x_emb=None):
+                defer_psum: bool = False, x_emb=None, layer=None):
     """Returns (x, new_cache, aux_vec[2], pending_out).
+
+    In decode mode ``cache`` is the stack of this layer's caches over the
+    layer loop that runs it (a leading layer axis) and ``layer`` its index
+    in that stack: the layer writes its new KV rows and its recurrent state
+    into the stack in place, and ``new_cache`` is the whole stack.
 
     ``h_pre`` short-circuits the input RMSNorm: a caller that already holds
     ``rms_norm(x, norm1)`` (the fused dequant+restore+norm kernel at the
@@ -542,12 +571,14 @@ def apply_layer(ldef: LayerDef, lparams, x, *, cfg: ModelConfig,
         x = x + model_psum(pending, pctx)
     pending_out = jnp.zeros_like(x) if defer_psum else None
 
+    assert (mode == "decode") == (layer is not None), \
+        "decode, and only decode, runs against a stacked cache"
     t = kv = None
     if ldef.hybrid is not None:
         assert h_pre is None, "a hybrid layer's norm reads its shared block"
         t, kv = _hybrid_block(ldef, p, x, x_emb, cfg=cfg, mode=mode,
                               cache=cache, pos=pos,
-                              shared_params=shared_params)
+                              shared_params=shared_params, layer=layer)
     h = h_pre if h_pre is not None else \
         rms_norm(x if t is None else x + t, p["norm1"], cfg.rms_eps)
     rope = not cfg.is_encdec          # whisper uses sinusoid embeds, no RoPE
@@ -555,7 +586,7 @@ def apply_layer(ldef: LayerDef, lparams, x, *, cfg: ModelConfig,
         if mode == "decode":
             out, kv = attn.attention_decode(
                 p["mixer"], h, cache["kv"], pos, cfg=cfg, window=ldef.window,
-                rope=rope)
+                rope=rope, layer=layer)
             new_cache = {"kv": kv}
         else:
             out, kv = attn.attention_fullseq(
@@ -569,14 +600,14 @@ def apply_layer(ldef: LayerDef, lparams, x, *, cfg: ModelConfig,
         if ldef.cross:
             hc = rms_norm(x, p["norm_cross"], cfg.rms_eps)
             if mode == "decode":
-                ckv = cache["cross_kv"]
+                ckv = _layer_of(cache["cross_kv"], layer)
             else:
                 ckv = attn.encoder_kv(p["cross"], enc_out, cfg=cfg)
             x = x + attn.cross_attention(p["cross"], hc, ckv, cfg=cfg)
             if mode == "prefill":
                 new_cache["cross_kv"] = ckv
             elif mode == "decode":
-                new_cache["cross_kv"] = ckv
+                new_cache["cross_kv"] = cache["cross_kv"]
         if ldef.ffn is not None:
             h2 = rms_norm(x, p["norm2"], cfg.rms_eps)
             if ldef.ffn == "mlp":
@@ -594,8 +625,8 @@ def apply_layer(ldef: LayerDef, lparams, x, *, cfg: ModelConfig,
                                        moe_aux["router_z"]])
     elif ldef.mixer == "mamba":
         if mode == "decode":
-            out, st = ssm_lib.mamba_decode(p["mixer"], h, cache, cfg=cfg)
-            new_cache = st
+            out, new_cache = _state_step(ssm_lib.mamba_decode, p["mixer"], h,
+                                         cache, layer, cfg)
         else:
             out, st = ssm_lib.mamba_fullseq(p["mixer"], h, cfg=cfg,
                                             return_state=(mode == "prefill"))
@@ -605,19 +636,19 @@ def apply_layer(ldef: LayerDef, lparams, x, *, cfg: ModelConfig,
         x = x + out
     elif ldef.mixer == "mlstm":
         if mode == "decode":
-            out, st = xlstm_lib.mlstm_decode(p["mixer"], h, cache, cfg=cfg)
+            out, new_cache = _state_step(xlstm_lib.mlstm_decode, p["mixer"],
+                                         h, cache, layer, cfg)
         else:
-            out, st = xlstm_lib.mlstm_fullseq(p["mixer"], h, cfg=cfg,
-                                              return_state=(mode == "prefill"))
-        new_cache = st
+            out, new_cache = xlstm_lib.mlstm_fullseq(
+                p["mixer"], h, cfg=cfg, return_state=(mode == "prefill"))
         x = x + out
     elif ldef.mixer == "slstm":
         if mode == "decode":
-            out, st = xlstm_lib.slstm_decode(p["mixer"], h, cache, cfg=cfg)
+            out, new_cache = _state_step(xlstm_lib.slstm_decode, p["mixer"],
+                                         h, cache, layer, cfg)
         else:
-            out, st = xlstm_lib.slstm_fullseq(p["mixer"], h, cfg=cfg,
-                                              return_state=(mode == "prefill"))
-        new_cache = st
+            out, new_cache = xlstm_lib.slstm_fullseq(
+                p["mixer"], h, cfg=cfg, return_state=(mode == "prefill"))
         x = x + out
     else:
         raise ValueError(ldef.mixer)
@@ -631,9 +662,12 @@ def apply_layer(ldef: LayerDef, lparams, x, *, cfg: ModelConfig,
 
 def _apply_unit(seg: Segment, unit_params, unit_cache, x, aux_sum, pending, *,
                 cfg, pctx, mode, pos, enc_out, shared_params, use_kernel,
-                causal, first_h=None, defer_psum=False, x_emb=None):
+                causal, first_h=None, defer_psum=False, x_emb=None,
+                layer=None):
     """One pass over a segment's repeat unit; shared by the scan body and
-    the peeled first repeat."""
+    the peeled first repeat.  In decode ``unit_cache`` holds the stacked
+    caches of the loop's repeats and ``layer`` is this repeat's index in
+    them (see :func:`apply_layer`)."""
     new_caches = []
     for i, ldef in enumerate(seg.unit):
         c = None if unit_cache is None else unit_cache[i]
@@ -642,7 +676,7 @@ def _apply_unit(seg: Segment, unit_params, unit_cache, x, aux_sum, pending, *,
             cache=c, pos=pos, enc_out=enc_out, shared_params=shared_params,
             use_kernel=use_kernel, causal=causal,
             h_pre=first_h if i == 0 else None, pending=pending,
-            defer_psum=defer_psum, x_emb=x_emb)
+            defer_psum=defer_psum, x_emb=x_emb, layer=layer)
         aux_sum = aux_sum + aux
         new_caches.append(nc)
     return x, aux_sum, pending, new_caches
@@ -660,47 +694,51 @@ def apply_segment(seg: Segment, seg_params, x, *, cfg, pctx, mode, seg_cache,
     cannot live inside it) and the remaining repeats scan as usual.
     ``overlap_psum`` threads a deferred MLP partial (``pending``) through
     the repeats — see :func:`apply_layer`; the caller flushes the returned
-    pending."""
+    pending.  In decode the segment's stacked cache is carried through the
+    repeats and written in place, as :func:`apply_layer_range` does."""
     kw = dict(cfg=cfg, pctx=pctx, mode=mode, pos=pos, enc_out=enc_out,
               shared_params=shared_params, use_kernel=use_kernel,
               causal=causal, defer_psum=overlap_psum, x_emb=x_emb)
+    decode = mode == "decode"
     if overlap_psum and pending is None:
         pending = jnp.zeros_like(x)
     aux0 = jnp.zeros((2,), jnp.float32)
     peel = first_h is not None
     if peel:
         p0 = jax.tree.map(lambda a: a[0], seg_params)
-        c0 = None if seg_cache is None else \
+        c0 = seg_cache if decode or seg_cache is None else \
             jax.tree.map(lambda a: a[0], seg_cache)
         x, aux0, pending, first_caches = _apply_unit(
-            seg, p0, c0, x, aux0, pending, first_h=first_h, **kw)
+            seg, p0, c0, x, aux0, pending, first_h=first_h,
+            layer=0 if decode else None, **kw)
+        if decode:
+            seg_cache = first_caches
         if seg.repeats == 1:
-            new_cache = jax.tree.map(lambda a: a[None], first_caches)
+            new_cache = first_caches if decode else \
+                jax.tree.map(lambda a: a[None], first_caches)
             return x, new_cache, aux0, pending
         seg_params = jax.tree.map(lambda a: a[1:], seg_params)
-        seg_cache = None if seg_cache is None else \
-            jax.tree.map(lambda a: a[1:], seg_cache)
+        if not decode and seg_cache is not None:
+            seg_cache = jax.tree.map(lambda a: a[1:], seg_cache)
 
     def body(carry, xs):
-        if overlap_psum:
-            xc, pend, aux_sum = carry
-        else:
-            (xc, aux_sum), pend = carry, None
-        unit_params, unit_cache = xs
-        xc, aux_sum, pend, new_caches = _apply_unit(
-            seg, unit_params, unit_cache, xc, aux_sum, pend, **kw)
-        carry = (xc, pend, aux_sum) if overlap_psum else (xc, aux_sum)
-        return carry, new_caches
+        xc, pend, aux_sum, stack = carry
+        unit_params, li, unit_cache = xs
+        xc, aux_sum, pend, nc = _apply_unit(
+            seg, unit_params, stack if decode else unit_cache, xc, aux_sum,
+            pend, layer=li if decode else None, **kw)
+        return (xc, pend, aux_sum, nc if decode else None), \
+            None if decode else nc
 
-    reps = seg.repeats - 1 if peel else seg.repeats
-    init = (x, pending, aux0) if overlap_psum else (x, aux0)
-    carry, new_cache = jax.lax.scan(body, init, (seg_params, seg_cache),
-                                    length=reps, unroll=_scan_unroll(reps))
-    if overlap_psum:
-        x, pending, aux = carry
-    else:
-        (x, aux), pending = carry, None
-    if peel:
+    start = 1 if peel else 0
+    init = (x, pending, aux0, seg_cache if decode else None)
+    (x, pending, aux, stack), new_cache = jax.lax.scan(
+        body, init, (seg_params, jnp.arange(start, seg.repeats),
+                     None if decode else seg_cache),
+        unroll=_scan_unroll(seg.repeats - start))
+    if decode:
+        new_cache = stack
+    elif peel:
         new_cache = jax.tree.map(
             lambda f, r: jnp.concatenate([f[None], r], axis=0),
             first_caches, new_cache)

@@ -717,7 +717,9 @@ class SplitModelBank:
             return ((self._tp_specs(), P(), spec0, P()), (P(), P(), spec0))
 
         edge_step = self._mp_wrap(edge_step, mp, specs)
-        return jax.jit(edge_step)
+        # every caller replaces its cache with the one handed back, so the
+        # step takes the old one over and writes its rows in place
+        return jax.jit(edge_step, donate_argnums=2)
 
     def _make_cloud_step(self, split: int, mp: int = 1):
         """Streamed-decode cloud half: restore one arrived row and run layers
@@ -819,7 +821,8 @@ class SplitRunner:
         the edge-resident stage-0 decode cache (pad with
         :meth:`pad_decode_cache` first), ``pos`` (B,) int32 write positions,
         held on the host.  Returns ``(payload, scales, new_cache0)`` — one
-        wire row per batch element."""
+        wire row per batch element.  ``cache0`` is donated to the step: the
+        caller keeps ``new_cache0`` in its place."""
         import jax.numpy as jnp
         bank = self.bank
         with span("split.edge_step", pos=int(pos[0]),

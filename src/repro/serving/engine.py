@@ -8,7 +8,15 @@ stopping.  The decode hot path is one jitted step per batch: sampling
 so ``step()`` costs a single host sync for the whole slot pool instead of a
 per-slot ``device_get`` + Python argmax; per-step logits snapshots are
 opt-in (``record_logits``).  Slot admission writes the cache pool through
-one jitted donated update instead of an eager per-leaf dispatch.
+one jitted donated update instead of an eager per-leaf dispatch.  The
+pool is donated to every decode step too: the step writes one row per
+slot and layer into it in place and hands it back as ``engine.cache``, so
+no caller may keep an old ``engine.cache`` across a step or an admission
+(its buffers are deleted).  ``donated_steps`` counts the steps that took
+their cache over, beside ``decode_steps``.  A wrapper put in place of
+``_step``/``_stream_step`` (to observe or alter a step) runs on the
+step that leaves its cache as it was (the same program, run on a copy),
+since it may keep or hand back the cache it is given.
 
 The engine's forward functions are pluggable: the split runtime's
 ``SplitModelBank`` supplies jitted prefill/decode closures over the shared
@@ -25,14 +33,14 @@ For the streamed decode transport the engine adds a single-slot entry
 (``submit_streamed`` + ``stream_step``): the request holds no cache-pool
 slot — its cloud-side stage cache lives with the caller — and each arrived
 ``(1, d_r)`` row runs through the bank-shared compiled cloud step with
-in-graph sampling.
+in-graph sampling, which takes the request's cache over the same way.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import weakref
-from typing import Callable, List, Optional
+from typing import Callable, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -87,10 +95,40 @@ def _span(name: str, **args):
     return span(name, **args)
 
 
-# decode_fn -> jitted (decode + in-graph sampling) step, shared by every
+# decode_fn -> jitted (decode + in-graph sampling) steps, shared by every
 # engine using the same decode closure (e.g. all engines of one bank split)
 _STEP_FNS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 _STREAM_STEP_FNS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+class _Steps(NamedTuple):
+    """One compiled sampled step, called two ways: ``donating`` takes its
+    cache argument over and updates it in place; ``kept`` leaves the cache
+    it is given as it was (it runs the same program on a device copy)."""
+    kept: Callable
+    donating: Callable
+
+    @classmethod
+    def of(cls, step, cache_arg: int) -> "_Steps":
+        donating = jax.jit(step, donate_argnums=cache_arg)
+
+        def kept(*args):
+            args = list(args)
+            args[cache_arg] = jax.device_put(args[cache_arg], may_alias=False)
+            return donating(*args)
+        return cls(kept, donating)
+
+
+def _consumed(cache) -> int:
+    """1 when a dispatched step took ``cache`` over (its buffers were
+    donated and are deleted), else 0.  A host check: it waits for nothing."""
+    return int(jax.tree.leaves(cache)[0].is_deleted())
+
+
+def _program(step, steps: _Steps):
+    """What runs for ``step``: the engine's own step donates its cache; a
+    step put in its place runs as given."""
+    return steps.donating if step is steps.kept else step
 
 
 def _sample_ingraph(row, key, temps):
@@ -123,9 +161,9 @@ def _sampled_step(decode_fn):
         toks, key = _sample_ingraph(row, key, temps)
         return toks, row, caches, key
 
-    jitted = jax.jit(step)
-    _STEP_FNS[decode_fn] = jitted
-    return jitted
+    steps = _Steps.of(step, cache_arg=2)
+    _STEP_FNS[decode_fn] = steps
+    return steps
 
 
 def _sampled_stream_step(stream_fn):
@@ -145,9 +183,9 @@ def _sampled_stream_step(stream_fn):
         toks, key = _sample_ingraph(row, key, temps)
         return toks, row, cache, key
 
-    jitted = jax.jit(step)
-    _STREAM_STEP_FNS[stream_fn] = jitted
-    return jitted
+    steps = _Steps.of(step, cache_arg=3)
+    _STREAM_STEP_FNS[stream_fn] = steps
+    return steps
 
 
 class ServingEngine:
@@ -178,14 +216,21 @@ class ServingEngine:
         # are weak-keyed, so each shared jitted step lives exactly as long
         # as its closure
         self._decode = decode_fn or self._decode_fn
-        self._step = _sampled_step(self._decode)
+        self._steps = _sampled_step(self._decode)
         self._stream = stream_fn
-        self._stream_step = _sampled_stream_step(stream_fn) \
+        self._stream_steps = _sampled_stream_step(stream_fn) \
+            if stream_fn is not None else None
+        # the steps as the engine calls them; a wrapper put in their place
+        # (one that observes or alters a step) may keep or hand back the
+        # cache it is given, so what it calls leaves that cache as it was
+        self._step = self._steps.kept
+        self._stream_step = self._stream_steps.kept \
             if stream_fn is not None else None
         self._last = np.zeros((max_batch, 1), np.int32)     # last token/slot
         self._temps = np.zeros((max_batch,), np.float32)
         self._uid = 0
         self.decode_steps = 0
+        self.donated_steps = 0      # steps that consumed the cache passed in
         # opt-in wall-clock attribution of the fused sampling steps
         # (metrics.JitProfiler); profile_key distinguishes engines sharing
         # the module-level step caches (e.g. the bank's (split, mp))
@@ -262,15 +307,19 @@ class ServingEngine:
         """Single-slot streamed decode: apply one externally-computed edge
         row to ``cache`` (the request's cloud-side stage cache) through the
         shared compiled cloud step (one dispatch: restore + layers [split, N)
-        + sampling) and return ``(token, new_cache)``."""
+        + sampling) and return ``(token, new_cache)``.  ``cache`` is donated
+        to the step: the caller keeps ``new_cache`` in its place."""
         assert self._stream_step is not None, "engine built without stream_fn"
         with _span("engine.stream_step", uid=req.uid, pos=pos):
+            old = cache
             toks, row, cache, self.key = self._dispatch(
-                "engine_stream_step", self._stream_step,
+                "engine_stream_step",
+                _program(self._stream_step, self._stream_steps),
                 self.params, jax.tree.map(jnp.asarray, payload),
                 jnp.asarray(scales), cache,
                 jnp.asarray([pos], jnp.int32), self.key,
                 jnp.asarray([req.temperature], jnp.float32))
+            self.donated_steps += _consumed(old)
             with _span("sync"):
                 tok = int(jax.device_get(toks)[0])
             if req.record_logits:
@@ -376,16 +425,21 @@ class ServingEngine:
         active = self.num_active
         if not active:
             return
-        with _span("engine.step", active=active, slots=self.max_batch):
+        with _span("engine.step", active=active,
+                   slots=self.max_batch) as step_span:
             # .copy() is load-bearing: on the CPU backend jnp.asarray can
             # alias the numpy buffer zero-copy, and the in-place
             # `positions[i] += 1` below would race with the still-dispatching
             # decode (observed as a rare wrong-slot cache write under load)
             pos = jnp.asarray(self.positions.copy())
+            old = self.cache
             toks, logits, self.cache, self.key = self._dispatch(
-                "engine_step", self._step,
+                "engine_step", _program(self._step, self._steps),
                 self.params, jnp.asarray(self._last.copy()), self.cache, pos,
                 self.key, jnp.asarray(self._temps.copy()))
+            donated = _consumed(old)
+            step_span.set_metadata(donated=donated)
+            self.donated_steps += donated
             with _span("sync"):                        # the one host sync
                 toks_host = np.asarray(jax.device_get(toks))
             logits_host = None

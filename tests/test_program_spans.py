@@ -33,7 +33,8 @@ ARGS = {"split.edge_half": {"rows", "ids_bytes"},
         "split.edge_step": {"pos", "ids_bytes"}, "split.pad_cache": {"stage"},
         "engine.admit": {"uid", "rows", "state_bytes", "kv_bytes"},
         "engine.write_slot": {"slot"},
-        "engine.sample": {"uid"}, "engine.step": {"active", "slots"},
+        "engine.sample": {"uid"},
+        "engine.step": {"active", "slots", "donated"},
         "engine.stream_step": {"uid", "pos"}, "sync": set()}
 
 
@@ -145,7 +146,7 @@ def test_step_counts_the_slots_in_use(served):
     spans, _, _ = served
     steps = [a for n, _, _, a in spans if n == "engine.step"]
     assert len(steps) == NEW - 1
-    assert all(a == {"active": 1, "slots": 2} for a in steps)
+    assert all(a == {"active": 1, "slots": 2, "donated": 1} for a in steps)
 
 
 

@@ -41,11 +41,12 @@ def test_ring_decode_matches_fullseq(window, extra, data):
         pad = window - cache["k"].shape[1]
         cache = {k: jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
                  for k, v in cache.items()}
+    cache = {k: v[None] for k, v in cache.items()}   # a stack of one layer
     out = None
     for t in range(pre, S):
         out, cache = attn.attention_decode(params, x[:, t:t + 1], cache,
                                            jnp.asarray(t, jnp.int32),
-                                           cfg=cfg, window=window)
+                                           cfg=cfg, window=window, layer=0)
     np.testing.assert_allclose(np.asarray(full[:, -1]), np.asarray(out[:, 0]),
                                rtol=2e-4, atol=2e-4)
 

@@ -88,17 +88,18 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     _assert_named(_kernel_instructions(text), name)
 
 
-def _shapes_bank(one_chip, layers: int, split: int):
-    """Full-width qwen3-8b cut to ``layers`` layers in its own dtype (bf16),
-    with shapes in place of weights (the bank's init is swapped for its
-    ``jax.eval_shape`` so nothing is allocated), and the params of the
-    runner for ``split`` as shapes on the described chip."""
+def _shapes_bank(one_chip, layers: int, split: int, arch: str = "qwen3-8b",
+                 **over):
+    """Full-width ``arch`` (qwen3-8b) cut to ``layers`` layers in its own
+    dtype (bf16), with shapes in place of weights (the bank's init is
+    swapped for its ``jax.eval_shape`` so nothing is allocated), and the
+    params of the runner for ``split`` as shapes on the described chip."""
     import dataclasses
 
     from repro.configs import get_config
     from repro.models import model as M
     from repro.runtime.split_exec import SplitModelBank
-    cfg = dataclasses.replace(get_config("qwen3-8b"), num_layers=layers)
+    cfg = dataclasses.replace(get_config(arch), num_layers=layers, **over)
     with pytest.MonkeyPatch.context() as mp:
         real_init = M.init_model
         mp.setattr(M, "init_model", lambda key, built: (jax.eval_shape(
@@ -175,7 +176,8 @@ def test_bank_program_reads_stacked_weights_in_place(stack_bank, one_chip,
     """Each bank program scans its layer range over the whole stacked
     leaves: the compiler keeps less temp than one range's weights (a copy
     of the range would need all of it), and no slice reads a stacked
-    weight parameter."""
+    weight parameter but a one-layer prefetch into VMEM of a leaf named in
+    ``PREFETCHED_ONE_LAYER``."""
     bank, params = stack_bank
 
     def sds(shape, dt):
@@ -187,10 +189,121 @@ def test_bank_program_reads_stacked_weights_in_place(stack_bank, one_chip,
                       for a in jax.tree.leaves(params["stages"][0]))
     range_bytes = stack_bytes * SPLIT // STACK    # both ranges: 2 of 4 layers
     assert compiled.memory_analysis().temp_size_in_bytes < range_bytes
-    weight_slices = re.findall(
-        r"\bslice(?:-start)?\((%params__stages\S*?)[,)]", compiled.as_text())
+    weight_slices = _stacked_weight_slices(compiled.as_text())
     assert not weight_slices, weight_slices
 
+
+# The stacked leaves whose layers the compiler may prefetch into on-chip
+# memory one at a time: in the decode program, whose layer loops carry the
+# cache pool, it reads each layer of ``wv`` with a ``slice-start`` into
+# VMEM, where it had prefetched the whole stack with a ``copy-start``.
+PREFETCHED_ONE_LAYER = ("mixer____wv",)
+
+
+def _stacked_weight_slices(text: str) -> list:
+    """The ``slice``/``slice-start`` instructions of compiled ``text`` that
+    read a stacked weight parameter, less the one exemption: a slice of one
+    layer (leading dimension 1) of a leaf in ``PREFETCHED_ONE_LAYER`` whose
+    result lies in VMEM (``S(1)``), which copies nothing in HBM."""
+    found = []
+    for m in re.finditer(r"= (.*?) \b(slice(?:-start)?)\((%params__stages"
+                         r"\S*?)[,)]", text):
+        result, op, leaf = m.groups()
+        shapes = re.findall(r"\w+\[([\d,]*)\]\{([^}]*)\}", result)
+        # a slice-start's result is (operand, slice, context)
+        dims, layout = shapes[1] if op == "slice-start" else shapes[0]
+        one_layer = dims.split(",")[0] == "1"
+        if not (one_layer and "S(1)" in layout
+                and any(n in leaf for n in PREFETCHED_ONE_LAYER)):
+            found.append(m.group(0))
+    return found
+
+
+_WV_PREFETCH = ("%slice-start = ((bf16[4,4096,1024]{2,1,0:T(8,128)(2,1)}), "
+                "bf16[1,4096,1024]{2,1,0:T(8,128)(2,1)S(1)}, s32[]{:S(2)}) "
+                "slice-start(%params__stages___0__0__0___mixer____wv__.1), "
+                "slice={[0:1], [0:4096], [0:1024]}")
+
+
+@pytest.mark.parametrize("case,line,flagged", [
+    ("one_layer_of_wv_into_vmem", _WV_PREFETCH, False),
+    ("two_layers_of_wv_into_vmem",
+     _WV_PREFETCH.replace("bf16[1,4096", "bf16[2,4096"), True),
+    ("one_layer_of_wv_into_hbm", _WV_PREFETCH.replace("S(1)", ""), True),
+    ("one_layer_of_wq_into_vmem",
+     _WV_PREFETCH.replace("____wv__", "____wq__"), True),
+    ("plain_slice_of_one_layer",
+     "%slice.3 = bf16[1,4096,1024]{2,1,0:T(8,128)(2,1)} "
+     "slice(%params__stages___0__0__0___mixer____wv__.1), "
+     "slice={[0:1], [0:4096], [0:1024]}", True),
+])
+def test_weight_slice_check(case, line, flagged):
+    """The check above passes only the one-layer VMEM prefetch of a named
+    leaf: more layers, HBM, another leaf or a plain slice still fail."""
+    assert bool(_stacked_weight_slices(line)) == flagged
+
+
+def _sampled_step_compiled(bank, params, sds, slots: int, length: int):
+    """The engine's sampled decode step (``serving.engine._sampled_step``)
+    over the bank's batched decode for ``slots`` slots of ``length``
+    positions, compiled for the described chip; and the pool's bytes."""
+    from repro.serving.engine import _sampled_step
+    pool = [jax.tree.map(lambda t: sds(t.shape, t.dtype),
+                         bank._cache_template(stage, SPLIT, slots, length))
+            for stage in (0, 1)]
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = _sampled_step(bank._fn("decode", SPLIT)).donating.lower(
+        params, sds((slots, 1), jnp.int32), pool, sds((slots,), jnp.int32),
+        sds(key.shape, key.dtype), sds((slots,), jnp.float32)).compile()
+    return compiled, sum(t.size * t.dtype.itemsize
+                         for t in jax.tree.leaves(pool))
+
+
+def test_engine_step_updates_its_pool_in_place(stack_bank, one_chip):
+    """The engine's step over 16 slots of 1024 positions takes its cache
+    pool over: the whole pool is aliased to the step's output, the
+    temporaries are under 150 MB (the transposed ``wq`` stack copy of 134 MB
+    is most of them; the pool's per-layer copies took them to 203 MB), and
+    no dynamic-update-slice fusion writes a range's stacked K or V."""
+    bank, params = stack_bank
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    slots, length = 16, 1024
+    compiled, pool_bytes = _sampled_step_compiled(bank, params, sds, slots,
+                                                  length)
+    mem = compiled.memory_analysis()
+    assert pool_bytes == 268_435_456
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < 150_000_000
+    stacked = f"bf16[{SPLIT},{slots},{length},8,128]"
+    writes = [line for line in compiled.as_text().splitlines()
+              if re.search(r"%\S*dynamic-update-slice\S*fusion\S* = ", line)
+              and stacked in line.split(" = ", 1)[1].split(" ", 1)[0]]
+    assert not writes, writes
+
+
+def test_hybrid_engine_step_updates_its_pool_in_place(one_chip):
+    """zamba2 at its published widths, 4 layers with the shared blocks'
+    invocations at layers 1 and 3 (head_dim 224, so the chip's default
+    layout puts the hybrid KV's positions minor): the 8-slot step aliases
+    its whole pool (some state leaves with the tiles' padding) and relayouts
+    none of it, so its temporaries stay under one hybrid layer's K."""
+    bank, params = _shapes_bank(one_chip, 4, SPLIT, "zamba2-7b",
+                                hybrid_layer_ids=(1, 3))
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    slots, length = 8, 1024
+    compiled, pool_bytes = _sampled_step_compiled(bank, params, sds, slots,
+                                                  length)
+    mem = compiled.memory_analysis()
+    layer_k = slots * length * 32 * 224 * 2
+    # every leaf aliased; the chip's tiles pad the small state leaves
+    assert pool_bytes <= mem.alias_size_in_bytes < pool_bytes * 1.02
+    assert mem.temp_size_in_bytes < layer_k
 
 
 # each kernel's custom call, by the name of its wrapper in kernels/ops.py
