@@ -314,7 +314,7 @@ def _wire_codec_report():
     import numpy as np
 
     from repro.configs import get_config
-    from repro.core import butterfly as bf_lib
+    from repro.core.quantization import Wire
     from repro.core import wire_codec
     from repro.data import lm_batches
     from repro.models import model as M
@@ -352,7 +352,8 @@ def _wire_codec_report():
             pctx=M.LOCAL, mode="train", stage_cache=None, pos=None,
             enc_out=None, shared_params=params.get("shared_attn"),
             use_kernel=False)
-        codes, _ = bf_lib.reduce_unit(params["butterfly"], x)
+        codes, _ = Wire("int8", 8, d_r).encode(
+            x, params["butterfly"]["w_reduce"], kernel=False)
         return np.asarray(codes).reshape(-1, d_r)
 
     def eval_loss(params, built):

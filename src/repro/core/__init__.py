@@ -6,13 +6,12 @@ from repro.core.butterfly import (
     butterfly_wire_bytes,
     compression_ratio,
     init_butterfly,
-    reduce_unit,
-    restore_unit,
 )
-from repro.core.quantization import dequantize, fake_quant, quantize, wire_bytes
+from repro.core.quantization import (Wire, dequantize, fake_quant, quantize,
+                                     wire_bytes)
 
 __all__ = [
     "apply_butterfly", "butterfly_wire_bytes", "compression_ratio",
-    "init_butterfly", "reduce_unit", "restore_unit",
-    "dequantize", "fake_quant", "quantize", "wire_bytes",
+    "init_butterfly", "Wire", "dequantize", "fake_quant", "quantize",
+    "wire_bytes",
 ]

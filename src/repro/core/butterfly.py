@@ -11,18 +11,15 @@ ResNet form, models/resnet.py) is exactly the same per-position linear map.
 The unit is trained end-to-end inside the host model (``fake_quant`` is a
 straight-through estimator), and at serving time the reduce+quantize half
 runs on the edge stage while dequantize+restore runs on the cloud stage
-(serving/pipeline.py), with only (codes, scales) crossing the pod boundary.
+(``quantization.Wire``), with only (codes, scales) crossing the boundary.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
-
 import jax
-import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ButterflyConfig
-from repro.core.quantization import dequantize, fake_quant, quantize, wire_bytes
+from repro.core.quantization import Wire, fake_quant, wire_bytes
 from repro.models.common import dense_init
 
 
@@ -36,48 +33,17 @@ def init_butterfly(key, d: int, bf: ButterflyConfig, dtype):
     return params, specs
 
 
-def reduce_unit(params, x: jax.Array, *, use_kernel: bool = False,
-                wire_bits: int = 8):
-    """Edge half: project + quantize.  Returns (codes, scales)."""
-    if use_kernel:
-        from repro.kernels import ops as kops
-        return kops.butterfly_reduce_quant(x, params["w_reduce"], bits=wire_bits)
-    r = x @ params["w_reduce"]
-    return quantize(r, wire_bits)
-
-
-def restore_unit(params, codes: jax.Array, scales: jax.Array, dtype,
-                 *, use_kernel: bool = False):
-    """Cloud half: dequantize + project back to d."""
-    if use_kernel:
-        from repro.kernels import ops as kops
-        return kops.butterfly_dequant_restore(codes, scales,
-                                              params["w_restore"],
-                                              out_dtype=dtype)
-    r = dequantize(codes, scales, dtype)
-    return r @ params["w_restore"]
-
-
 def apply_butterfly(params, x: jax.Array, *, wire_bits: int = 8,
                     train: bool = True, use_kernel: bool = False) -> jax.Array:
     """In-graph form (training / single-mesh inference): the wire is a
     fake-quant so gradients flow straight through (paper: trained
-    end-to-end).  With ``train=False, use_kernel=True`` the quantized wire
-    runs through the fused Pallas reduce+quant / dequant+restore kernels
-    (the serving hot path; a (B, 1, d) decode row takes the kops fast path)."""
-    if not train and use_kernel and wire_bits <= 8:
-        from repro.kernels import ops as kops
-        codes, scales = kops.butterfly_reduce_quant(x, params["w_reduce"],
-                                                    bits=wire_bits)
-        return kops.butterfly_dequant_restore(codes, scales,
-                                              params["w_restore"],
-                                              out_dtype=x.dtype)
-    r = x @ params["w_reduce"]
-    if train:
-        r = fake_quant(r, wire_bits)
-    else:
-        codes, scales = quantize(r, wire_bits)
-        r = dequantize(codes, scales, x.dtype)
+    end-to-end).  With ``train=False`` the quantized wire runs through
+    :class:`Wire`, with ``use_kernel`` on the fused Pallas reduce+quant /
+    dequant+restore kernels (the serving hot path)."""
+    if not train:
+        wire = Wire("int8", wire_bits, params["w_reduce"].shape[1])
+        return wire.through(x, **params, kernel=use_kernel)
+    r = fake_quant(x @ params["w_reduce"], wire_bits)
     return r @ params["w_restore"]
 
 

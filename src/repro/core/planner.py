@@ -225,42 +225,6 @@ def resolve_objective(name: str) -> Callable:
                        f"{sorted(SELECTION_OBJECTIVES)}") from None
 
 
-def wire_mode_bytes(cfg, seq: int, d_r: int, wire_mode: str,
-                    batch: int = 1) -> float:
-    """Uplink payload per request for each wire ablation mode.
-
-    "raw"     the boundary activation in model dtype (prior-work CI offload)
-    "reduced" butterfly reduction, no wire quantization
-    "int8"    the paper: int8 codes + per-row f32 scales
-    "int4"    beyond-paper: nibble-packed codes (2/byte) + f32 scales
-    "entropy" int8 codes rANS-coded against the learned per-channel prior
-              (predicted at the trained-prior nominal rate; the runtime
-              charges *actual* coded bytes when real codes exist), raw f32
-              scales, plus the per-payload stream overhead.  Never predicted
-              worse than int8: the edge ships raw codes when coding would
-              expand the payload — which is why single decode rows stay
-              fixed-rate int8 (the ~12 B/lane state flush dwarfs them).
-    """
-    from repro.core import wire_codec
-    from repro.core.quantization import wire_bytes
-
-    act_bytes = 2 if cfg.dtype == "bfloat16" else 4
-    if wire_mode == "raw":
-        return float(batch * seq * cfg.d_model * act_bytes)
-    if wire_mode == "reduced":
-        return float(batch * seq * d_r * act_bytes)
-    if wire_mode == "int8":
-        return float(wire_bytes((batch, seq, d_r), 8))
-    if wire_mode == "int4":
-        return float(wire_bytes((batch, seq, d_r), 4))
-    if wire_mode == "entropy":
-        n = batch * seq * d_r
-        coded = wire_codec.predicted_code_bytes(n) \
-            + wire_codec.payload_overhead_bytes(d_r)
-        return float(min(coded, n) + batch * seq * 4)
-    raise ValueError(f"unknown wire_mode {wire_mode!r}")
-
-
 def select_split_online(cfg, seq: int, d_r: int, *,
                         candidate_splits: Sequence[int],
                         edge: HardwareProfile, cloud: HardwareProfile,
@@ -309,12 +273,13 @@ def select_split_online(cfg, seq: int, d_r: int, *,
     Returns ``(best_row, rows)``; rows carry a ``transport`` field on top of
     the offline planner's schema."""
     from repro.core import costs
+    from repro.core.quantization import Wire
 
     pick = resolve_objective(objective)
     n = cfg.num_layers
     T = max(int(new_tokens), 1)
-    base_wire = wire_mode_bytes(cfg, seq, d_r, wire_mode)
-    row_bytes = wire_mode_bytes(cfg, 1, d_r, wire_mode)
+    wire = Wire.parse(wire_mode, 8, d_r)
+    base_wire, row_bytes = wire.nbytes(cfg, seq), wire.nbytes(cfg, 1)
     down_bps = downlink_bytes_per_s if downlink_bytes_per_s else float("inf")
     token_down_s = costs.TOKEN_BYTES / down_bps
     link_bps = max(link_bytes_per_s, 1e-9)

@@ -12,6 +12,7 @@ JPEG onto a frozen model.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Tuple
 
 import jax
@@ -127,3 +128,124 @@ class CodedRows:
     def __getitem__(self, index):
         """``index`` selects over the leading (batch, position) axes."""
         return CodedRows(self.codes[index], self.ids[index])
+
+
+WIRE_MODES = ("raw", "reduced", "int8", "int4", "entropy")
+
+
+@dataclass(frozen=True)
+class Wire:
+    """What crosses the butterfly's boundary, in one of five modes:
+
+      "raw"     the boundary rows in the model dtype (prior-work offload)
+      "reduced" the reduction unit's rows, unquantized, in the model dtype
+      "int8"    the paper: reduction + ``bits``-bit codes + per-row f32 scales
+      "int4"    reduction + 4-bit codes packed two per byte + scales
+      "entropy" the int8 codes, rANS-coded on the link: numerically int8 (the
+                coding is lossless), only the bytes differ
+
+    The one place a mode string is interpreted.  Options, configs and the
+    CLI name the mode; each entry point parses it once (:meth:`parse`).
+    ``encode`` is the edge's side (reduce, quantize, pack) and ``decode`` the
+    cloud's (unpack, dequantize, restore).  With ``kernel`` a quantized wire
+    of at most 8 bits runs through the fused Pallas kernels
+    (kernels/ops.py), else through the eager codec above."""
+    mode: str
+    bits: int
+    d_r: int
+
+    def __post_init__(self):
+        if self.mode not in WIRE_MODES:
+            raise ValueError(f"unknown wire mode {self.mode!r}; "
+                             f"known: {WIRE_MODES}")
+        if self.quantized and self.bits not in (4, 8, 16):
+            raise ValueError(f"{self.mode} wire of {self.bits} bits")
+        if self.mode == "int4" and (self.bits != 4 or self.d_r % 2):
+            raise ValueError(f"int4 wire packs two 4-bit codes per byte: "
+                             f"bits {self.bits}, d_r {self.d_r}")
+
+    @classmethod
+    def parse(cls, mode: str, wire_bits: int, d_r: int) -> "Wire":
+        """The wire of a configured mode: int4 is 4-bit whatever
+        ``wire_bits`` says."""
+        return cls(mode, 4 if mode == "int4" else wire_bits, d_r)
+
+    @property
+    def quantized(self) -> bool:
+        return self.mode in ("int8", "int4", "entropy")
+
+    def _kernel(self, kernel: bool) -> bool:
+        # the fused codec emits int8 codes, which covers 4-bit ones too
+        return kernel and self.bits <= 8
+
+    def encode(self, x, w_reduce, *, kernel: bool = True):
+        """Boundary rows ``x`` (..., d) -> (payload, scales (..., 1) f32);
+        the unquantized modes send zero scales."""
+        if self.mode == "raw":
+            return x, jnp.zeros((*x.shape[:-1], 1), jnp.float32)
+        if self.mode == "reduced":
+            r = x @ w_reduce
+            return r, jnp.zeros((*r.shape[:-1], 1), jnp.float32)
+        if self._kernel(kernel):
+            from repro.kernels import ops
+            codes, scales = ops.butterfly_reduce_quant(x, w_reduce,
+                                                       bits=self.bits)
+        else:
+            codes, scales = quantize(x @ w_reduce, self.bits)
+        return (pack_int4(codes) if self.mode == "int4" else codes), scales
+
+    def unpack(self, payload):
+        """A quantized payload's codes, one per channel."""
+        return unpack_int4(payload) if self.mode == "int4" else payload
+
+    def decode(self, payload, scales, w_restore, dtype, *,
+               kernel: bool = True):
+        """Invert :meth:`encode`: the restored rows (..., d) in ``dtype``
+        (the unquantized modes keep the payload's)."""
+        if self.mode == "raw":
+            return payload
+        if self.mode == "reduced":
+            return payload @ w_restore
+        codes = self.unpack(payload)
+        if self._kernel(kernel):
+            from repro.kernels import ops
+            return ops.butterfly_dequant_restore(codes, scales, w_restore,
+                                                 out_dtype=dtype)
+        return dequantize(codes, scales, dtype) @ w_restore
+
+    def through(self, x, w_reduce, w_restore, *, kernel: bool = True):
+        """``x`` as the far side of the wire sees it, in one graph.  The
+        weights are the butterfly's two leaves by name, so
+        ``through(x, **params["butterfly"])`` passes them."""
+        return self.decode(*self.encode(x, w_reduce, kernel=kernel),
+                           w_restore, x.dtype, kernel=kernel)
+
+    def payload_shape_dtype(self, batch: int, seq: int, d_model: int, dtype):
+        """ShapeDtypeStructs of :meth:`encode`'s (payload, scales) for
+        ``(batch, seq, d_model)`` rows of ``dtype``."""
+        return jax.eval_shape(
+            lambda x, w: self.encode(x, w, kernel=False),
+            jax.ShapeDtypeStruct((batch, seq, d_model), dtype),
+            jax.ShapeDtypeStruct((d_model, self.d_r), dtype))
+
+    def nbytes(self, cfg, seq: int, batch: int = 1) -> float:
+        """Uplink bytes of ``batch`` x ``seq`` boundary rows of model
+        ``cfg``: codes packed to ``bits`` plus per-row f32 scales for the
+        quantized modes.  The entropy wire is predicted at the trained
+        prior's nominal rate (the runtime charges the actual coded bytes
+        when real codes exist) plus the per-payload stream overhead, and
+        never worse than int8: the edge ships the raw codes when coding
+        would expand them, which is why single decode rows stay int8."""
+        act_bytes = 2 if cfg.dtype == "bfloat16" else 4
+        rows = batch * seq
+        if self.mode == "raw":
+            return float(rows * cfg.d_model * act_bytes)
+        if self.mode == "reduced":
+            return float(rows * self.d_r * act_bytes)
+        if self.mode == "entropy":
+            from repro.core import wire_codec
+            n = rows * self.d_r
+            coded = wire_codec.predicted_code_bytes(n) \
+                + wire_codec.payload_overhead_bytes(self.d_r)
+            return float(min(coded, n) + rows * 4)
+        return float(wire_bytes((batch, seq, self.d_r), self.bits))

@@ -18,8 +18,9 @@ candidate.
 :class:`SplitRunner` is a thin facade over the bank's compile cache: jitted
 edge/cloud/prefill/decode functions are keyed on ``(kind, split)`` with
 bucket-padded ``(B, S)`` shapes, so a candidate sweep re-uses executables
-instead of recompiling per prompt length.  The int8 wire runs through the
-fused Pallas reduce+quant / dequant+restore kernels (kernels/ops.py).
+instead of recompiling per prompt length.  Every program is composed of the
+same edge front, the wire (``quantization.Wire``: the int8 wire runs through
+the fused Pallas reduce+quant / dequant+restore kernels) and the same head.
 
 Multi-token requests pick a decode transport (runtime/transports.py):
 ``cache_handoff`` ships the edge stage-0 KV cache to the cloud alongside the
@@ -37,19 +38,9 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from repro.core import costs
-from repro.core.planner import wire_mode_bytes
 from repro.core.profiler import HardwareProfile
+from repro.core.quantization import CodedRows, Wire
 from repro.runtime.tracing import span
-
-
-def act_bytes(cfg) -> int:
-    return 2 if cfg.dtype == "bfloat16" else 4
-
-
-def input_bytes(cfg, seq: int) -> float:
-    """Cloud-only offload ships the frontend's feature output (the paper
-    ships the raw 224x224x3 image) — one d_model-wide row per position."""
-    return float(seq * cfg.d_model * act_bytes(cfg))
 
 
 # ---------------------------------------------------------------------------
@@ -129,11 +120,9 @@ class CostModel:
     def stream_row_bytes(self, wire_mode: str, d_r: int,
                          split: Optional[int] = None) -> float:
         """Per-token uplink bytes of the streamed transport: one boundary
-        row in the wire format (int8 codes + f32 scale for the paper's
-        mode; "int4" nibble-packs two codes per byte, halving the code
-        bytes), and the token's id where a layer past ``split`` reads the
-        embeddings."""
-        b = wire_mode_bytes(self.cfg, 1, d_r, wire_mode)
+        row in the wire format, and the token's id where a layer past
+        ``split`` reads the embeddings."""
+        b = Wire.parse(wire_mode, 8, d_r).nbytes(self.cfg, 1)
         if split is not None:
             b += costs.id_wire_bytes(self.cfg, split, 1)
         return b
@@ -177,10 +166,12 @@ class CostModel:
         ``streamed`` transport keeps that cache on the edge and pays one
         ``stream_row_bytes`` row per later token instead."""
         if mode == "cloud":
-            return input_bytes(self.cfg, seq)
+            # the frontend's feature output, one raw d_model-wide row per
+            # position (the paper ships the raw 224x224x3 image)
+            return Wire("raw", 8, d_r).nbytes(self.cfg, seq)
         if mode == "edge":
             return 0.0
-        b = wire_mode_bytes(self.cfg, seq, d_r, wire_mode)
+        b = Wire.parse(wire_mode, 8, d_r).nbytes(self.cfg, seq)
         b += costs.id_wire_bytes(self.cfg, split, seq)
         if new_tokens > 1 and transport == "cache_handoff":
             b += self.stage0_cache_bytes(seq, split)
@@ -234,20 +225,12 @@ class SplitModelBank:
         from repro.models import transformer as tfm
 
         assert base_cfg.num_layers >= 2, "need >=2 layers to split"
-        # "entropy" is numerically int8 — the rANS coding of the codes is
-        # lossless, so the in-graph halves are shared with the int8 wire and
-        # only byte accounting / transport choreography differ (wire_codec)
-        assert wire_mode in ("raw", "reduced", "int8", "int4", "entropy"), \
-            wire_mode
-        if wire_mode == "int4":
-            assert d_r % 2 == 0, "int4 wire packs two codes per byte"
+        self.wire = Wire.parse(wire_mode, wire_bits, d_r)
         if base_cfg.butterfly is not None:
             import dataclasses
             base_cfg = dataclasses.replace(base_cfg, butterfly=None)
         self.base_cfg = base_cfg
         self.d_r = d_r
-        self.wire_bits = wire_bits
-        self.wire_mode = wire_mode
         self.seed = seed
         self.edge_mp = int(edge_mp)
         self.cloud_mp = int(cloud_mp)
@@ -258,6 +241,8 @@ class SplitModelBank:
         self.params, _ = M.init_model(jax.random.key(seed), self.built)
         self._M, self._tfm = M, tfm
         self._dt = jnp.dtype(base_cfg.dtype)
+        self._emb_scale = base_cfg.arch_type == "dense" and \
+            base_cfg.act == "gelu"
         self._defs = tfm.build_layer_defs(base_cfg)
         # hybrid layers (zamba2) read the token embeddings beside the
         # residual: each program passes them to its layer ranges, and a
@@ -276,20 +261,13 @@ class SplitModelBank:
         # batch rows are independent everywhere except MoE (shared capacity);
         # the actors also consult this before coalescing request numerics
         self._batch_bucket_ok = all(d.ffn != "moe" for d in self._defs)
-        # effective wire precision: "int4" quantizes to 4-bit codes (packed
-        # two per byte outside the kernel) regardless of the config default
-        self.wire_eff_bits = 4 if wire_mode == "int4" else wire_bits
-        # the fused Pallas codec emits int8 codes, which covers every
-        # sub-byte precision too (packing happens outside the kernel); only
-        # wider wires (wire_bits=16 -> int16 codes) take the eager path
-        self._kernel_wire_ok = self.wire_eff_bits <= 8
         # decode-row kernel block size, derived ONCE from the wire format
         # instead of per call, and folded into every compile-cache key so
         # int4 and int8 rows (same (B, S) buckets, different packed widths)
         # never alias a jitted step
         from repro.kernels import ops as _kops
         self.row_block = _kops.decode_row_block()
-        self._wire_sig = (wire_mode, self.wire_eff_bits, self.row_block)
+        self._wire_sig = (self.wire.mode, self.wire.bits, self.row_block)
 
         self._butterfly: Dict[int, dict] = {}
         # runner key: (split, edge_mp, cloud_mp); fn key: (kind, split, mp) —
@@ -399,7 +377,7 @@ class SplitModelBank:
             from repro.configs.base import ButterflyConfig
             key = jax.random.fold_in(jax.random.key(self.seed), split)
             bf = ButterflyConfig(layer=split, d_r=self.d_r,
-                                 wire_bits=self.wire_eff_bits)
+                                 wire_bits=self.wire.bits)
             self._butterfly[split], _ = init_butterfly(
                 key, self.base_cfg.d_model, bf, self._dt)
         return self._butterfly[split]
@@ -452,58 +430,12 @@ class SplitModelBank:
                 self._tfm.range_segments(segs, split,
                                          self.base_cfg.num_layers)]
 
-    # ------------------------------------------------- wire transforms (jit)
-    def _pack_wire(self, codes):
-        """Wire-format packing of quantized codes: int4 nibble-packs two
-        codes per byte (pack/unpack round-trips exactly, so the in-graph
-        numerics are unchanged); every other mode ships codes as-is."""
-        if self.wire_mode == "int4":
-            from repro.core.quantization import pack_int4
-            return pack_int4(codes)
-        return codes
-
-    def _unpack_wire(self, codes):
-        if self.wire_mode == "int4":
-            from repro.core.quantization import unpack_int4
-            return unpack_int4(codes)
-        return codes
-
-    def _wire_ingraph(self, bf, x, *, use_kernel: bool):
-        """The wire as the hosted model sees it, per wire_mode: raw ships the
-        boundary tensor untouched, reduced projects down/up without
-        quantization, int8/int4 round-trip the fused quantized codec (int4
-        additionally round-trips the nibble packing)."""
-        import jax.numpy as jnp
-        from repro.core.quantization import dequantize, quantize
-        if self.wire_mode == "raw":
-            return x
-        if self.wire_mode == "reduced":
-            return (x @ bf["w_reduce"]) @ bf["w_restore"]
-        if use_kernel and self._kernel_wire_ok:
-            from repro.kernels import ops as kops
-            codes, scales = kops.butterfly_reduce_quant(
-                x, bf["w_reduce"], bits=self.wire_eff_bits)
-            codes = self._unpack_wire(self._pack_wire(codes))
-            return kops.butterfly_dequant_restore(
-                codes, scales, bf["w_restore"], out_dtype=x.dtype)
-        r = x @ bf["w_reduce"]
-        codes, scales = quantize(r, self.wire_eff_bits)
-        codes = self._unpack_wire(self._pack_wire(codes))
-        return dequantize(codes, scales, x.dtype) @ bf["w_restore"]
-
     # --------------------------------------------------- jitted core factory
     def _fn(self, kind: str, split: int, mp: int = 1):
         key = (kind, split, mp) + self._wire_sig
         if key not in self._fns:
             self._fns[key] = getattr(self, f"_make_{kind}")(split, mp)
         return self._fns[key]
-
-    def _stage_ctx(self, mp: int = 1):
-        from repro.models.common import embed, rms_norm, unembed
-        cfg = self.base_cfg
-        segs = list(self.built.stages[0])
-        scale = cfg.arch_type == "dense" and cfg.act == "gelu"
-        return cfg, segs, scale, embed, rms_norm, unembed, self._pctx(mp)
 
     def _tp_specs(self):
         if not hasattr(self, "_tp_specs_tree"):
@@ -533,19 +465,74 @@ class SplitModelBank:
         return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)
 
+    # -------------------------------------- the pieces every program is made of
+    def _layers(self, mp: int):
+        """``layers(params, x, lo, hi, mode, cache, pos, x_emb) -> (x,
+        cache)``: layers [lo, hi) of the stacked backbone at model-axis
+        degree ``mp``.  The range function is bound when a program is made,
+        not when it is traced."""
+        cfg, segs = self.base_cfg, list(self.built.stages[0])
+        apply_range, pctx = self._tfm.apply_layer_range, self._pctx(mp)
+
+        def layers(params, x, lo, hi, mode, cache=None, pos=None, x_emb=None):
+            x, cache, _ = apply_range(
+                segs, params["stages"][0], x, lo, hi, cfg=cfg, pctx=pctx,
+                mode=mode, range_cache=cache, pos=pos,
+                shared_params=params.get("shared_attn"), x_emb=x_emb)
+            return x, cache
+
+        return layers
+
+    def _embed(self, params, toks):
+        from repro.models.common import embed
+        return embed(params["embed"], toks, scale=self._emb_scale)
+
+    def _front(self, layers, params, toks, split: int, mode: str, cache=None,
+               pos=None):
+        """The edge's front: embed ``toks`` and run layers [0, split).
+        Returns ``(x, cache, x_emb)``; ``x_emb``, the embeddings the hybrid
+        layers read beside the residual, is None for other models."""
+        x = self._embed(params, toks)
+        x_emb = x if self._reads_emb else None
+        x, cache = layers(params, x, 0, split, mode, cache, pos, x_emb)
+        return x, cache, x_emb
+
+    def _head(self, params, x):
+        """Final norm and LM head over the rows ``x``: the logits."""
+        from repro.models.common import rms_norm, unembed
+        cfg = self.base_cfg
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        table = params["embed"] if cfg.tie_embeddings else params["head"]
+        return unembed(table, x, cfg.logit_softcap)
+
+    def _wire_out(self, params, x, toks, split: int):
+        """The edge's side of the wire: (payload, scales) of the boundary
+        rows ``x``, the token ids beside the codes where a layer past
+        ``split`` reads the embeddings."""
+        payload, scales = self.wire.encode(x, params["butterfly"]["w_reduce"])
+        if self.ships_ids(split):
+            payload = CodedRows(payload, toks.astype(self.ids_dtype))
+        return payload, scales
+
+    def _wire_in(self, params, payload, scales, split: int):
+        """The cloud's side of the wire: the restored boundary rows and,
+        where the payload carries token ids, their embeddings (else None)."""
+        x_emb = None
+        if self.ships_ids(split):
+            x_emb = self._embed(params, payload.ids.astype("int32"))
+            payload = payload.codes
+        return self.wire.decode(payload, scales,
+                                params["butterfly"]["w_restore"],
+                                self._dt), x_emb
+
+    # ------------------------------------------------------------- programs
     def _make_edge(self, split: int, mp: int = 1):
         import jax
         from jax.sharding import PartitionSpec as P
-        cfg, segs, scale, embed, _, _, pctx = self._stage_ctx(mp)
-        tfm = self._tfm
+        layers = self._layers(mp)
 
         def edge(params, toks):
-            x = embed(params["embed"], toks, scale=scale)
-            x, cache0, _ = tfm.apply_layer_range(
-                segs, params["stages"][0], x, 0, split, cfg=cfg, pctx=pctx,
-                mode="prefill", range_cache=None, pos=None,
-                shared_params=params.get("shared_attn"),
-                x_emb=x if self._reads_emb else None)
+            x, cache0, _ = self._front(layers, params, toks, split, "prefill")
             payload, scales = self._wire_out(params, x, toks, split)
             return payload, scales, cache0
 
@@ -554,74 +541,16 @@ class SplitModelBank:
                                (P(), P(), self._cache_spec_tree(0, split))))
         return jax.jit(edge)
 
-    def _wire_out(self, params, x, toks, split: int):
-        """The edge's side of the wire, in-graph: (payload, scales) of the
-        boundary rows ``x`` in the wire format, the token ids beside the
-        codes where a layer past ``split`` reads the embeddings."""
-        import jax.numpy as jnp
-        from repro.core.quantization import CodedRows
-        from repro.kernels import ops as kops
-        wm = self.wire_mode
-        if wm == "raw":
-            payload, scales = x, jnp.zeros((*x.shape[:2], 1), jnp.float32)
-        elif wm == "reduced":
-            payload = x @ params["butterfly"]["w_reduce"]
-            scales = jnp.zeros((*payload.shape[:2], 1), jnp.float32)
-        elif self._kernel_wire_ok:
-            codes, scales = kops.butterfly_reduce_quant(
-                x, params["butterfly"]["w_reduce"], bits=self.wire_eff_bits)
-            payload = self._pack_wire(codes)
-        else:
-            from repro.core.quantization import quantize
-            codes, scales = quantize(x @ params["butterfly"]["w_reduce"],
-                                     self.wire_eff_bits)
-            payload = self._pack_wire(codes)
-        if self.ships_ids(split):
-            payload = CodedRows(payload, toks.astype(self.ids_dtype))
-        return payload, scales
-
-    def _wire_in(self, params, payload, scales, split: int, scale: bool):
-        """The cloud's side of the wire, in-graph: the restored boundary rows
-        and, where the payload carries token ids, their embeddings (else
-        None)."""
-        from repro.kernels import ops as kops
-        from repro.models.common import embed
-        wm, dt = self.wire_mode, self._dt
-        x_emb = None
-        if self.ships_ids(split):
-            x_emb = embed(params["embed"], payload.ids.astype("int32"),
-                          scale=scale)
-            payload = payload.codes
-        if wm == "raw":
-            x = payload
-        elif wm == "reduced":
-            x = payload @ params["butterfly"]["w_restore"]
-        elif self._kernel_wire_ok:
-            x = kops.butterfly_dequant_restore(
-                self._unpack_wire(payload), scales,
-                params["butterfly"]["w_restore"], out_dtype=dt)
-        else:
-            from repro.core.quantization import dequantize
-            x = dequantize(self._unpack_wire(payload), scales, dt) @ \
-                params["butterfly"]["w_restore"]
-        return x, x_emb
-
     def _make_cloud(self, split: int, mp: int = 1):
         import jax
         from jax.sharding import PartitionSpec as P
-        cfg, segs, scale, _, rms_norm, unembed, pctx = self._stage_ctx(mp)
-        tfm = self._tfm
+        layers, N = self._layers(mp), self.base_cfg.num_layers
 
         def cloud(params, payload, scales, length):
-            x, x_emb = self._wire_in(params, payload, scales, split, scale)
-            x, cache1, _ = tfm.apply_layer_range(
-                segs, params["stages"][0], x, split, cfg.num_layers, cfg=cfg,
-                pctx=pctx, mode="prefill", range_cache=None, pos=None,
-                shared_params=params.get("shared_attn"), x_emb=x_emb)
+            x, x_emb = self._wire_in(params, payload, scales, split)
+            x, cache1 = layers(params, x, split, N, "prefill", x_emb=x_emb)
             x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
-            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-            table = params["embed"] if cfg.tie_embeddings else params["head"]
-            return unembed(table, x, cfg.logit_softcap)[:, 0], cache1
+            return self._head(params, x)[:, 0], cache1
 
         cloud = self._mp_wrap(
             cloud, mp, lambda: ((self._tp_specs(), P(), P(), P()),
@@ -630,28 +559,19 @@ class SplitModelBank:
 
     def _make_prefill(self, split: int, mp: int = 1):
         """Full hosted-model prefill (both halves + the wire, one graph):
-        the engine path for cloud-only / mobile-only serving."""
+        the engine path for cloud-only / mobile-only serving.  The cloud
+        range reads the edge's own embeddings: no ids cross in-graph."""
         import jax
         from jax.sharding import PartitionSpec as P
-        cfg, segs, scale, embed, rms_norm, unembed, pctx = self._stage_ctx(mp)
-        tfm = self._tfm
+        layers, N = self._layers(mp), self.base_cfg.num_layers
 
         def prefill(params, toks, length):
-            x = embed(params["embed"], toks, scale=scale)
-            x_emb = x if self._reads_emb else None
-            x, cache0, _ = tfm.apply_layer_range(
-                segs, params["stages"][0], x, 0, split, cfg=cfg, pctx=pctx,
-                mode="prefill", range_cache=None, pos=None,
-                shared_params=params.get("shared_attn"), x_emb=x_emb)
-            x = self._wire_ingraph(params["butterfly"], x, use_kernel=True)
-            x, cache1, _ = tfm.apply_layer_range(
-                segs, params["stages"][0], x, split, cfg.num_layers, cfg=cfg,
-                pctx=pctx, mode="prefill", range_cache=None, pos=None,
-                shared_params=params.get("shared_attn"), x_emb=x_emb)
+            x, cache0, x_emb = self._front(layers, params, toks, split,
+                                           "prefill")
+            x = self.wire.through(x, **params["butterfly"])
+            x, cache1 = layers(params, x, split, N, "prefill", x_emb=x_emb)
             x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
-            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-            table = params["embed"] if cfg.tie_embeddings else params["head"]
-            return unembed(table, x, cfg.logit_softcap), [cache0, cache1]
+            return self._head(params, x), [cache0, cache1]
 
         prefill = self._mp_wrap(
             prefill, mp,
@@ -666,24 +586,15 @@ class SplitModelBank:
         fused kernels' (B, 1, d) fast path.  NOT jit-wrapped here — the
         engine folds sampling into the same jitted step."""
         from jax.sharding import PartitionSpec as P
-        cfg, segs, scale, embed, rms_norm, unembed, pctx = self._stage_ctx(mp)
-        tfm = self._tfm
+        layers, N = self._layers(mp), self.base_cfg.num_layers
 
         def decode(params, tokens, caches, pos):
-            x = embed(params["embed"], tokens, scale=scale)
-            x_emb = x if self._reads_emb else None
-            x, nc0, _ = tfm.apply_layer_range(
-                segs, params["stages"][0], x, 0, split, cfg=cfg, pctx=pctx,
-                mode="decode", range_cache=caches[0], pos=pos,
-                shared_params=params.get("shared_attn"), x_emb=x_emb)
-            x = self._wire_ingraph(params["butterfly"], x, use_kernel=True)
-            x, nc1, _ = tfm.apply_layer_range(
-                segs, params["stages"][0], x, split, cfg.num_layers, cfg=cfg,
-                pctx=pctx, mode="decode", range_cache=caches[1], pos=pos,
-                shared_params=params.get("shared_attn"), x_emb=x_emb)
-            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-            table = params["embed"] if cfg.tie_embeddings else params["head"]
-            return unembed(table, x, cfg.logit_softcap), [nc0, nc1]
+            x, nc0, x_emb = self._front(layers, params, tokens, split,
+                                        "decode", caches[0], pos)
+            x = self.wire.through(x, **params["butterfly"])
+            x, nc1 = layers(params, x, split, N, "decode", caches[1], pos,
+                            x_emb)
+            return self._head(params, x), [nc0, nc1]
 
         def specs():
             cache_specs = [self._cache_spec_tree(0, split),
@@ -699,16 +610,11 @@ class SplitModelBank:
         the per-token payload that replaces the stage-0 cache handoff."""
         import jax
         from jax.sharding import PartitionSpec as P
-        cfg, segs, scale, embed, _, _, pctx = self._stage_ctx(mp)
-        tfm = self._tfm
+        layers = self._layers(mp)
 
         def edge_step(params, tok, cache0, pos):
-            x = embed(params["embed"], tok, scale=scale)
-            x, nc0, _ = tfm.apply_layer_range(
-                segs, params["stages"][0], x, 0, split, cfg=cfg, pctx=pctx,
-                mode="decode", range_cache=cache0, pos=pos,
-                shared_params=params.get("shared_attn"),
-                x_emb=x if self._reads_emb else None)
+            x, nc0, _ = self._front(layers, params, tok, split, "decode",
+                                    cache0, pos)
             payload, scales = self._wire_out(params, x, tok, split)
             return payload, scales, nc0
 
@@ -728,25 +634,18 @@ class SplitModelBank:
         step (serving/engine._sampled_stream_step), shared by every engine of
         this split."""
         from jax.sharding import PartitionSpec as P
-        cfg, segs, scale, _, rms_norm, unembed, pctx = self._stage_ctx(mp)
-        tfm = self._tfm
+        layers, N = self._layers(mp), self.base_cfg.num_layers
 
         def cloud_step(params, payload, scales, cache1, pos):
-            x, x_emb = self._wire_in(params, payload, scales, split, scale)
-            x, nc1, _ = tfm.apply_layer_range(
-                segs, params["stages"][0], x, split, cfg.num_layers, cfg=cfg,
-                pctx=pctx, mode="decode", range_cache=cache1, pos=pos,
-                shared_params=params.get("shared_attn"), x_emb=x_emb)
-            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-            table = params["embed"] if cfg.tie_embeddings else params["head"]
-            return unembed(table, x, cfg.logit_softcap), nc1
+            x, x_emb = self._wire_in(params, payload, scales, split)
+            x, nc1 = layers(params, x, split, N, "decode", cache1, pos, x_emb)
+            return self._head(params, x), nc1
 
         def specs():
             spec1 = self._cache_spec_tree(1, split)
             return ((self._tp_specs(), P(), P(), spec1, P()), (P(), spec1))
 
         return self._mp_wrap(cloud_step, mp, specs)
-
 
 class SplitRunner:
     """Thin facade over the bank's shared backbone + compile cache for one
@@ -765,8 +664,7 @@ class SplitRunner:
         self.edge_mp = int(edge_mp)
         self.cloud_mp = int(cloud_mp)
         self.cfg = bank.base_cfg.with_butterfly(split, bank.d_r,
-                                                bank.wire_eff_bits)
-        self.wire_mode = bank.wire_mode
+                                                bank.wire.bits)
         self.built = bank.built
         # shallow dict: backbone leaves are bank.params' leaves, not copies
         self.params = dict(bank.params)
@@ -878,7 +776,7 @@ class SplitRunner:
         under the wire signature."""
         import jax
         bank = self.bank
-        assert bank.wire_mode in ("int8", "int4", "entropy"), \
+        assert bank.wire.quantized, \
             "decode pipeline wires quantized codes (int8/int4/entropy)"
         key = ("decode_pipeline", self.split, id(mesh), num_microbatches,
                prompt_len, microbatch, new_tokens, bool(pipelined),
@@ -900,7 +798,7 @@ class SplitRunner:
                                long_mode=self.built.long_mode)
             fn = spl.make_decode_pipeline(
                 built, mesh, num_microbatches, prompt_len, microbatch,
-                new_tokens, wire_mode=bank.wire_mode, pipelined=pipelined,
+                new_tokens, wire_mode=bank.wire.mode, pipelined=pipelined,
                 use_kernel=use_kernel, overlap_psum=overlap_psum)
             bank._fns[key] = (jax.jit(fn), params)
         fn, params = bank._fns[key]
@@ -952,21 +850,10 @@ class SplitRunner:
         """Single-mesh forward (what the split path must reproduce): eager,
         reference (non-kernel) wire codec, same wire_mode semantics."""
         import jax.numpy as jnp
-        bank = self.bank
-        cfg, segs, scale, embed, rms_norm, unembed, LOCAL = bank._stage_ctx()
-        tfm = bank._tfm
-        params = self.params
-        x = embed(params["embed"], jnp.asarray(toks), scale=scale)
-        x_emb = x if bank._reads_emb else None
-        x, cache0, _ = tfm.apply_layer_range(
-            segs, params["stages"][0], x, 0, self.split, cfg=cfg, pctx=LOCAL,
-            mode="prefill", range_cache=None, pos=None,
-            shared_params=params.get("shared_attn"), x_emb=x_emb)
-        x = bank._wire_ingraph(params["butterfly"], x, use_kernel=False)
-        x, cache1, _ = tfm.apply_layer_range(
-            segs, params["stages"][0], x, self.split, cfg.num_layers, cfg=cfg,
-            pctx=LOCAL, mode="prefill", range_cache=None, pos=None,
-            shared_params=params.get("shared_attn"), x_emb=x_emb)
-        x = rms_norm(x[:, -1:], params["final_norm"], cfg.rms_eps)
-        table = params["embed"] if cfg.tie_embeddings else params["head"]
-        return unembed(table, x, cfg.logit_softcap), [cache0, cache1]
+        bank, params = self.bank, self.params
+        layers, N = bank._layers(1), bank.base_cfg.num_layers
+        x, cache0, x_emb = bank._front(layers, params, jnp.asarray(toks),
+                                       self.split, "prefill")
+        x = bank.wire.through(x, **params["butterfly"], kernel=False)
+        x, cache1 = layers(params, x, self.split, N, "prefill", x_emb=x_emb)
+        return bank._head(params, x[:, -1:]), [cache0, cache1]

@@ -68,13 +68,13 @@ def _entropy_payload_adjust(device, req) -> float:
     bank) keep the deterministic nominal prediction — delta 0.0 — so
     record->replay stays byte-identical in both modes (the encoder is a
     pure function of the codes)."""
-    from repro.core.planner import wire_mode_bytes
+    from repro.core.quantization import Wire
 
     t = req.trace
-    predicted = wire_mode_bytes(device.cost.cfg, t.prompt_len, device.d_r,
-                                "entropy")
-    raw_int8 = wire_mode_bytes(device.cost.cfg, t.prompt_len, device.d_r,
-                               "int8")
+    predicted = Wire("entropy", 8, device.d_r).nbytes(device.cost.cfg,
+                                                      t.prompt_len)
+    raw_int8 = Wire("int8", 8, device.d_r).nbytes(device.cost.cfg,
+                                                  t.prompt_len)
     coded = predicted
     delta = 0.0
     if req.payload is not None and req.payload[0] is not None:

@@ -37,8 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.quantization import (dequantize, pack_int4, quantize,
-                                     unpack_int4, wire_bytes)
+from repro.core.quantization import Wire
 from repro.kernels import ops
 from repro.models import model as M
 from repro.models import transformer as tfm
@@ -51,13 +50,19 @@ def wire_stats(cfg, microbatch: int, seq: int,
     """Bytes crossing the pod boundary per microbatch tick: ceil-packed
     codes (two int4 codes per byte — sub-byte wires no longer floor to 0)
     plus per-row scales at their real dtype width (f32)."""
-    d_r = cfg.butterfly.d_r
     bits = cfg.butterfly.wire_bits if wire_bits is None else wire_bits
-    act_bytes = 2 if cfg.dtype == "bfloat16" else 4
-    wire = wire_bytes((microbatch, seq, d_r), bits)
-    raw = microbatch * seq * cfg.d_model * act_bytes
+    d_r = cfg.butterfly.d_r
+    wire = int(Wire("int8", bits, d_r).nbytes(cfg, seq, microbatch))
+    raw = int(Wire("raw", bits, d_r).nbytes(cfg, seq, microbatch))
     return {"wire_bytes": wire, "raw_boundary_bytes": raw,
             "compression": raw / wire}
+
+
+def _zero_wire(wire: Wire, batch: int, seq: int, cfg):
+    """Zeros in the shape of ``wire``'s (payload, scales): what a pod that
+    sends nothing on a tick puts on the ppermute."""
+    return tuple(jnp.zeros(a.shape, a.dtype) for a in wire.payload_shape_dtype(
+        batch, seq, cfg.d_model, jnp.dtype(cfg.dtype)))
 
 
 def pipeline_param_specs(built: M.BuiltModel, mp: int):
@@ -82,13 +87,9 @@ def make_split_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
     (heads/d_ff/experts must divide the axis — see
     ``transformer.check_tp_divisibility``).
 
-    wire_mode — what crosses the pod boundary (the perf-iteration knob):
-      "raw"     vanilla collaborative intelligence: the full (mb, S, d_model)
-                activation in model dtype (prior work [6]-[12])
-      "reduced" butterfly reduction only, no quantization: (mb, S, d_r) dtype
-      "int8"    the paper: reduction + int8 wire (codes + f32 scales)
-      "int4"    reduction + 4-bit wire: codes quantize to [-8, 7] and pack
-                two per byte, halving per-token uplink bytes vs int8
+    wire_mode — what crosses the pod boundary: one of
+    ``quantization.Wire``'s modes ("raw", "reduced", "int8", "int4",
+    "entropy"; the last is numerically int8).
 
     use_kernel — quantized wires run through the fused Pallas reduce+quant
     (edge) and dequant+restore (cloud) kernels instead of the eager codec.
@@ -106,19 +107,10 @@ def make_split_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
     tfm.check_tp_divisibility(tfm.build_layer_defs(cfg, built.long_mode),
                               cfg, mp)
     pctx = manual_context(mesh) if mp > 1 else LOCAL
-    d_r = cfg.butterfly.d_r
     V = cfg.vocab_size
-    d = cfg.d_model
     Mmb = num_microbatches
     dt = jnp.dtype(cfg.dtype)
-
-    # "entropy" shares the int8 numerics end to end (rANS is lossless over
-    # the codes); it only changes byte accounting outside the graph
-    assert wire_mode in ("raw", "reduced", "int8", "int4", "entropy"), \
-        wire_mode
-    if wire_mode == "int4":
-        assert d_r % 2 == 0, "int4 wire packs two codes per byte"
-    bits = 4 if wire_mode == "int4" else cfg.butterfly.wire_bits
+    wire = Wire.parse(wire_mode, cfg.butterfly.wire_bits, cfg.butterfly.d_r)
 
     def stage_edge(params, toks):
         scale = cfg.arch_type == "dense" and cfg.act == "gelu"
@@ -127,39 +119,12 @@ def make_split_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
             list(built.stages[0]), params["stages"][0], x, cfg=cfg,
             pctx=pctx, mode="train", stage_cache=None, pos=None,
             shared_params=params.get("shared_attn"))
-        if wire_mode == "raw":
-            return x, jnp.zeros((x.shape[0], seq_len, 1), jnp.float32)
-        if use_kernel and wire_mode != "reduced":
-            codes, scales = ops.butterfly_reduce_quant(
-                x, params["butterfly"]["w_reduce"], bits=bits)
-        else:
-            r = x @ params["butterfly"]["w_reduce"]
-            if wire_mode == "reduced":
-                return r, jnp.zeros((r.shape[0], seq_len, 1), jnp.float32)
-            codes, scales = quantize(r, bits)
-        if wire_mode == "int4":
-            codes = pack_int4(codes)
-        return codes, scales
+        return wire.encode(x, params["butterfly"]["w_reduce"],
+                           kernel=use_kernel)
 
-    def stage_cloud(params, codes, scales):
-        if wire_mode == "raw":
-            x = codes
-            x, _, _ = tfm.apply_stage(
-                list(built.stages[1]), params["stages"][1], x, cfg=cfg,
-                pctx=pctx, mode="train", stage_cache=None, pos=None,
-                shared_params=params.get("shared_attn"))
-            x = rms_norm(x[:, -1:], params["final_norm"], cfg.rms_eps)
-            table = params["embed"] if cfg.tie_embeddings else params["head"]
-            return unembed(table, x)[:, 0]
-        if wire_mode == "int4":
-            codes = unpack_int4(codes)
-        if use_kernel and wire_mode != "reduced":
-            x = ops.butterfly_dequant_restore(
-                codes, scales, params["butterfly"]["w_restore"], out_dtype=dt)
-        else:
-            r = codes if wire_mode == "reduced" else \
-                dequantize(codes, scales, dt)
-            x = r @ params["butterfly"]["w_restore"]
+    def stage_cloud(params, payload, scales):
+        x = wire.decode(payload, scales, params["butterfly"]["w_restore"], dt,
+                        kernel=use_kernel)
         x, _, _ = tfm.apply_stage(
             list(built.stages[1]), params["stages"][1], x, cfg=cfg,
             pctx=pctx, mode="train", stage_cache=None, pos=None,
@@ -173,16 +138,7 @@ def make_split_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
         mb_toks = tokens.reshape(Mmb, -1, seq_len)
         mb = mb_toks.shape[1]
 
-        if wire_mode == "raw":
-            wire_shape, wire_dtype = (mb, seq_len, d), dt
-        elif wire_mode == "reduced":
-            wire_shape, wire_dtype = (mb, seq_len, d_r), dt
-        elif wire_mode == "int4":
-            wire_shape, wire_dtype = (mb, seq_len, d_r // 2), jnp.int8
-        else:
-            wire_shape, wire_dtype = (mb, seq_len, d_r), jnp.int8
-        zero_wire = (jnp.zeros(wire_shape, wire_dtype),
-                     jnp.zeros((mb, seq_len, 1), jnp.float32))
+        zero_wire = _zero_wire(wire, mb, seq_len, cfg)
         zero_logits = jnp.zeros((mb, V), jnp.float32)
 
         def tick(t, carry):
@@ -296,16 +252,13 @@ def make_decode_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
     tfm.check_tp_divisibility(tfm.build_layer_defs(cfg, built.long_mode),
                               cfg, mp)
     pctx = manual_context(mesh) if mp > 1 else LOCAL
-    d_r = cfg.butterfly.d_r
     S = int(prompt_len)
     T = int(new_tokens)
     Mmb = int(num_microbatches)
     dt = jnp.dtype(cfg.dtype)
-    assert wire_mode in ("int8", "int4", "entropy"), wire_mode
-    if wire_mode == "int4":
-        assert d_r % 2 == 0, "int4 wire packs two codes per byte"
-    bits = 4 if wire_mode == "int4" else 8
-    wire_cols = d_r // 2 if wire_mode == "int4" else d_r
+    # the codes are int8, or int4 packed two per byte
+    wire = Wire.parse(wire_mode, 8, cfg.butterfly.d_r)
+    assert wire.quantized, wire_mode
     assert T >= 2, "need at least one decode tick"
     if pipelined:
         assert Mmb >= 2, "pipelined decode needs >= 2 in-flight microbatches"
@@ -314,29 +267,19 @@ def make_decode_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
     embed_scale = cfg.arch_type == "dense" and cfg.act == "gelu"
 
     def edge_wire(params, x):
-        if use_kernel:
-            codes, scales = ops.butterfly_reduce_quant(
-                x, params["butterfly"]["w_reduce"], bits=bits)
-        else:
-            r = x @ params["butterfly"]["w_reduce"]
-            codes, scales = quantize(r, bits)
-        if wire_mode == "int4":
-            codes = pack_int4(codes)
-        return codes, scales
+        return wire.encode(x, params["butterfly"]["w_reduce"],
+                           kernel=use_kernel)
 
     def cloud_restore(params, codes, scales):
-        if wire_mode == "int4":
-            codes = unpack_int4(codes)
+        """The restored rows and, on the kernel path, the first cloud
+        layer's norm of them from the same fused kernel (else None)."""
+        w_restore = params["butterfly"]["w_restore"]
         if use_kernel:
             nw = tfm.first_layer_norm1(stages1, params["stages"][1])
-            x, h = ops.butterfly_restore_norm(
-                codes, scales, params["butterfly"]["w_restore"], nw,
-                eps=cfg.rms_eps, out_dtype=dt)
-        else:
-            r = dequantize(codes, scales, dt)
-            x = r @ params["butterfly"]["w_restore"]
-            h = None
-        return x, h
+            return ops.butterfly_restore_norm(
+                wire.unpack(codes), scales, w_restore, nw, eps=cfg.rms_eps,
+                out_dtype=dt)
+        return wire.decode(codes, scales, w_restore, dt, kernel=False), None
 
     def greedy(params, x):
         x = rms_norm(x[:, -1:], params["final_norm"], cfg.rms_eps)
@@ -396,10 +339,8 @@ def make_decode_pipeline(built: M.BuiltModel, mesh, num_microbatches: int,
         pod = jax.lax.axis_index("pod")
         mb_toks = tokens.reshape(Mmb, -1, S)
         mb = mb_toks.shape[1]
-        zero_prefill_wire = (jnp.zeros((mb, S, wire_cols), jnp.int8),
-                             jnp.zeros((mb, S, 1), jnp.float32))
-        zero_row_wire = (jnp.zeros((mb, 1, wire_cols), jnp.int8),
-                        jnp.zeros((mb, 1, 1), jnp.float32))
+        zero_prefill_wire = _zero_wire(wire, mb, S, cfg)
+        zero_row_wire = _zero_wire(wire, mb, 1, cfg)
         zero_tok = jnp.zeros((mb,), jnp.int32)
         # Each model rank caches only its own KV-head slice, so size the
         # decode templates with per-rank head counts (recurrent-mixer states

@@ -8,7 +8,7 @@ import pytest
 from repro.configs import get_config
 from repro.configs.resnet50 import PAPER_MIN_DR, resnet50
 from repro.core import butterfly as bf
-from repro.core.quantization import dequantize
+from repro.core.quantization import Wire, dequantize
 from repro.models import model as M
 
 
@@ -41,9 +41,11 @@ def test_reduce_restore_units_roundtrip():
                                   .reduced().with_butterfly(1, 16).butterfly,
                                   dtype=jnp.float32)
     x = jax.random.normal(jax.random.key(1), (4, 8, 64))
-    codes, scales = bf.reduce_unit(params, x)
+    wire = Wire("int8", 8, 16)
+    codes, scales = wire.encode(x, params["w_reduce"], kernel=False)
     assert codes.dtype == jnp.int8 and codes.shape == (4, 8, 16)
-    out = bf.restore_unit(params, codes, scales, jnp.float32)
+    out = wire.decode(codes, scales, params["w_restore"], jnp.float32,
+                      kernel=False)
     assert out.shape == x.shape
     # identical to the in-graph fake-quant form
     ref = bf.apply_butterfly(params, x, train=True)
@@ -57,8 +59,9 @@ def test_kernel_path_matches_reference_path():
         "w_restore": jax.random.normal(jax.random.key(3), (16, 64)) * 0.1,
     }
     x = jax.random.normal(jax.random.key(4), (2, 8, 64))
-    c1, s1 = bf.reduce_unit(params, x, use_kernel=False)
-    c2, s2 = bf.reduce_unit(params, x, use_kernel=True)
+    wire = Wire("int8", 8, 16)
+    c1, s1 = wire.encode(x, params["w_reduce"], kernel=False)
+    c2, s2 = wire.encode(x, params["w_reduce"], kernel=True)
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), rtol=1e-6)
 

@@ -1,5 +1,6 @@
 """Property-based tests (hypothesis) for the int8 wire codec — the invariants
-the paper's wire format relies on."""
+the paper's wire format relies on — and the wire format (``Wire``) pinned
+to the codec's formulas and byte counts."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,8 +10,11 @@ pytest.importorskip("hypothesis", reason="property suites need hypothesis "
                     "(pip install -r requirements-dev.txt)")
 from hypothesis import given, settings, strategies as st
 
-from repro.core.quantization import (dequantize, fake_quant, pack_int4,
-                                     quantize, unpack_int4, wire_bytes)
+from repro.configs import get_config
+from repro.core.quantization import (WIRE_MODES, Wire, dequantize, fake_quant,
+                                     pack_int4, quantize, unpack_int4,
+                                     wire_bytes)
+from repro.kernels import ops
 
 arrays = st.integers(1, 7).flatmap(
     lambda rows: st.integers(2, 33).flatmap(
@@ -92,3 +96,71 @@ def test_fake_quant_equals_quant_dequant():
     c, s = quantize(x, 8)
     b = dequantize(c, s)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
+
+
+def _reference_wire(mode, kernel, x, w_reduce, w_restore):
+    """The wire's (payload, restored rows) by the codec's own formulas."""
+    if mode == "raw":
+        return x, x
+    if mode == "reduced":
+        return x @ w_reduce, (x @ w_reduce) @ w_restore
+    bits = 4 if mode == "int4" else 8
+    if kernel:
+        codes, scales = ops.butterfly_reduce_quant(x, w_reduce, bits=bits)
+        out = ops.butterfly_dequant_restore(codes, scales, w_restore,
+                                            out_dtype=x.dtype)
+    else:
+        codes, scales = quantize(x @ w_reduce, bits)
+        out = dequantize(codes, scales, x.dtype) @ w_restore
+    return (pack_int4(codes) if mode == "int4" else codes), out
+
+
+@pytest.mark.parametrize("kernel", [True, False])
+@pytest.mark.parametrize("mode", WIRE_MODES)
+def test_wire_roundtrip_matches_codec(mode, kernel):
+    """Encode then decode is the codec, bit for bit: quantize (fused or
+    eager), int4 packing, dequantize and the two projections; the
+    unquantized modes send zero scales."""
+    x = jax.random.normal(jax.random.key(0), (2, 24, 64))
+    w_reduce = jax.random.normal(jax.random.key(1), (64, 16)) * 0.1
+    w_restore = jax.random.normal(jax.random.key(2), (16, 64)) * 0.1
+    wire = Wire.parse(mode, 8, 16)
+    payload, scales = wire.encode(x, w_reduce, kernel=kernel)
+    out = wire.decode(payload, scales, w_restore, x.dtype, kernel=kernel)
+    want_payload, want_out = _reference_wire(mode, kernel, x, w_reduce,
+                                             w_restore)
+    np.testing.assert_array_equal(np.asarray(payload),
+                                  np.asarray(want_payload))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want_out))
+    np.testing.assert_array_equal(
+        np.asarray(wire.through(x, w_reduce, w_restore, kernel=kernel)),
+        np.asarray(out))
+    assert scales.shape == (2, 24, 1) and scales.dtype == jnp.float32
+    if not wire.quantized:
+        assert not np.asarray(scales).any()
+    want = wire.payload_shape_dtype(2, 24, 64, x.dtype)
+    assert (want[0].shape, want[0].dtype) == (payload.shape, payload.dtype)
+
+
+# bytes of one and of 128 rows (d_r 16, bf16 activations of width 256)
+WIRE_NBYTES = {"raw": (512, 65536), "reduced": (32, 4096),
+               "int8": (20, 2560), "int4": (12, 1536),
+               "entropy": (20, 1540)}
+
+
+@pytest.mark.parametrize("mode", WIRE_MODES)
+def test_wire_nbytes(mode):
+    import dataclasses
+    cfg = dataclasses.replace(get_config("qwen3-8b").reduced(),
+                              dtype="bfloat16")
+    wire = Wire.parse(mode, 8, 16)
+    assert (wire.nbytes(cfg, 1), wire.nbytes(cfg, 128)) == WIRE_NBYTES[mode]
+    assert wire.nbytes(cfg, 64, batch=2) == wire.nbytes(cfg, 128)
+
+
+def test_wire_refuses_unknown_mode_and_odd_int4():
+    with pytest.raises(ValueError, match="unknown wire mode"):
+        Wire.parse("int2", 8, 16)
+    with pytest.raises(ValueError, match="int4"):
+        Wire.parse("int4", 8, 15)
+    assert Wire.parse("int4", 8, 16).bits == 4

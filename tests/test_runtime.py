@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.planner import select_split_online, wire_mode_bytes
+from repro.core import quantization
+from repro.core.planner import select_split_online
 from repro.core.profiler import JETSON_TX2
 from repro.core.wireless import NETWORKS, get_link
 from repro.runtime.clock import EventLoop
@@ -169,9 +170,8 @@ def test_int8_wire_beats_raw_offload_on_3g():
 
 def test_wire_mode_bytes_ordering():
     cfg = small_cfg()
-    raw = wire_mode_bytes(cfg, 32, 16, "raw")
-    red = wire_mode_bytes(cfg, 32, 16, "reduced")
-    q = wire_mode_bytes(cfg, 32, 16, "int8")
+    raw, red, q = (quantization.Wire(m, 8, 16).nbytes(cfg, 32)
+                   for m in ("raw", "reduced", "int8"))
     assert q < red < raw
     assert q == 32 * 16 + 32 * 4                      # codes + f32 scales
 
@@ -279,7 +279,9 @@ def test_bank_holds_one_backbone_param_copy():
     assert butterfly_bytes == 3 * 2 * d * 16 * 4
 
 
-def test_cache_injection_parity_all_wire_modes():
+@pytest.mark.parametrize("wm", ["raw", "reduced", "int8", "int4",
+                                "entropy"])
+def test_cache_injection_parity_all_wire_modes(wm):
     """Edge half -> wire -> cloud half -> submit_prefilled must reproduce
     the single-mesh reference forward (logits) and the engine's own
     full-prefill decode (tokens) for every wire mode."""
@@ -288,24 +290,23 @@ def test_cache_injection_parity_all_wire_modes():
 
     rng = np.random.default_rng(7)
     toks = rng.integers(0, 512, size=(1, 16)).astype(np.int32)
-    for wm in ("raw", "reduced", "int8"):
-        bank = SplitModelBank(small_cfg(layers=2), 16, wire_mode=wm, seed=0)
-        r = bank.runner(1)
-        payload, scales, c0 = r.edge_half(r.params, toks)
-        logits, c1 = r.cloud_half(r.params, payload, scales)
-        ref, _ = r.reference_prefill(toks)
-        np.testing.assert_allclose(np.asarray(logits),
-                                   np.asarray(ref[:, -1]),
-                                   rtol=1e-4, atol=1e-4, err_msg=wm)
-        # inject the handed-off caches and decode greedily ...
-        eng = r.make_engine(max_batch=2, max_len=24, seed=0)
-        inj = eng.submit_prefilled(16, [c0, c1], logits[0], max_new_tokens=4)
-        eng.run()
-        # ... and compare against the same engine prefilling from scratch
-        ref_req = eng.submit(toks[0], max_new_tokens=4)
-        eng.run()
-        assert inj.generated[0] == int(jnp.argmax(ref[0, -1])), wm
-        assert inj.generated == ref_req.generated, wm
+    bank = SplitModelBank(small_cfg(layers=2), 16, wire_mode=wm, seed=0)
+    r = bank.runner(1)
+    payload, scales, c0 = r.edge_half(r.params, toks)
+    logits, c1 = r.cloud_half(r.params, payload, scales)
+    ref, _ = r.reference_prefill(toks)
+    np.testing.assert_allclose(np.asarray(logits),
+                               np.asarray(ref[:, -1]),
+                               rtol=1e-4, atol=1e-4, err_msg=wm)
+    # inject the handed-off caches and decode greedily ...
+    eng = r.make_engine(max_batch=2, max_len=24, seed=0)
+    inj = eng.submit_prefilled(16, [c0, c1], logits[0], max_new_tokens=4)
+    eng.run()
+    # ... and compare against the same engine prefilling from scratch
+    ref_req = eng.submit(toks[0], max_new_tokens=4)
+    eng.run()
+    assert inj.generated[0] == int(jnp.argmax(ref[0, -1])), wm
+    assert inj.generated == ref_req.generated, wm
 
 
 def test_bank_unaligned_boundary_peels_units():

@@ -13,8 +13,9 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.core import quantization
 from repro.core.costs import TOKEN_BYTES
-from repro.core.planner import select_split_online, wire_mode_bytes
+from repro.core.planner import select_split_online
 from repro.core.profiler import GTX_1080TI, JETSON_TX2
 from repro.core.wireless import INTER_POD, NETWORKS
 from repro.runtime.simulator import (SimConfig, Simulation, poisson_arrivals)
@@ -132,8 +133,9 @@ def test_streamed_uplink_flat_in_prompt_len():
             tel = Simulation(timing_cfg(transport=tp, prompt_len=S,
                                         num_requests=8)).run()
             totals[(tp, S)] = sum(t.wire_bytes for t in tel.traces)
-    codes_delta = 8 * (wire_mode_bytes(small_cfg(), 128, 16, "int8") -
-                       wire_mode_bytes(small_cfg(), 32, 16, "int8"))
+    wire = quantization.Wire("int8", 8, 16)
+    codes_delta = 8 * (wire.nbytes(small_cfg(), 128) -
+                       wire.nbytes(small_cfg(), 32))
     stream_growth = totals[("streamed", 128)] - totals[("streamed", 32)]
     handoff_growth = totals[("cache_handoff", 128)] - \
         totals[("cache_handoff", 32)]

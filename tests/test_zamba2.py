@@ -18,8 +18,7 @@ import pytest
 from repro.configs import get_config
 from repro.configs.base import ModelConfig, SSMConfig
 from repro.core.costs import id_wire_bytes
-from repro.core.planner import wire_mode_bytes
-from repro.core.quantization import CodedRows
+from repro.core.quantization import CodedRows, Wire
 from repro.models import ssm as ssm_lib
 from repro.runtime.split_exec import SplitModelBank
 
@@ -253,9 +252,9 @@ def test_ids_cross_the_wire_in_both_transports(served):
     from repro.runtime.split_exec import CostModel
     cm = CostModel(CFG, JETSON_TX2, GTX_1080TI)
     assert cm.payload_bytes("split", "int8", PROMPT, D_R, SPLIT) == \
-        wire_mode_bytes(CFG, PROMPT, D_R, "int8") + 2 * PROMPT
+        Wire("int8", 8, D_R).nbytes(CFG, PROMPT) + 2 * PROMPT
     assert cm.stream_row_bytes("int8", D_R, SPLIT) == \
-        wire_mode_bytes(CFG, 1, D_R, "int8") + 2
+        Wire("int8", 8, D_R).nbytes(CFG, 1) + 2
     # a split past the last hybrid layer ships no ids
     edge_only = dataclasses.replace(CFG, hybrid_layer_ids=(2,))
     assert id_wire_bytes(edge_only, SPLIT, PROMPT) == 0
@@ -277,9 +276,9 @@ def test_qwen3_wire_is_unchanged():
     assert payload.nbytes == 10 * 16 and r.id_bytes == 0
     cm = CostModel(cfg, JETSON_TX2, GTX_1080TI)
     assert cm.payload_bytes("split", "int8", 10, 16, 1) == \
-        wire_mode_bytes(cfg, 10, 16, "int8")
+        Wire("int8", 8, 16).nbytes(cfg, 10)
     assert cm.stream_row_bytes("int8", 16, 1) == \
-        cm.stream_row_bytes("int8", 16) == wire_mode_bytes(cfg, 1, 16, "int8")
+        cm.stream_row_bytes("int8", 16) == Wire("int8", 8, 16).nbytes(cfg, 1)
 
 
 @pytest.mark.parametrize("transport", ["cache_handoff", "streamed"])
